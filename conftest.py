@@ -1,7 +1,5 @@
 # Ensures `import benchmarks` and `import repro` work from pytest (adds
-# repo root + src/ to sys.path), and installs the in-repo hypothesis
-# fallback when the real package is absent (hermetic containers; CI installs
-# the real one via the `test` extra in pyproject.toml).
+# repo root + src/ to sys.path).
 import os
 import sys
 
@@ -9,9 +7,3 @@ _ROOT = os.path.dirname(os.path.abspath(__file__))
 for _p in (_ROOT, os.path.join(_ROOT, "src")):
     if _p not in sys.path:
         sys.path.insert(0, _p)
-
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    from repro._compat import hypothesis_fallback
-    hypothesis_fallback.install()

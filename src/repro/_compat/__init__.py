@@ -1,1 +1,1 @@
-"""Dependency fallbacks for hermetic environments (see conftest.py)."""
+"""Backend-dependent defaults shared by the Pallas kernels."""

@@ -1,13 +1,9 @@
-"""Pallas API compatibility aliases (jax renamed these across versions)."""
+"""Where Pallas kernels run: compiled by Mosaic on a TPU, interpreted
+elsewhere."""
 
 from typing import Optional
 
 import jax
-from jax.experimental.pallas import tpu as pltpu
-
-# jax < 0.5 exposes this as TPUCompilerParams, newer jax as CompilerParams
-CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams")
 
 
 def default_interpret() -> bool:

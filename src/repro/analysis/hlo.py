@@ -42,11 +42,10 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 # "  %name = dtype[dims]{layout} opcode(operands...), attrs" — tuple-typed
-# results allowed; ROOT prefix optional.
-_INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
-    r"(?P<type>\(.*?\)|[\w\[\]{},:#\d]+)\s+"
-    r"(?P<opcode>[\w\-]+)\((?P<rest>.*)$")
+# results allowed; ROOT prefix optional.  TPU layouts carry parentheses
+# (``{1,0:T(8,128)(2,1)}``), so a tuple type ends at its *matching* ')'.
+_DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*")
+_OPCODE_RE = re.compile(r"\s+(?P<opcode>[\w\-]+)\((?P<rest>.*)$")
 _LEAF_RE = re.compile(r"\b([a-z0-9]+)\[([\d,]*)\]")
 _NAME_RE = re.compile(r"^%?([\w.\-]+)$")
 
@@ -88,6 +87,22 @@ def _split_top_level(s: str) -> List[str]:
     if tail:
         parts.append(tail)
     return parts
+
+
+def _split_type(s: str) -> Optional[Tuple[str, str]]:
+    """``(result type, remainder)`` of a definition's right-hand side."""
+    if not s.startswith("("):
+        head, _, tail = s.partition(" ")
+        return head, " " + tail
+    depth = 0
+    for i, ch in enumerate(s):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+            if depth == 0:
+                return s[:i + 1], s[i + 1:]
+    return None
 
 
 def _operand_region(rest: str) -> str:
@@ -183,12 +198,14 @@ def parse_hlo(text: str) -> HloModule:
     instructions: List[HloInstruction] = []
     by_name: Dict[str, HloInstruction] = {}
     for lineno, line in enumerate(text.splitlines(), start=1):
-        m = _INSTR_RE.match(line)
+        d = _DEF_RE.match(line)
+        typed = _split_type(line[d.end():]) if d else None
+        m = _OPCODE_RE.match(typed[1]) if typed else None
         if not m:
             continue
         operands = tuple(_split_top_level(_operand_region(m.group("rest"))))
         instr = HloInstruction(
-            name=m.group("name"), result_type=m.group("type"),
+            name=d.group("name"), result_type=typed[0],
             opcode=m.group("opcode"), operands=operands, line=lineno,
             is_root=line.lstrip().startswith("ROOT"))
         instructions.append(instr)

@@ -83,7 +83,7 @@ def _verify_static(rt: Any, config: Any, steps: Optional[int]
                    ) -> Tuple[List[Finding], Dict[str, Any]]:
     tr = rt.trainer
     batch = rt._batch_fn(0)
-    hlo = rt._step_fn.lower(rt._state, batch).compile().as_text()
+    hlo = rt.compiled_step_text(batch)
     compressor = getattr(tr, "compressor", None)
     zero3 = config.execution.zero3
     findings = verify_schedule(hlo, rt.plan, tr.specs,

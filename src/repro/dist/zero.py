@@ -32,7 +32,6 @@ from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ArchConfig
@@ -188,10 +187,10 @@ class ZeroTrainer:
         def step(state, batch):
             batch_specs = jax.tree_util.tree_map(
                 lambda b: P(self.axis_name, *([None] * (b.ndim - 1))), batch)
-            fn = shard_map(self._local_step, mesh=self.mesh,
-                           in_specs=(state_specs, batch_specs),
-                           out_specs=(state_specs, P()),
-                           check_rep=False)
+            fn = jax.shard_map(self._local_step, mesh=self.mesh,
+                               in_specs=(state_specs, batch_specs),
+                               out_specs=(state_specs, P()),
+                               check_vma=False)
             return fn(state, batch)
 
         return step

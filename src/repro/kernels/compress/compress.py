@@ -1,13 +1,20 @@
 """Pallas kernels: fused gradient compression on the transmission path.
 
-Two payload formats, both extending the ``bucket_pack`` streaming-copy
-pattern (scalar-prefetched offsets, grid ``(K, Lmax // TILE)``, scratch
-tile redirect for out-of-range programs):
+Two payload formats:
 
 * ``quantize_pack``   — fp32 segments → int8 payload + per-TILE fp32
-  scales, in one HBM→VMEM→HBM pass.  Per tile: ``scale = absmax/127``,
-  ``q = round(x * 127/absmax)``; the inverse ``dequantize_unpack``
-  restores zero-padded (K, Lmax) rows as ``q * scale``.
+  scales, in one HBM→VMEM→HBM pass.  Per tile: ``scale = absmax *
+  INV_127``, ``q = round(x * 127/absmax)``; the inverse
+  ``dequantize_unpack`` restores zero-padded (K, Lmax) rows as
+  ``q * scale``.  The scale is a product with a constant, not a division
+  by 127: compilers may rewrite a division by a constant as a multiply in
+  one program and not in another, and the kernel must match its oracle
+  bit for bit.  The kernels see each segment as a lane-dense
+  ``(tiles, TILE)`` matrix and take up to ``BLOCK_ROWS`` tiles per grid
+  step (scales as a ``(tiles, 1)`` column), which is the block layout
+  Mosaic accepts for int8 and for one scale per tile; the last block of a
+  segment may be partial (its out-of-range rows are never written).  The
+  wrappers compact the per-segment results into the flat payload.
 * ``sparsify``/``densify`` — magnitude top-k payloads.  Index *selection*
   is data-dependent and happens outside the kernel (shared jnp helper in
   ``ops.py`` so kernel and oracle agree bit-exactly); the kernels do the
@@ -26,35 +33,45 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro._compat.pallas import resolve_interpret
 from repro.kernels.bucket_pack.bucket_pack import (TILE, _check_aligned_lengths,
-                                                   _pack_index_out,
-                                                   _unpack_index_in, aligned)
+                                                   aligned)
 
 __all__ = ["TILE", "aligned", "quantize_pack_pallas",
            "dequantize_unpack_pallas", "sparsify_pallas", "densify_pallas"]
 
+INV_127 = np.float32(1.0 / 127.0)
 
-def _quantize_pack_kernel(offsets_ref, seg_ref, q_ref, scale_ref):
-    tile = seg_ref[...]
-    absmax = jnp.max(jnp.abs(tile))
+# tiles per grid step: a (256, TILE) f32 block is 512 KiB of VMEM
+BLOCK_ROWS = 256
+# int8 blocks tile as (32, 128): a block's row count is a multiple of 32
+_INT8_SUBLANES = 32
+
+
+def _tiled_call(kernel, ntiles: int, k_count: int, in_widths, out_shape,
+                interpret: bool):
+    """``kernel`` over (K, ntiles, width) operands, up to ``BLOCK_ROWS``
+    tiles of one segment per grid step."""
+    rows = min(BLOCK_ROWS, -(-ntiles // _INT8_SUBLANES) * _INT8_SUBLANES)
+    block = lambda width: pl.BlockSpec((None, rows, width),
+                                       lambda k, i: (k, i, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(k_count, -(-ntiles // rows)),
+        in_specs=[block(w) for w in in_widths],
+        out_specs=[block(s.shape[-1]) for s in out_shape],
+        out_shape=out_shape,
+        interpret=interpret,
+    )
+
+
+def _quantize_kernel(x_ref, q_ref, scale_ref):
+    x = x_ref[...]                                          # (rows, TILE)
+    absmax = jnp.max(jnp.abs(x), axis=1, keepdims=True)     # (rows, 1)
     inv = jnp.where(absmax > 0, 127.0 / absmax, 0.0)
-    q_ref[...] = jnp.round(tile * inv).astype(jnp.int8)
-    scale_ref[...] = jnp.full((1,), absmax / 127.0, seg_ref.dtype)
-
-
-def _scale_index_out(k, t, offsets_ref):
-    # one scale per TILE; out-of-range tiles land in the trailing scratch slot
-    base = offsets_ref[k] // TILE
-    ntiles = offsets_ref[k + 1] // TILE - base
-    in_range = t < ntiles
-    return (jnp.where(in_range, base + t, offsets_ref[-1] // TILE),)
-
-
-def _offsets(aligned_lengths: Sequence[int]) -> np.ndarray:
-    return np.concatenate([[0], np.cumsum(aligned_lengths)]).astype(np.int32)
+    q_ref[...] = jnp.round(x * inv).astype(jnp.int32).astype(jnp.int8)
+    scale_ref[...] = absmax * INV_127
 
 
 def quantize_pack_pallas(segments: jnp.ndarray,
@@ -73,46 +90,22 @@ def quantize_pack_pallas(segments: jnp.ndarray,
         raise ValueError(f"segment row length {lmax} is not a multiple of "
                          f"TILE={TILE}")
     _check_aligned_lengths(aligned_lengths, k_count)
-    offsets = _offsets(aligned_lengths)
-    total = int(offsets[-1])
-    ntiles = total // TILE
-
-    grid = (k_count, lmax // TILE)
-    payload, scales = pl.pallas_call(
-        _quantize_pack_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((None, TILE), lambda k, t, offs: (k, t))],
-            out_specs=[pl.BlockSpec((TILE,), _pack_index_out),
-                       pl.BlockSpec((1,), _scale_index_out)],
-        ),
-        out_shape=[jax.ShapeDtypeStruct((total + TILE,), jnp.int8),
-                   jax.ShapeDtypeStruct((ntiles + 1,), segments.dtype)],
-        interpret=interpret,
-    )(jnp.asarray(offsets), segments)
-    return payload[:total], scales[:ntiles]
+    ntiles = lmax // TILE
+    q, scales = _tiled_call(
+        _quantize_kernel, ntiles, k_count, (TILE,),
+        [jax.ShapeDtypeStruct((k_count, ntiles, TILE), jnp.int8),
+         jax.ShapeDtypeStruct((k_count, ntiles, 1), jnp.float32)],
+        interpret)(segments.reshape(k_count, ntiles, TILE))
+    payload = jnp.concatenate([q[k, :n // TILE].reshape(-1)
+                               for k, n in enumerate(aligned_lengths)])
+    scales = jnp.concatenate([scales[k, :n // TILE, 0]
+                              for k, n in enumerate(aligned_lengths)])
+    return payload, scales
 
 
-def _dequantize_unpack_kernel(offsets_ref, q_ref, scale_ref, out_ref):
-    k = pl.program_id(0)
-    t = pl.program_id(1)
-    ntiles = (offsets_ref[k + 1] - offsets_ref[k]) // TILE
-
-    @pl.when(t < ntiles)
-    def _():
-        out_ref[...] = q_ref[...].astype(out_ref.dtype) * scale_ref[0]
-
-    @pl.when(t >= ntiles)
-    def _():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-
-def _scale_index_in(k, t, offsets_ref):
-    base = offsets_ref[k] // TILE
-    ntiles = offsets_ref[k + 1] // TILE - base
-    in_range = t < ntiles
-    return (jnp.where(in_range, base + t, 0),)
+def _dequantize_kernel(q_ref, scale_ref, out_ref):
+    q = q_ref[...].astype(jnp.int32).astype(jnp.float32)
+    out_ref[...] = q * scale_ref[...]
 
 
 def dequantize_unpack_pallas(payload: jnp.ndarray, scales: jnp.ndarray,
@@ -124,29 +117,31 @@ def dequantize_unpack_pallas(payload: jnp.ndarray, scales: jnp.ndarray,
         raise ValueError(f"lmax {lmax} is not a multiple of TILE={TILE}")
     k_count = len(aligned_lengths)
     _check_aligned_lengths(aligned_lengths, k_count)
-    offsets = _offsets(aligned_lengths)
-    total = int(offsets[-1])
+    total = int(sum(aligned_lengths))
     if payload.shape != (total,):
         raise ValueError(f"payload shape {payload.shape} != ({total},) "
                          f"implied by aligned lengths")
     if scales.shape != (total // TILE,):
         raise ValueError(f"scales shape {scales.shape} != ({total // TILE},) "
                          f"(one per TILE={TILE})")
+    ntiles = lmax // TILE
+    # tiles past a segment's length carry q = 0 and scale 0, so they
+    # decode to exact zeros without a mask in the kernel
+    q_rows, s_rows = [], []
+    off = 0
+    for n in aligned_lengths:
+        nt = n // TILE
+        q_rows.append(jnp.pad(payload[off:off + n].reshape(nt, TILE),
+                              ((0, ntiles - nt), (0, 0))))
+        s_rows.append(jnp.pad(scales[off // TILE:off // TILE + nt],
+                              (0, ntiles - nt))[:, None])
+        off += n
 
-    grid = (k_count, lmax // TILE)
-    out = pl.pallas_call(
-        _dequantize_unpack_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[pl.BlockSpec((TILE,), _unpack_index_in),
-                      pl.BlockSpec((1,), _scale_index_in)],
-            out_specs=pl.BlockSpec((None, TILE), lambda k, t, offs: (k, t)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((k_count, lmax), scales.dtype),
-        interpret=interpret,
-    )(jnp.asarray(offsets), payload, scales)
-    return out
+    (out,) = _tiled_call(
+        _dequantize_kernel, ntiles, k_count, (TILE, 1),
+        [jax.ShapeDtypeStruct((k_count, ntiles, TILE), scales.dtype)],
+        interpret)(jnp.stack(q_rows), jnp.stack(s_rows))
+    return out.reshape(k_count, lmax)
 
 
 def _sparsify_kernel(idx_ref, seg_ref, out_ref):

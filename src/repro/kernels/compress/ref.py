@@ -12,6 +12,7 @@ from typing import Sequence, Tuple
 import jax.numpy as jnp
 
 from repro.kernels.bucket_pack.bucket_pack import TILE
+from repro.kernels.compress.compress import INV_127
 
 
 def quantize_pack_ref(segments: jnp.ndarray,
@@ -19,7 +20,7 @@ def quantize_pack_ref(segments: jnp.ndarray,
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(K, Lmax) f32 segments → (int8 payload, per-TILE f32 scales).
 
-    Per tile: scale = absmax / 127, q = round(x * 127 / absmax); an
+    Per tile: scale = absmax * INV_127, q = round(x * 127 / absmax); an
     all-zero tile quantizes to zeros with scale 0.
     """
     qs, scales = [], []
@@ -28,7 +29,7 @@ def quantize_pack_ref(segments: jnp.ndarray,
         absmax = jnp.max(jnp.abs(tiles), axis=1)
         inv = jnp.where(absmax > 0, 127.0 / absmax, 0.0)
         qs.append(jnp.round(tiles * inv[:, None]).astype(jnp.int8).reshape(-1))
-        scales.append(absmax / 127.0)
+        scales.append(absmax * INV_127)
     return jnp.concatenate(qs), jnp.concatenate(scales)
 
 

@@ -21,7 +21,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro._compat.pallas import CompilerParams as _CompilerParams
 from repro._compat.pallas import resolve_interpret
 
 DEFAULT_BQ = 128
@@ -123,7 +122,7 @@ def flash_attention_pallas(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 1), jnp.float32),       # l
             pltpu.VMEM((bq, hd), jnp.float32),      # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(q, k, v)

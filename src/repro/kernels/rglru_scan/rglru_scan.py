@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro._compat.pallas import CompilerParams as _CompilerParams
 from repro._compat.pallas import resolve_interpret
 
 DEFAULT_BT = 128
@@ -67,7 +66,7 @@ def rglru_scan_pallas(a: jnp.ndarray, x: jnp.ndarray, *,
         out_specs=pl.BlockSpec((None, bt, bw), lambda bi, wi, ti: (bi, ti, wi)),
         out_shape=jax.ShapeDtypeStruct((b, t, w), a.dtype),
         scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=resolve_interpret(interpret),
     )(a, x)

@@ -25,8 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ARCHITECTURES, INPUT_SHAPES, get_config, shape_applicable
 from repro.dist.sharding import (batch_shardings, cache_shardings,
                                  params_shardings)
-from repro.launch.hlo_analysis import (collective_bytes, cost_analysis_dict,
-                                       roofline)
+from repro.launch.hlo_analysis import collective_bytes, roofline
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import decode_specs, input_specs, state_specs
 from repro.models import model as model_lib
@@ -176,7 +175,7 @@ def run_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         t_compile = time.perf_counter() - t0
 
         mem = compiled.memory_analysis()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
         coll = collective_bytes(hlo)
         chips = mesh.devices.size

@@ -20,15 +20,6 @@ from repro.core.netmodel import (TPU_HBM_BW, TPU_ICI_BW_PER_LINK,
                                  TPU_PEAK_FLOPS_BF16)
 
 
-def cost_analysis_dict(compiled) -> Dict:
-    """`Compiled.cost_analysis()` returns a dict or a one-element list of
-    dicts depending on the jax version — normalize to a dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
 def collective_bytes(hlo_text: str) -> Dict[str, int]:
     """Per-op-kind operand bytes summed over the module (per device),
     plus a ``"_counts"`` entry with per-kind instruction counts.

@@ -310,6 +310,9 @@ def main() -> None:
         raise SystemExit("train.py drives text archs; stubbed-modality "
                          "archs are exercised via the dry-run and tests")
 
+    from repro.launch.chip import device_line, use_compile_cache
+    use_compile_cache()
+    print(device_line())
     rt = build_runtime(config)
     spec = f"[{config.runtime}] arch {config.arch}" + \
         (" (reduced)" if config.reduced else "") + \

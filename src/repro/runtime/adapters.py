@@ -213,6 +213,11 @@ class _CompiledRuntime(RuntimeAdapter):
         tree = self._load_tree(path, {"model": self._state})
         self._state = self._replace_like(self._state, tree["model"])
 
+    def compiled_step_text(self, batch) -> str:
+        """HLO text of the step as compiled for the current state and
+        ``batch`` (the runtimes that hold one jitted step: zero, ps)."""
+        return self._step_fn.lower(self._state, batch).compile().as_text()
+
 
 @register_runtime("local", description="single-process jit training, no "
                                        "distribution layer")

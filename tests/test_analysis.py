@@ -92,6 +92,21 @@ class TestHloParser:
         done = [i for i in mod.instructions if i.is_async_done]
         assert len(done) == 3 and not any(i.is_collective for i in done)
 
+    def test_tpu_layouts_parsed(self):
+        # TPU layouts carry parentheses ({0:T(1024)}, {1,0:T(8,128)(2,1)}),
+        # inside plain and tuple result types alike
+        mod = parse_hlo(fixture("tpu_layouts.txt"))
+        counts = collective_counts(mod)
+        assert counts["all-gather"] == 1 and counts["all-reduce"] == 2
+        summary = collective_summary(mod)
+        assert [b for _, b in summary["all-gather"]] == [4 * 15205376]
+        assert sorted(b for _, b in summary["all-reduce"]) == \
+            [4, 4 * 4 * 40372736]
+        fusion = mod.find("fusion")[0]
+        assert fusion.name == "fusion.493"
+        assert type_bytes(fusion.result_type) == 2 * 2 * 16777216
+        assert mod.find("tuple")[0].operands[1] == "%all-gather.17"
+
     def test_collective_bytes_contract(self):
         # launch.hlo_analysis.collective_bytes keeps its dict contract on
         # top of the structured walker

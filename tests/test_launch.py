@@ -11,8 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import INPUT_SHAPES, get_config
-from repro.launch.hlo_analysis import (Roofline, collective_bytes,
-                                       cost_analysis_dict, roofline)
+from repro.launch.hlo_analysis import Roofline, collective_bytes, roofline
 from repro.launch.specs import decode_specs, input_specs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -112,7 +111,7 @@ class TestAnalyticFlopsMatchUnrolledHLO:
                  "labels": jnp.zeros((4, 128), jnp.int32)}
         lowered = jax.jit(
             lambda p, b: train_loss(cfg, p, b)).lower(params, batch)
-        cost = cost_analysis_dict(lowered.compile())
+        cost = lowered.compile().cost_analysis()
         hlo_flops = float(cost.get("flops", 0))
         analytic_fwd = sum(p.flops_fwd for p in layer_profiles(cfg, shape))
         assert hlo_flops > 0

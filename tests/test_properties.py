@@ -1,11 +1,4 @@
-"""System-invariant property tests (hypothesis).
-
-Runs under the real `hypothesis` package when installed (CI) or the
-deterministic fallback in ``repro._compat.hypothesis_fallback`` (installed
-by conftest.py when the import fails) — both execute every ``@given`` test
-against randomized instances, so the strategies stick to the shared API
-surface (floats/integers/lists/tuples/booleans + map/flatmap).
-"""
+"""System-invariant property tests (hypothesis)."""
 
 import numpy as np
 import pytest
