@@ -1,0 +1,8 @@
+"""On-chip benchmark of the DynaComm trainers.
+
+``python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s>
+--trace <0|1>`` runs one cell of ``BENCHMARK.json`` once.  Everything a
+cell needs is found by name: its model in ``configs/``, its traffic in
+``traffic/``, its runtime settings and check limits in ``cells/``, and
+each per-layer metric's reader in ``metrics/``.
+"""
