@@ -22,6 +22,12 @@ step in which
 The step is built with ``shard_map`` so the collectives above are the
 *only* all-gathers / reduce-scatters in the compiled HLO —
 ``tests/test_dist.py`` asserts the counts against the plan.
+
+Each part of the step runs under a ``jax.named_scope`` that the profiler
+attaches to its device ops: ``zero.pull.b{i}``, ``zero.fwd.L{l}``,
+``zero.regather.b{i}``, ``zero.bwd.L{l}``, ``zero.push.b{i}`` (bucket
+``i`` of the plan, sched layer ``l``) and ``zero.opt``.  Scopes are
+metadata only: the compiled instructions are the same without them.
 """
 
 from __future__ import annotations
@@ -203,21 +209,25 @@ class ZeroTrainer:
 
         # ---- pull phase: one all-gather per forward bucket --------------
         full: Dict[int, Any] = {}
-        for bucket in self.plan.forward:
-            full.update(gather_bucket(shards, self.specs, bucket,
-                                      self.axis_name))
+        for i, bucket in enumerate(self.plan.forward):
+            with jax.named_scope(f"zero.pull.b{i}"):
+                full.update(gather_bucket(shards, self.specs, bucket,
+                                          self.axis_name))
 
         # ---- forward, saving each layer's input activation --------------
         acts: Dict[int, jnp.ndarray] = {}
         aux = jnp.zeros((), jnp.float32)
-        h = self._apply_embed(full[0], batch)
+        with jax.named_scope("zero.fwd.L0"):
+            h = self._apply_embed(full[0], batch)
         for l in range(1, Ls - 1):
             acts[l] = h
-            h, a = self._apply_block(full[l], h, kinds[l - 1])
-            aux = aux + a
+            with jax.named_scope(f"zero.fwd.L{l}"):
+                h, a = self._apply_block(full[l], h, kinds[l - 1])
+                aux = aux + a
         acts[Ls - 1] = h
-        ce = self._apply_final(full[Ls - 1], full[0], h, batch)
-        loss_local = ce + self.aux_weight * aux
+        with jax.named_scope(f"zero.fwd.L{Ls - 1}"):
+            ce = self._apply_final(full[Ls - 1], full[0], h, batch)
+            loss_local = ce + self.aux_weight * aux
 
         # ---- ZeRO-3: re-pull mid-layer buckets for the backward ---------
         # The barrier keeps the re-gather a distinct program point from the
@@ -226,10 +236,11 @@ class ZeroTrainer:
         regathered: Dict[int, Any] = {}
         if self.zero3:
             barred = list(jax.lax.optimization_barrier(tuple(shards)))
-            for bucket in self.plan.backward:
+            for i, bucket in enumerate(self.plan.backward):
                 if any(0 < l < Ls - 1 for l in bucket):
-                    regathered.update(gather_bucket(barred, self.specs,
-                                                    bucket, self.axis_name))
+                    with jax.named_scope(f"zero.regather.b{i}"):
+                        regathered.update(gather_bucket(
+                            barred, self.specs, bucket, self.axis_name))
 
         # ---- backward: per-layer VJPs, one reduce-scatter per bucket ----
         one = jnp.ones((), jnp.float32)
@@ -237,48 +248,52 @@ class ZeroTrainer:
         grad_shards: List[Optional[jnp.ndarray]] = [None] * Ls
         embed_from_head = None     # tied-head contribution to the embedding
         ct_h = None                # cotangent w.r.t. the current activation
-        for bucket in self.plan.backward:
+        for i, bucket in enumerate(self.plan.backward):
             bucket_grads: Dict[int, Any] = {}
             for l in bucket:       # descending layer order within the bucket
                 p_l = regathered.get(l, full[l])
-                if l == Ls - 1:
-                    _, vjp = jax.vjp(
-                        lambda pf, pe, hh: self._apply_final(pf, pe, hh,
-                                                             batch),
-                        p_l, full[0], acts[l])
-                    g_final, embed_from_head, ct_h = vjp(one)
-                    bucket_grads[l] = g_final
-                elif l == 0:
-                    _, vjp = jax.vjp(
-                        lambda pe: self._apply_embed(pe, batch), p_l)
-                    (g_embed,) = vjp(ct_h)
-                    bucket_grads[l] = jax.tree_util.tree_map(
-                        jnp.add, g_embed, embed_from_head)
+                with jax.named_scope(f"zero.bwd.L{l}"):
+                    if l == Ls - 1:
+                        _, vjp = jax.vjp(
+                            lambda pf, pe, hh: self._apply_final(pf, pe, hh,
+                                                                 batch),
+                            p_l, full[0], acts[l])
+                        g_final, embed_from_head, ct_h = vjp(one)
+                        bucket_grads[l] = g_final
+                    elif l == 0:
+                        _, vjp = jax.vjp(
+                            lambda pe: self._apply_embed(pe, batch), p_l)
+                        (g_embed,) = vjp(ct_h)
+                        bucket_grads[l] = jax.tree_util.tree_map(
+                            jnp.add, g_embed, embed_from_head)
+                    else:
+                        kind = kinds[l - 1]
+                        _, vjp = jax.vjp(
+                            lambda p, hh, _k=kind: self._apply_block(p, hh,
+                                                                     _k),
+                            p_l, acts[l])
+                        g_block, ct_h = vjp((ct_h, aux_ct))
+                        bucket_grads[l] = g_block
+            with jax.named_scope(f"zero.push.b{i}"):
+                if self.compressor is not None:
+                    res_in = ({l: res_local[l][0] for l in bucket}
+                              if res_local is not None else None)
+                    pushed, res_out = compressed_reduce_scatter_bucket(
+                        bucket_grads, self.specs, bucket, self.axis_name,
+                        self.compressor, residuals=res_in)
+                    if res_out is not None:
+                        for l, r in res_out.items():
+                            new_res[l] = r[None, :]
                 else:
-                    kind = kinds[l - 1]
-                    _, vjp = jax.vjp(
-                        lambda p, hh, _k=kind: self._apply_block(p, hh, _k),
-                        p_l, acts[l])
-                    g_block, ct_h = vjp((ct_h, aux_ct))
-                    bucket_grads[l] = g_block
-            if self.compressor is not None:
-                res_in = ({l: res_local[l][0] for l in bucket}
-                          if res_local is not None else None)
-                pushed, res_out = compressed_reduce_scatter_bucket(
-                    bucket_grads, self.specs, bucket, self.axis_name,
-                    self.compressor, residuals=res_in)
-                if res_out is not None:
-                    for l, r in res_out.items():
-                        new_res[l] = r[None, :]
-            else:
-                pushed = reduce_scatter_bucket(bucket_grads, self.specs,
-                                               bucket, self.axis_name)
-            for l, g in pushed.items():
-                grad_shards[l] = g / self.axis_size     # sum → mean
+                    pushed = reduce_scatter_bucket(bucket_grads, self.specs,
+                                                   bucket, self.axis_name)
+                for l, g in pushed.items():
+                    grad_shards[l] = g / self.axis_size     # sum → mean
 
         # ---- sharded optimizer update (ZeRO: on local shards only) ------
-        new_flats, new_opt = self.optimizer.update(grad_shards, state["opt"],
-                                                   shards)
+        with jax.named_scope("zero.opt"):
+            new_flats, new_opt = self.optimizer.update(
+                grad_shards, state["opt"], shards)
         loss = jax.lax.pmean(loss_local, self.axis_name)
         new_state = {"flat_params": new_flats, "opt": new_opt,
                      "step": state["step"] + 1}

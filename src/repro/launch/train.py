@@ -59,6 +59,8 @@ from __future__ import annotations
 import argparse
 import time
 
+import jax
+
 from repro.configs import ARCHITECTURES
 from repro.runtime import (CompressionConfig, ExecutionConfig, FleetConfig,
                            MeasureConfig, NetworkConfig, PipelineConfig,
@@ -293,6 +295,9 @@ def main() -> None:
                          "--checkpoint-every units and after training")
     ap.add_argument("--checkpoint-every", type=int, default=50)
     ap.add_argument("--log-every", type=int, default=10)
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="write a jax.profiler trace of every unit after "
+                         "the first (compiling) one under DIR")
     args = ap.parse_args()
 
     if args.config is not None:
@@ -304,6 +309,9 @@ def main() -> None:
         return
     if args.steps < 1:
         raise SystemExit(f"--steps must be >= 1, got {args.steps}")
+    if args.trace and args.steps < 2:
+        raise SystemExit("--trace records the units after the first: "
+                         "give --steps >= 2")
 
     from repro.configs import get_config
     if get_config(config.arch).frontend != "none":
@@ -312,6 +320,11 @@ def main() -> None:
 
     from repro.launch.chip import device_line, use_compile_cache
     use_compile_cache()
+    if args.trace:
+        # the cache's key leaves op_name metadata out, so a program loaded
+        # from it could carry the scopes of the build that wrote it
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     print(device_line())
     rt = build_runtime(config)
     spec = f"[{config.runtime}] arch {config.arch}" + \
@@ -337,6 +350,10 @@ def main() -> None:
     # chunks by the logging cadence for the wall-clock progress line
     while len(losses) < args.steps:
         chunk = min(args.log_every or args.steps, args.steps - len(losses))
+        if args.trace and not losses:
+            chunk = 1                  # the compiling unit, untraced
+        elif args.trace and len(losses) == 1:
+            jax.profiler.start_trace(args.trace)
         losses.extend(rt.fit(
             chunk,
             checkpoint_every=(args.checkpoint_every if args.checkpoint
@@ -346,6 +363,9 @@ def main() -> None:
             dt = (time.perf_counter() - t0) / max(len(losses), 1)
             print(f"step {len(losses):4d}  loss {losses[-1]:.4f}  "
                   f"{dt:.3f}s/step")
+    if args.trace:
+        jax.profiler.stop_trace()
+        print(f"[trace] units 2-{len(losses)} written under {args.trace}")
 
     _print_events(rt)
     led = rt.ledger

@@ -8,6 +8,9 @@ MODEL_FLOPS = 6·N_active·D comparison is honest.
 
 Tokens beyond an expert's capacity are dropped (their combine weight is
 zero) — standard capacity-factor semantics.
+
+The four stages run under the profiler scopes ``moe.route``,
+``moe.dispatch``, ``moe.experts`` and ``moe.combine``.
 """
 
 from __future__ import annotations
@@ -60,40 +63,47 @@ def apply_moe(params, x: jnp.ndarray, cfg: ArchConfig
     cap = expert_capacity(n, cfg)
     xf = x.reshape(n, d)
 
-    logits = dense(xf, params["router"]).astype(jnp.float32)       # (N, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)                          # (N, k)
-    top_p = (top_p / jnp.sum(top_p, axis=-1, keepdims=True)).astype(x.dtype)
+    with jax.named_scope("moe.route"):
+        logits = dense(xf, params["router"]).astype(jnp.float32)   # (N, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_p, top_e = jax.lax.top_k(probs, k)                      # (N, k)
+        top_p = (top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+                 ).astype(x.dtype)
 
-    # position within each expert, assignment-major order
-    flat_e = top_e.reshape(-1)                                      # (N*k,)
-    onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)             # (N*k, E)
-    pos_in_e = jnp.cumsum(onehot, axis=0) - onehot                  # exclusive
-    pos = jnp.sum(pos_in_e * onehot, axis=1)                        # (N*k,)
-    keep = pos < cap
-    slot = flat_e * cap + jnp.where(keep, pos, 0)                   # (N*k,)
+    with jax.named_scope("moe.dispatch"):
+        # position within each expert, assignment-major order
+        flat_e = top_e.reshape(-1)                                  # (N*k,)
+        onehot = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)         # (N*k, E)
+        pos_in_e = jnp.cumsum(onehot, axis=0) - onehot              # exclusive
+        pos = jnp.sum(pos_in_e * onehot, axis=1)                    # (N*k,)
+        keep = pos < cap
+        slot = flat_e * cap + jnp.where(keep, pos, 0)               # (N*k,)
 
-    # scatter tokens into the dispatch buffer (dropped tokens write nowhere)
-    buf = jnp.zeros((e * cap, d), x.dtype)
-    src = jnp.repeat(xf, k, axis=0) * keep[:, None].astype(x.dtype)
-    buf = buf.at[slot].add(jnp.where(keep[:, None], src, 0.0))
-    buf = buf.reshape(e, cap, d)
+        # scatter tokens into the dispatch buffer (dropped tokens write
+        # nowhere)
+        buf = jnp.zeros((e * cap, d), x.dtype)
+        src = jnp.repeat(xf, k, axis=0) * keep[:, None].astype(x.dtype)
+        buf = buf.at[slot].add(jnp.where(keep[:, None], src, 0.0))
+        buf = buf.reshape(e, cap, d)
 
-    # batched per-expert gated FFN
-    act = activation_fn(cfg.activation)
-    up = jnp.einsum("ecd,edf->ecf", buf, params["up"].astype(x.dtype))
-    if "gate" in params:
-        up = act(jnp.einsum("ecd,edf->ecf", buf,
-                            params["gate"].astype(x.dtype))) * up
-    else:
-        up = act(up)
-    out_buf = jnp.einsum("ecf,efd->ecd", up, params["down"].astype(x.dtype))
-    out_buf = out_buf.reshape(e * cap, d)
+    with jax.named_scope("moe.experts"):
+        # batched per-expert gated FFN
+        act = activation_fn(cfg.activation)
+        up = jnp.einsum("ecd,edf->ecf", buf, params["up"].astype(x.dtype))
+        if "gate" in params:
+            up = act(jnp.einsum("ecd,edf->ecf", buf,
+                                params["gate"].astype(x.dtype))) * up
+        else:
+            up = act(up)
+        out_buf = jnp.einsum("ecf,efd->ecd", up,
+                             params["down"].astype(x.dtype))
+        out_buf = out_buf.reshape(e * cap, d)
 
-    # gather back and combine with routing weights (dropped → weight 0)
-    gathered = out_buf[slot]                                        # (N*k, d)
-    w = top_p.reshape(-1) * keep.astype(x.dtype)
-    combined = jnp.sum((gathered * w[:, None]).reshape(n, k, d), axis=1)
+    with jax.named_scope("moe.combine"):
+        # gather back and combine with routing weights (dropped → weight 0)
+        gathered = out_buf[slot]                                    # (N*k, d)
+        w = top_p.reshape(-1) * keep.astype(x.dtype)
+        combined = jnp.sum((gathered * w[:, None]).reshape(n, k, d), axis=1)
 
     aux = router_load_balance_loss(probs, top_e, e)
     return combined.reshape(b, t, d), aux

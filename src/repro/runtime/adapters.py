@@ -125,7 +125,9 @@ class RuntimeAdapter:
         self._check_checkpoint(checkpoint_every, checkpoint_path)
         losses = []
         for _ in range(steps):
-            losses.append(self.step(self._batch_fn(self._data_idx)))
+            with jax.profiler.TraceAnnotation("repro.data"):
+                batch = self._batch_fn(self._data_idx)
+            losses.append(self.step(batch))
             if log_every and len(losses) % log_every == 0:
                 print(f"step {self._data_idx:4d}  loss {losses[-1]:.4f}")
             if eval_fn is not None and self._data_idx % eval_every == 0:
@@ -285,11 +287,13 @@ class ZeroRuntime(_CompiledRuntime):
         return self.trainer.plan
 
     def step(self, batch) -> float:
-        self._state, loss = self._step_fn(self._state, batch)
+        with jax.profiler.TraceAnnotation("repro.dispatch"):
+            self._state, loss = self._step_fn(self._state, batch)
         self._account(self.trainer.specs, self.trainer.plan,
                       self.trainer.axis_size)
         self._data_idx += 1
-        return float(loss)
+        with jax.profiler.TraceAnnotation("repro.sync"):
+            return float(loss)
 
     def timeline(self):
         from repro.core import simulate_iteration
