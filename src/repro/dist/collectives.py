@@ -18,6 +18,13 @@ once along rows.
 
 ``gather_bucket`` / ``reduce_scatter_bucket`` must run inside ``shard_map``
 (they issue ``jax.lax`` collectives over a named axis).
+
+The flat buffer is the *wire format* of a segment, and the ZeRO trainer
+(``repro.dist.zero``) holds its state in it only where a wire exists: more
+than one device on the data axis, or a compressor modelling the PS wire.
+On a one-device axis with no compressor the state holds the parameter
+leaves instead and none of these functions runs — packing there would be
+a relayout of every weight each step for nothing.
 """
 
 from __future__ import annotations

@@ -21,7 +21,8 @@ the two halves — ``repro.core`` decides, ``repro.dist.zero`` executes — and
   driver (``repro.ps.dynamic``).
 
 Because the ZeRO state layout (one ``FlatSpec`` flat buffer per sched
-layer) is plan-independent, states carry across plan swaps unchanged, and
+layer, or the parameter leaves on a one-device axis) depends only on the
+mesh and the compressor, states carry across plan swaps unchanged, and
 the loss trajectory of a dynamic run is bit-identical to running the same
 plan sequence statically (asserted by ``tests/test_dynamic.py``).
 """

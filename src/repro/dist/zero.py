@@ -4,10 +4,12 @@ The TPU-native adaptation of the paper's pull/push procedures: a
 ``BucketPlan`` (from ``repro.core.buckets``) drives a data-parallel training
 step in which
 
-* parameters live sharded as one padded flat float32 buffer per sched layer
-  (``state["flat_params"][l]`` has global shape ``(spec.padded,)`` split over
-  the ``data`` axis — ZeRO: optimizer state and master weights are never
-  replicated);
+* where a wire exists — the ``data`` axis has more than one device, or a
+  ``compressor`` models the PS wire — parameters live sharded as one padded
+  flat float32 buffer per sched layer (``state["flat_params"][l]`` has
+  global shape ``(spec.padded,)`` split over the ``data`` axis — ZeRO:
+  optimizer state and master weights are never replicated).  That buffer
+  is the wire format of a DynaComm segment:
 * the forward phase launches **exactly one all-gather per forward bucket**
   (the paper's parameter pull of a transmission segment);
 * the backward phase launches **exactly one reduce-scatter per backward
@@ -19,6 +21,16 @@ step in which
   layers are exempt — the head is hot at the fwd→bwd boundary and the
   embedding VJP needs no weights).
 
+Where no wire exists — one device on the ``data`` axis and no compressor —
+a wire format is pure cost (a relayout of every weight into and out of a
+1-D buffer each step), so the state holds the parameter leaves themselves:
+``state["flat_params"]`` is the model's float32 leaves in their natural
+shapes, sched layer by sched layer (each layer's ``tree_flatten`` order).
+A bucket's pull is then its layers' leaves, its push the VJP's leaf
+gradients, and ZeRO-3's re-pull has nothing to do.  The two layouts share
+the step's structure and no packing logic; the mesh and the compressor
+pick one (``ZeroTrainer.layout``: ``"flat"`` or ``"leaves"``).
+
 The step is built with ``shard_map`` so the collectives above are the
 *only* all-gathers / reduce-scatters in the compiled HLO —
 ``tests/test_dist.py`` asserts the counts against the plan.
@@ -28,6 +40,7 @@ attaches to its device ops: ``zero.pull.b{i}``, ``zero.fwd.L{l}``,
 ``zero.regather.b{i}``, ``zero.bwd.L{l}``, ``zero.push.b{i}`` (bucket
 ``i`` of the plan, sched layer ``l``) and ``zero.opt``.  Scopes are
 metadata only: the compiled instructions are the same without them.
+Holding leaves, the pull, push and re-pull scopes hold no operation.
 """
 
 from __future__ import annotations
@@ -49,6 +62,73 @@ from repro.dist.collectives import (FlatSpec, compressed_reduce_scatter_bucket,
 from repro.models import blocks as blocks_lib
 from repro.models import model as model_lib
 from repro.optim import Optimizer
+
+
+class _FlatLayout:
+    """A wire exists: one padded 1-D f32 buffer per sched layer, sharded
+    over the data axis; a bucket's pull is one all-gather and its push one
+    (optionally compressed) reduce-scatter."""
+
+    name = "flat"
+
+    def __init__(self, specs: List[FlatSpec], axis_name: str,
+                 compressor: Optional[Any]):
+        self.specs, self.axis_name = specs, axis_name
+        self.compressor = compressor
+
+    def init(self, trees) -> List[jnp.ndarray]:
+        return [flatten_tree(t, s) for t, s in zip(trees, self.specs)]
+
+    def to_trees(self, params) -> List[Any]:
+        return [unflatten_tree(jnp.asarray(f), s)
+                for f, s in zip(params, self.specs)]
+
+    def pull(self, params, bucket) -> Dict[int, Any]:
+        return gather_bucket(params, self.specs, bucket, self.axis_name)
+
+    def push(self, grads, bucket, residuals):
+        """``({layer: [its mean gradient shard]}, new residuals or None)``."""
+        if self.compressor is None:
+            # resolved in this module's namespace when called, so a
+            # replaced ``zero.reduce_scatter_bucket`` takes effect
+            pushed = reduce_scatter_bucket(grads, self.specs, bucket,
+                                           self.axis_name)
+            res_out = None
+        else:
+            pushed, res_out = compressed_reduce_scatter_bucket(
+                grads, self.specs, bucket, self.axis_name, self.compressor,
+                residuals=residuals)
+        axis_size = self.specs[bucket[0]].axis_size       # sum → mean
+        return {l: [g / axis_size] for l, g in pushed.items()}, res_out
+
+
+class _LeafLayout:
+    """No wire (one device, no compressor): the state is the parameter
+    leaves, sched layer by sched layer; pull and push move nothing."""
+
+    name = "leaves"
+
+    def __init__(self, specs: List[FlatSpec]):
+        self.specs = specs
+        self._starts = [0]
+        for spec in specs:
+            self._starts.append(self._starts[-1] + spec.num_leaves)
+
+    def init(self, trees) -> List[jnp.ndarray]:
+        return [x for t in trees for x in jax.tree_util.tree_leaves(t)]
+
+    def _tree(self, params, l: int) -> Any:
+        leaves = params[self._starts[l]:self._starts[l + 1]]
+        return jax.tree_util.tree_unflatten(self.specs[l].treedef, leaves)
+
+    def to_trees(self, params) -> List[Any]:
+        return [self._tree(params, l) for l in range(len(self.specs))]
+
+    def pull(self, params, bucket) -> Dict[int, Any]:
+        return {l: self._tree(params, l) for l in bucket}
+
+    def push(self, grads, bucket, residuals):
+        return {l: jax.tree_util.tree_leaves(grads[l]) for l in bucket}, None
 
 
 @dataclasses.dataclass
@@ -81,6 +161,15 @@ class ZeroTrainer:
             make_flat_spec(tree, self.axis_size)
             for tree in model_lib.sched_layer_trees(shapes)]
         self._kinds = self.cfg.layer_kinds()
+        self._layout = (
+            _LeafLayout(self.specs)
+            if self.axis_size == 1 and self.compressor is None else
+            _FlatLayout(self.specs, self.axis_name, self.compressor))
+
+    @property
+    def layout(self) -> str:
+        """``"leaves"`` (one device, no compressor: no wire) or ``"flat"``."""
+        return self._layout.name
 
     # ------------------------------------------------------------------
     # construction helpers
@@ -100,21 +189,16 @@ class ZeroTrainer:
     def with_plan(self, plan: BucketPlan) -> "ZeroTrainer":
         """Same trainer driving a different bucket plan.
 
-        The state layout (``FlatSpec`` per sched layer) depends only on the
-        architecture and the axis size, never on the plan — so states carry
-        across plan swaps unchanged.  Shares the already-computed specs
-        instead of re-running ``eval_shape``.
+        The state layout (``FlatSpec`` per sched layer, flat buffers or
+        leaves) depends only on the architecture, the axis size and the
+        compressor, never on the plan — so states carry across plan swaps
+        unchanged.  Shares the already-computed specs instead of re-running
+        ``eval_shape``.
         """
         new = copy.copy(self)
         new.plan = plan
         new._validate_plan()
         return new
-
-    def _flat_sharding(self) -> NamedSharding:
-        return NamedSharding(self.mesh, P(self.axis_name))
-
-    def _replicated(self) -> NamedSharding:
-        return NamedSharding(self.mesh, P())
 
     # ------------------------------------------------------------------
     # state
@@ -125,11 +209,10 @@ class ZeroTrainer:
         return self.compressor is not None and self.compressor.error_feedback
 
     def _make_state(self, key) -> Dict[str, Any]:
-        params = model_lib.init_params(self.cfg, key, jnp.float32)
-        flats = [flatten_tree(tree, spec) for tree, spec in
-                 zip(model_lib.sched_layer_trees(params), self.specs)]
-        state = {"flat_params": flats,
-                 "opt": self.optimizer.init(flats),
+        params = self._layout.init(model_lib.sched_layer_trees(
+            model_lib.init_params(self.cfg, key, jnp.float32)))
+        state = {"flat_params": params,
+                 "opt": self.optimizer.init(params),
                  "step": jnp.zeros((), jnp.int32)}
         if self._use_residuals:
             # error-feedback residual of each device's own compressed push:
@@ -140,22 +223,37 @@ class ZeroTrainer:
         return state
 
     def _state_layout(self, shapes, one_d, replicated, residual):
-        """Map state leaves to shardings/specs: flat buffers by ndim, the
-        error-feedback residuals (2-D, one row per device) explicitly."""
+        """Map state leaves to shardings/specs: arrays (flat buffers, or
+        leaves on a one-device axis) split on the data axis, scalars
+        replicated, the error-feedback residuals (2-D, one row per device)
+        explicitly."""
         out = {k: jax.tree_util.tree_map(
-                   lambda s: one_d if s.ndim == 1 else replicated, v)
+                   lambda s: one_d if s.ndim >= 1 else replicated, v)
                for k, v in shapes.items() if k != "residuals"}
         if "residuals" in shapes:
             out["residuals"] = [residual for _ in shapes["residuals"]]
         return out
 
-    def init_state(self, key) -> Dict[str, Any]:
-        """Init identical to ``init_params(cfg, key)`` then flatten + shard."""
-        shapes = jax.eval_shape(self._make_state, key)
-        out_sh = self._state_layout(
-            shapes, self._flat_sharding(), self._replicated(),
+    def _state_shardings(self, shapes):
+        return self._state_layout(
+            shapes, NamedSharding(self.mesh, P(self.axis_name)),
+            NamedSharding(self.mesh, P()),
             NamedSharding(self.mesh, P(self.axis_name, None)))
-        return jax.jit(self._make_state, out_shardings=out_sh)(key)
+
+    def state_structs(self) -> Dict[str, Any]:
+        """The state as sharded ``ShapeDtypeStruct``s (nothing allocated):
+        what a step is lowered against without a state."""
+        shapes = jax.eval_shape(self._make_state, jax.random.PRNGKey(0))
+        return jax.tree_util.tree_map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+            shapes, self._state_shardings(shapes))
+
+    def init_state(self, key) -> Dict[str, Any]:
+        """Init identical to ``init_params(cfg, key)``, laid out and
+        sharded."""
+        shapes = jax.eval_shape(self._make_state, key)
+        return jax.jit(self._make_state,
+                       out_shardings=self._state_shardings(shapes))(key)
 
     # ------------------------------------------------------------------
     # per-sched-layer applies (closed over cfg; used forward AND in VJPs)
@@ -202,7 +300,7 @@ class ZeroTrainer:
         return step
 
     def _local_step(self, state, batch):
-        Ls, kinds = self.num_layers, self._kinds
+        Ls, kinds, layout = self.num_layers, self._kinds, self._layout
         shards = list(state["flat_params"])
         res_local = state.get("residuals")     # local views: (1, padded_l)
         new_res = list(res_local) if res_local is not None else None
@@ -211,8 +309,7 @@ class ZeroTrainer:
         full: Dict[int, Any] = {}
         for i, bucket in enumerate(self.plan.forward):
             with jax.named_scope(f"zero.pull.b{i}"):
-                full.update(gather_bucket(shards, self.specs, bucket,
-                                          self.axis_name))
+                full.update(layout.pull(shards, bucket))
 
         # ---- forward, saving each layer's input activation --------------
         acts: Dict[int, jnp.ndarray] = {}
@@ -233,19 +330,19 @@ class ZeroTrainer:
         # The barrier keeps the re-gather a distinct program point from the
         # forward pull (so the forward copies are dead after their last
         # forward use and the re-gather cannot be folded into them).
+        # Holding leaves, the state is the full weights: nothing to re-pull.
         regathered: Dict[int, Any] = {}
-        if self.zero3:
+        if self.zero3 and layout.name == "flat":
             barred = list(jax.lax.optimization_barrier(tuple(shards)))
             for i, bucket in enumerate(self.plan.backward):
                 if any(0 < l < Ls - 1 for l in bucket):
                     with jax.named_scope(f"zero.regather.b{i}"):
-                        regathered.update(gather_bucket(
-                            barred, self.specs, bucket, self.axis_name))
+                        regathered.update(layout.pull(barred, bucket))
 
         # ---- backward: per-layer VJPs, one reduce-scatter per bucket ----
         one = jnp.ones((), jnp.float32)
         aux_ct = jnp.asarray(self.aux_weight, jnp.float32)
-        grad_shards: List[Optional[jnp.ndarray]] = [None] * Ls
+        grad_parts: List[Optional[List[jnp.ndarray]]] = [None] * Ls
         embed_from_head = None     # tied-head contribution to the embedding
         ct_h = None                # cotangent w.r.t. the current activation
         for i, bucket in enumerate(self.plan.backward):
@@ -275,27 +372,22 @@ class ZeroTrainer:
                         g_block, ct_h = vjp((ct_h, aux_ct))
                         bucket_grads[l] = g_block
             with jax.named_scope(f"zero.push.b{i}"):
-                if self.compressor is not None:
-                    res_in = ({l: res_local[l][0] for l in bucket}
-                              if res_local is not None else None)
-                    pushed, res_out = compressed_reduce_scatter_bucket(
-                        bucket_grads, self.specs, bucket, self.axis_name,
-                        self.compressor, residuals=res_in)
-                    if res_out is not None:
-                        for l, r in res_out.items():
-                            new_res[l] = r[None, :]
-                else:
-                    pushed = reduce_scatter_bucket(bucket_grads, self.specs,
-                                                   bucket, self.axis_name)
+                res_in = ({l: res_local[l][0] for l in bucket}
+                          if res_local is not None else None)
+                pushed, res_out = layout.push(bucket_grads, bucket, res_in)
+                if res_out is not None:
+                    for l, r in res_out.items():
+                        new_res[l] = r[None, :]
                 for l, g in pushed.items():
-                    grad_shards[l] = g / self.axis_size     # sum → mean
+                    grad_parts[l] = g
 
         # ---- sharded optimizer update (ZeRO: on local shards only) ------
+        grads = [g for part in grad_parts for g in part]
         with jax.named_scope("zero.opt"):
-            new_flats, new_opt = self.optimizer.update(
-                grad_shards, state["opt"], shards)
+            new_params, new_opt = self.optimizer.update(
+                grads, state["opt"], shards)
         loss = jax.lax.pmean(loss_local, self.axis_name)
-        new_state = {"flat_params": new_flats, "opt": new_opt,
+        new_state = {"flat_params": new_params, "opt": new_opt,
                      "step": state["step"] + 1}
         if new_res is not None:
             new_state["residuals"] = new_res
@@ -308,7 +400,5 @@ class ZeroTrainer:
     def params_from_state(self, state) -> Any:
         """Materialize the canonical (unsharded) param pytree from a state —
         checkpoint/eval interop, not part of the hot path."""
-        trees = []
-        for flat, spec in zip(state["flat_params"], self.specs):
-            trees.append(unflatten_tree(jnp.asarray(flat), spec))
-        return model_lib.params_from_sched_layers(trees)
+        return model_lib.params_from_sched_layers(
+            self._layout.to_trees(state["flat_params"]))

@@ -146,6 +146,20 @@ def config_from_flags(args) -> RuntimeConfig:
             error_feedback=not args.no_error_feedback))
 
 
+def _print_plan(rt) -> None:
+    """The ZeRO-driven regimes' plan and state layout, once at startup
+    (``leaves`` on a one-device axis with no compressor, else ``flat``)."""
+    trainer = getattr(rt, "trainer", None)
+    layout = getattr(getattr(trainer, "base", trainer), "layout", None)
+    if layout is None:
+        return
+    plan = rt.plan
+    segments = ("" if plan is None else
+                f"{len(plan.forward)} pull / {len(plan.backward)} push "
+                f"segments, ")
+    print(f"[plan] {segments}state layout {layout}")
+
+
 def _print_events(rt) -> None:
     for e in rt.events:
         if hasattr(e, "resharded"):          # fleet re-plan
@@ -343,6 +357,7 @@ def main() -> None:
                  f"M={config.pipeline.microbatches} "
                  f"({config.pipeline.schedule})")
     print(spec)
+    _print_plan(rt)
 
     t0 = time.perf_counter()
     losses = []

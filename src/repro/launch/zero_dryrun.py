@@ -45,17 +45,6 @@ def tpu_costs(arch: str, shape_name: str, data_axis: int) -> LayerCosts:
     return costs_from_profiles(profs, net=net)
 
 
-def state_structs(tr: ZeroTrainer):
-    sh = tr._flat_sharding()
-    flats = [S((spec.padded,), jnp.float32, sharding=sh) for spec in tr.specs]
-    opt_state = jax.eval_shape(tr.optimizer.init, flats)
-    opt_state = jax.tree_util.tree_map(
-        lambda x: S(x.shape, x.dtype, sharding=sh) if x.ndim == 1
-        else S(x.shape, x.dtype), opt_state)
-    return {"flat_params": flats, "opt": opt_state,
-            "step": S((), jnp.int32)}
-
-
 def steady_state_bound(costs: LayerCosts, decision) -> float:
     """Beyond-paper: double-buffered cross-iteration pipelining.
 
@@ -112,7 +101,7 @@ def main():
             tr = ZeroTrainer(cfg=cfg, mesh=mesh, plan=plan,
                              optimizer=adamw(1e-4))
             step = jax.jit(tr.build_train_step())
-            lowered = step.lower(state_structs(tr), batch_structs)
+            lowered = step.lower(tr.state_structs(), batch_structs)
             compiled = lowered.compile()
             hlo = compiled.as_text()
             coll = collective_bytes(hlo)

@@ -123,6 +123,11 @@ class PSTrainer:
     def params_from_state(self, state) -> Any:
         return self._zero.params_from_state(state)
 
+    @property
+    def layout(self) -> str:
+        """The contained ZeRO step's state layout (``"flat"``/``"leaves"``)."""
+        return self._zero.layout
+
     # ------------------------------------------------------------------
     # PS accounting: segments → shards, bytes → links
     # ------------------------------------------------------------------

@@ -287,8 +287,13 @@ class ZeroRuntime(_CompiledRuntime):
         return self.trainer.plan
 
     def step(self, batch) -> float:
+        # Hold the input state until the step has run (``old`` goes at
+        # return): dropped while the step runs, its buffers (one per leaf
+        # in a leaves state) are freed by the runtime after it, racing the
+        # next step's output allocations for a higher, varying peak.
+        old = self._state
         with jax.profiler.TraceAnnotation("repro.dispatch"):
-            self._state, loss = self._step_fn(self._state, batch)
+            self._state, loss = self._step_fn(old, batch)
         self._account(self.trainer.specs, self.trainer.plan,
                       self.trainer.axis_size)
         self._data_idx += 1

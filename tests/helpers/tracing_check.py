@@ -6,8 +6,9 @@ Forges ``<devices>`` host devices, then for the reduced dense and MoE
 configs (and the dense one under ZeRO-3) builds the ``zero`` runtime and
 reports, as one JSON line:
 
-* the plan (buckets, sched layers) and every ``zero.*`` / ``moe.*`` name
-  component found in the compiled step's ``op_name`` metadata;
+* the plan (buckets, sched layers), the state layout, and every
+  ``zero.*`` / ``moe.*`` name component found in the compiled step's
+  ``op_name`` metadata;
 * the non-trivial instructions of the entry computation, as JAX hands the
   step to XLA, whose ``op_name`` carries no ``zero.*`` scope;
 * two steps' losses, and the same with ``jax.named_scope`` stubbed out,
@@ -108,6 +109,7 @@ def check(arch, zero3=False, trace=False):
     out = {"forward": [list(b) for b in rt.plan.forward],
            "backward": [list(b) for b in rt.plan.backward],
            "sched_layers": rt.trainer.num_layers,
+           "layout": rt.trainer.layout,
            "scopes": scopes(compiled),
            "unscoped": [[op, name] for op, name in entry_instructions(lowered)
                         if op not in TRIVIAL and "zero." not in name],

@@ -7,7 +7,11 @@ one JSON line the parent asserts on.  Checks:
    #reduce-scatters == |D_b| buckets in the compiled HLO, per strategy;
 2. "accuracy untouched" (paper Fig. 10, strengthened): losses are
    bit-identical across sequential / LBL / iBatch / DynaComm schedules;
-3. ZeRO trainer vs single-device reference: same losses to fp32 roundoff.
+3. ZeRO trainer vs single-device reference: same losses to fp32 roundoff;
+4. the state layout follows the mesh and the compressor (``flat`` on four
+   devices or with a compressor, ``leaves`` on one device without), and a
+   one-device trainer on the same global batch gives the four-device
+   losses to fp32 roundoff.
 """
 
 import os
@@ -22,6 +26,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from repro.analysis import collective_counts
+from repro.compress import make_compressor
 from repro.configs import get_config
 from repro.core import plan_from_decision, random_costs, schedule
 from repro.dist.zero import ZeroTrainer
@@ -79,6 +84,23 @@ def main():
         "ag": collective_counts(hlo3)["all-gather"],
         "expected_ag": len(plan.forward) + mid_buckets,
     }
+
+    # the layout follows the mesh and the compressor; one device (leaves)
+    # follows the four-device (flat) trajectory
+    one = Mesh(np.array(jax.devices()[:1]), ("data",))
+    tr1 = ZeroTrainer(cfg=cfg, mesh=one, plan=plan, optimizer=adamw(1e-3))
+    state1 = tr1.init_state(jax.random.PRNGKey(0))
+    step1 = jax.jit(tr1.build_train_step())
+    losses1 = []
+    for _ in range(3):
+        state1, loss = step1(state1, batch)
+        losses1.append(float(loss))
+    out["layouts"] = {
+        "4dev": tr3.layout, "1dev": tr1.layout,
+        "1dev_int8": ZeroTrainer(cfg=cfg, mesh=one, plan=plan,
+                                 optimizer=adamw(1e-3),
+                                 compressor=make_compressor("int8")).layout}
+    out["one_device_losses"] = losses1
 
     # single-device reference
     params = init_params(cfg, jax.random.PRNGKey(0))

@@ -135,6 +135,77 @@ class TestShardingRules:
         jax.tree_util.tree_map_with_path(rule, shapes)
 
 
+class TestOneDeviceStateLayout:
+    """On a one-device data axis with no compressor the ZeRO state is the
+    parameter leaves (no wire, so no pack or unpack); a compressor keeps
+    the flat wire format there."""
+
+    B1 = 0.9
+
+    def _trainer(self, compress):
+        from jax.sharding import Mesh
+        from repro.compress import make_compressor
+        from repro.core import plan_from_decision, random_costs, schedule
+        from repro.dist.zero import ZeroTrainer
+        from repro.models import num_sched_layers
+        from repro.optim import adamw
+        cfg = get_config("granite-3-2b").reduced()
+        Ls = num_sched_layers(cfg)
+        plan = plan_from_decision(
+            *schedule(random_costs(Ls, seed=0, dt=1e-3), "dynacomm"), Ls)
+        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+        compressor = None if compress is None else make_compressor(compress)
+        return cfg, ZeroTrainer(cfg=cfg, mesh=mesh, plan=plan,
+                                optimizer=adamw(1e-3, b1=self.B1),
+                                compressor=compressor)
+
+    @pytest.mark.parametrize("compress,layout",
+                             [(None, "leaves"), ("int8", "flat")])
+    def test_params_from_state_is_init_params(self, compress, layout):
+        cfg, tr = self._trainer(compress)
+        assert tr.layout == layout
+        key = jax.random.PRNGKey(7)
+        state = tr.init_state(key)
+        # init_state draws the weights inside jit, which may round
+        # differently from eager dispatch: compare like with like
+        want = jax.jit(lambda k: init_params(cfg, k))(key)
+        got = tr.params_from_state(state)
+        assert jax.tree_util.tree_structure(got) == \
+            jax.tree_util.tree_structure(want)
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        # the benchmark's reading of the first moment as a parameter tree
+        mu = state["opt"].mu
+        moment = tr.params_from_state(
+            {"flat_params": [m / (1.0 - self.B1) for m in mu]})
+        assert jax.tree_util.tree_structure(moment) == \
+            jax.tree_util.tree_structure(want)
+        assert [x.shape for x in jax.tree_util.tree_leaves(moment)] == \
+            [x.shape for x in jax.tree_util.tree_leaves(want)]
+
+    @pytest.mark.parametrize("compress,packs",
+                             [(None, False), ("int8", True)])
+    def test_one_device_step_packs_only_with_a_wire(self, compress, packs):
+        """No 1-D f32 value as large as a sched layer's flat buffer is left
+        in the compiled leaves step (the flat one is the control)."""
+        import re
+        cfg, tr = self._trainer(compress)
+        toks = jax.random.randint(jax.random.PRNGKey(3), (2, 16), 0,
+                                  cfg.vocab_size)
+        batch = {"tokens": toks, "labels": jnp.roll(toks, -1, axis=1)}
+        state = tr.init_state(jax.random.PRNGKey(0))
+        text = jax.jit(tr.build_train_step()).lower(
+            state, batch).compile().as_text()
+        # a layer whose flat buffer is its one 1-D leaf is no pack
+        smallest = min(s.padded for s in tr.specs
+                       if s.num_leaves > 1 or len(s.shapes[0]) > 1)
+        big = sorted({int(n) for n in re.findall(r"\bf32\[(\d+)\]", text)
+                      if int(n) >= smallest})
+        assert bool(big) == packs, (tr.layout, smallest, big)
+
+
 @pytest.mark.slow
 class TestZeroTrainerMultiDevice:
     @pytest.fixture(scope="class")
@@ -163,6 +234,17 @@ class TestZeroTrainerMultiDevice:
         ref = result["reference_losses"]
         dyn = result["strategies"]["dynacomm"]["losses"]
         np.testing.assert_allclose(dyn, ref, rtol=2e-5)
+
+    def test_layout_follows_mesh_and_compressor(self, result):
+        assert result["layouts"] == {"4dev": "flat", "1dev": "leaves",
+                                     "1dev_int8": "flat"}
+
+    def test_one_device_leaves_match_four_devices(self, result):
+        """The leaves layout on one device trains as the flat wire on four
+        does, on the same global batch."""
+        np.testing.assert_allclose(
+            result["one_device_losses"],
+            result["strategies"]["dynacomm"]["losses"], rtol=2e-5)
 
     def test_bucket_structure_differs(self, result):
         s = result["strategies"]
