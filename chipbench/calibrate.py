@@ -35,11 +35,13 @@ import os
 import sys
 import time
 
+import jax
+
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from chipbench import check, data, run  # noqa: E402
+from chipbench import check, data, flops, run  # noqa: E402
 
 
 def seed_list(text: str):
@@ -51,6 +53,42 @@ def reference_readings(result, ref):
     return {"losses": result["losses"], "grad_norms": result["grad_norms"],
             "change_norms": check.diff_norms(result["params"],
                                              ref["params0"])}
+
+
+def sound_reference(model, cell, seed32, tokens, labels):
+    """The reference's checked steps with their change norms; its initial
+    parameters are kept on the host and its final ones dropped, so that a
+    fault's reference fits on the device beside it."""
+    ref = check.reference_steps(model, cell.config, cell.settings["optimizer"],
+                                seed32, tokens, labels, cell.chips)
+    ref["change_norms"] = check.diff_norms(ref["params"], ref["params0"])
+    ref["params0"] = jax.device_get(ref["params0"])
+    del ref["params"]
+    return ref
+
+
+def fault_readings(model, cell, seed32, tokens, labels, ref):
+    """``(kind, numbers, seconds)`` of each fault's reference against the
+    sound one, ``ref``; ``tokens``/``labels`` are the checked steps'."""
+    size = tokens.shape[1] // cell.chips
+    faults = {
+        "control": dict(precision="fp8"),
+        "half_batch": dict(rows=lambda n: slice(0, n // 2)),
+    }
+    if cell.chips > 1:
+        faults["no_exchange"] = dict(blocks=1)
+    for kind, kw in faults.items():
+        t0 = time.perf_counter()
+        blocks = kw.pop("blocks", cell.chips)
+        t, l = (tokens, labels) if blocks == cell.chips else \
+            (tokens[:, :size], labels[:, :size])
+        result = check.reference_steps(model, cell.config,
+                                       cell.settings["optimizer"], seed32,
+                                       t, l, blocks, **kw)
+        values = check.numbers(reference_readings(result, ref), ref)
+        del result
+        gc.collect()
+        yield kind, values, time.perf_counter() - t0
 
 
 def calibrate(argv=None, *, root: str = run.ROOT, require_tpu: bool = True):
@@ -66,9 +104,7 @@ def calibrate(argv=None, *, root: str = run.ROOT, require_tpu: bool = True):
     run.log(f"[cache] {run.use_compile_cache(root)}")
     devices = run.check_devices(cell.chips, require_tpu, cell.peaks)
     arch = run.arch_config(cell.config)
-    model = run._load_module(
-        os.path.join(root, "chipbench", "reference",
-                     cell.config["reference"] + ".py"), "reference")
+    model = flops.reference(cell.config["reference"])
     opt = cell.settings["optimizer"]
     steps = check.CHECK_STEPS
 
@@ -91,35 +127,15 @@ def calibrate(argv=None, *, root: str = run.ROOT, require_tpu: bool = True):
             del rt, batches
             gc.collect()
             tokens, labels = tokens[:steps], labels[:steps]
-            ref = check.reference_steps(model, cell.config, opt, seed32,
-                                        tokens, labels, cell.chips)
-            ref["change_norms"] = check.diff_norms(ref["params"],
-                                                   ref["params0"])
+            ref = sound_reference(model, cell, seed32, tokens, labels)
             program["change_norms"] = check.change_norms(
                 program["final"](), ref)
             record(seed, "program", check.numbers(program, ref),
                    time.perf_counter() - t0)
-            if i >= args.faults:
-                continue
-            size = tokens.shape[1] // cell.chips
-            faults = {
-                "control": dict(precision="fp8"),
-                "half_batch": dict(rows=lambda n: slice(0, n // 2)),
-            }
-            if cell.chips > 1:
-                faults["no_exchange"] = dict(blocks=1)
-            for kind, kw in faults.items():
-                t0 = time.perf_counter()
-                blocks = kw.pop("blocks", cell.chips)
-                t, l = (tokens, labels) if blocks == cell.chips else \
-                    (tokens[:, :size], labels[:, :size])
-                result = check.reference_steps(model, cell.config, opt,
-                                               seed32, t, l, blocks, **kw)
-                record(seed, kind, check.numbers(
-                    reference_readings(result, ref), ref),
-                    time.perf_counter() - t0)
-                del result
-                gc.collect()
+            if i < args.faults:
+                for kind, values, seconds in fault_readings(
+                        model, cell, seed32, tokens, labels, ref):
+                    record(seed, kind, values, seconds)
             del ref
             gc.collect()
 

@@ -18,8 +18,9 @@ batches.  Three numbers are compared, each against the cell's limit:
 
 The reference runs data parallel as the program does: each chip's rows
 form a block, the loss and gradient are the means over the blocks.  It
-runs after the program's state is freed, each block on a device of its
-own where there are enough, summed on the first.
+runs after the program's state is freed, the blocks in turn on the first
+device, so that every cell's reference is one program (one entry of the
+compile cache, shared by the cells of a configuration).
 """
 
 from __future__ import annotations
@@ -102,30 +103,18 @@ def reference_steps(model, cfg: Dict, opt: Dict, seed: int, tokens, labels,
     batch = tokens.shape[1]
     size = batch // blocks
     keep = rows(size) if rows is not None else slice(0, size)
-    # one block of rows to a device while there are devices: the blocks'
-    # gradients are computed at once and summed on the first device
-    devices = jax.local_devices()
-    devices = devices[:blocks] if len(devices) >= blocks else devices[:1]
-    home = devices[0]
+    home = jax.local_devices()[0]
+    add = jax.jit(lambda a, b: jax.tree_util.tree_map(jnp.add, a, b))
     for step in range(CHECK_STEPS):
-        copies = [params] + [jax.device_put(params, d) for d in devices[1:]]
-        outs = []
+        loss, grads = 0.0, None
         for b in range(blocks):
-            d = b % len(devices)
             sl = slice(b * size, (b + 1) * size)
-            outs.append(grad_fn(copies[d],
-                                jax.device_put(tokens[step, sl][keep],
-                                               devices[d]),
-                                jax.device_put(labels[step, sl][keep],
-                                               devices[d])))
-        del copies
-        losses.append(sum(float(v) for v, _ in outs) / blocks)
-        grads = None
-        for _, g in outs:
-            g = jax.device_put(g, home)
-            grads = g if grads is None else jax.tree_util.tree_map(
-                jnp.add, grads, g)
-        del outs
+            value, g = grad_fn(params,
+                               jax.device_put(tokens[step, sl][keep], home),
+                               jax.device_put(labels[step, sl][keep], home))
+            loss += float(value)
+            grads = g if grads is None else add(grads, g)
+        losses.append(loss / blocks)
         grads = jax.tree_util.tree_map(lambda g: g / blocks, grads)
         if first is None:
             first = leaf_norms(grads)

@@ -11,6 +11,8 @@ One general generator reads a traffic file (``traffic/<mix>.json``):
   so a model can learn the mapping;
 * ``ring``: how many distinct batches are drawn; step ``i`` trains on
   batch ``i % ring``.
+* ``chips`` (optional): the chips the mix is drawn for; a cell on
+  another number of chips is refused.
 
 The distribution and the label rule are those of the program's
 ``SyntheticText`` stream; the whole ring is drawn at once, by inverse-CDF

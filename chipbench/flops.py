@@ -1,55 +1,61 @@
 """Operations one training step requires, computed from the shapes.
 
 Read from a configuration file's published keys, not from the program.
-A training step is a forward and a backward pass: 2 operations per
-multiply-accumulate forward and 4 backward, so ``6 * N_active`` per token
-for the weights, where ``N_active`` counts each weight a token multiplies:
+Each architecture brings its own count: the file's ``reference`` names
+``reference/<name>.py``, whose ``train_flops_per_step(cfg, batch, seq)``
+this module calls.  The conventions every count keeps:
 
-* attention projections ``d*q + 2*d*kv + q*d`` per layer;
-* a gated MLP ``3*d*ff`` per layer, or for sparse experts the router
-  ``d*E`` plus ``top_k * 3*d*f``: the experts a token is routed to, not
-  the capacity slots a program may fill;
-* the output head ``d*V`` once (a tied table is counted as the head; the
-  embedding lookup is a gather and costs no operations).
+* a training step is a forward and a backward pass: 2 operations per
+  multiply-accumulate forward and 4 backward, so ``TRAIN_OPS_PER_WEIGHT``
+  (6) per weight a token multiplies;
+* a token's experts are counted where they are held: where the file
+  holds ``held`` of a layer's ``E`` routed experts (the chip's share of a
+  deployment), a token multiplies ``top_k * held / E`` experts here, not
+  the capacity slots a program may fill; a router keeps its ``E`` outputs;
+* the output head is counted once, tied or untied; an embedding lookup
+  is a gather and costs no operations;
+* attention scores add, per layer and sequence, ``Q K^T`` and ``P V``
+  over the causal pairs (:func:`score_flops`), three times over for
+  forward and backward.
 
-Attention scores add, per layer and sequence, ``2*q*T*(T+1)/2`` for
-``Q K^T`` and as much for ``P V`` under the causal mask, three times over
-for forward and backward.  Norms, softmax and the optimizer are left out,
-as is any forward that a program recomputes.
+Norms, softmax and the optimizer are left out, as is any forward that a
+program recomputes.
 """
 
 from __future__ import annotations
 
+import importlib.util
+import os
 from typing import Dict
 
-
-def _dims(cfg: Dict):
-    d = int(cfg["hidden_size"])
-    heads = int(cfg["num_attention_heads"])
-    head_dim = int(cfg.get("head_dim") or d // heads)
-    return d, heads * head_dim, int(cfg["num_key_value_heads"]) * head_dim
+HERE = os.path.dirname(os.path.abspath(__file__))
+#: operations a weight costs per token in a forward and a backward pass
+TRAIN_OPS_PER_WEIGHT = 6
 
 
-def active_params_per_token(cfg: Dict) -> int:
-    d, q, kv = _dims(cfg)
-    per_layer = d * q + 2 * d * kv + q * d
-    experts = int(cfg.get("num_local_experts") or 0)
-    if experts:
-        top_k = int(cfg["num_experts_per_tok"])
-        per_layer += d * experts + top_k * 3 * d * int(cfg["intermediate_size"])
-    else:
-        per_layer += 3 * d * int(cfg["intermediate_size"])
-    return int(cfg["num_hidden_layers"]) * per_layer \
-        + d * int(cfg["vocab_size"])
+def score_flops(qk_width: int, v_width: int, seq: int,
+                causal: bool = True) -> int:
+    """Forward and backward operations of one layer's attention scores
+    over one sequence: ``Q K^T`` contracts ``qk_width`` (heads times the
+    query-key head size) and ``P V`` makes ``v_width``, each over the
+    ``T (T + 1) / 2`` causal pairs, or all ``T * T`` where not causal."""
+    pairs = seq * (seq + 1) // 2 if causal else seq * seq
+    forward = 2 * (qk_width + v_width) * pairs
+    return 3 * forward
 
 
-def attention_flops_per_sequence(cfg: Dict, seq: int) -> int:
-    """Forward and backward score operations of one causal sequence."""
-    _, q, _ = _dims(cfg)
-    forward = 2 * (2 * q * seq * (seq + 1) // 2)
-    return 3 * forward * int(cfg["num_hidden_layers"])
+def reference(name: str):
+    """The reference module ``reference/<name>.py``."""
+    path = os.path.join(HERE, "reference", name + ".py")
+    spec = importlib.util.spec_from_file_location(
+        "chipbench_reference_" + name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
-def train_flops_per_step(cfg: Dict, batch: int, seq: int) -> int:
-    return (6 * active_params_per_token(cfg) * batch * seq
-            + batch * attention_flops_per_sequence(cfg, seq))
+def train_flops_per_step(config: Dict, batch: int, seq: int) -> int:
+    """Operations of one step of ``batch`` rows of ``seq`` tokens, by the
+    count of the reference that the configuration file names."""
+    return int(reference(config["reference"]).train_flops_per_step(
+        config, batch, seq))

@@ -1,4 +1,5 @@
-"""Collectives in a compiled step's HLO text, with their operand bytes.
+"""Collectives in a compiled step's HLO text, with their operand bytes,
+and each instruction's scope path.
 
 A copy of the parsing rules of the program's ``repro.analysis.hlo``, kept
 here so that no later change to the program can change how the benchmark
@@ -19,7 +20,7 @@ all-reduce of every step).
 from __future__ import annotations
 
 import re
-from typing import Dict, List
+from typing import Dict, List, Optional
 
 DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -34,6 +35,12 @@ _DEF = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*")
 _OPCODE = re.compile(r"\s+([\w\-]+)\((.*)$")
 _LEAF = re.compile(r"\b([a-z0-9]+)\[([\d,]*)\]")
 _NAME = re.compile(r"^%?([\w.\-]+)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?(%[\w.\-]+)\s.*\{\s*$")
+_REF = re.compile(r"%[\w.\-]+")
+_CALLED = re.compile(r"\b(?:calls|body|condition|to_apply|branch_computations|"
+                     r"true_computation|false_computation)=\{?"
+                     r"(%[\w.\-]+(?:\s*,\s*%[\w.\-]+)*)")
+_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
 
 
 def type_bytes(type_str: str) -> int:
@@ -124,3 +131,52 @@ def pulls_and_pushes(text: str) -> Dict[str, int]:
             "pushes": len(pushes), "push_bytes": sum(pushes),
             "small_all_reduces": sum(b <= SMALL_BYTES
                                      for b in c["all-reduce"])}
+
+
+def op_names(text: str) -> Dict[str, str]:
+    """The scope path of each instruction (``%name``) of an HLO module's
+    text: the ``op_name`` of its metadata.  The compiler drops the
+    metadata on some instructions it makes (a reduce-scatter rewritten as
+    an all-reduce, a relayout loop, a copy); such an instruction takes the
+    path of the first of its operands that has one, or else that of the
+    instruction that calls its computation (a loop's body that of the
+    loop).  Instructions are listed after their operands, as HLO text is."""
+    own: Dict[str, Optional[str]] = {}
+    home: Dict[str, str] = {}            # instruction -> its computation
+    callers: Dict[str, str] = {}         # computation -> first caller
+    operands: Dict[str, List[str]] = {}
+    computation = ""
+    for line in text.splitlines():
+        c = _COMPUTATION.match(line)
+        if c:
+            computation = c.group(1)
+            continue
+        d = _DEF.match(line)
+        if not d:
+            continue
+        name, rhs = "%" + d.group(1), line[d.end():]
+        rest = rhs[_matching(rhs, 1) + 1:] if rhs.startswith("(") \
+            else rhs.partition(" ")[2]          # past the result type
+        m = _OPCODE.match(" " + rest)
+        args = m.group(2)[:_matching(m.group(2), 0)] if m else ""
+        meta = _OP_NAME.search(rest)
+        own[name] = meta.group(1) if meta else None
+        home[name] = computation
+        operands[name] = _REF.findall(args)
+        for called in _CALLED.findall(rest):
+            for callee in _REF.findall(called):
+                callers.setdefault(callee, name)
+    from_operands: Dict[str, Optional[str]] = {}
+    for name in own:
+        from_operands[name] = own[name] or next(
+            (from_operands[o] for o in operands[name]
+             if from_operands.get(o)), None)
+
+    def resolved(name: str, seen=()) -> Optional[str]:
+        if from_operands[name] or name in seen:
+            return from_operands[name]
+        caller = callers.get(home[name])
+        return resolved(caller, seen + (name,)) if caller else None
+
+    return {name: path for name in own
+            for path in [resolved(name)] if path}

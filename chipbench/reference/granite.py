@@ -31,16 +31,22 @@ documented initialisation, reproduced here and not taken from it.
 
 Every matrix product goes through :func:`matmul`, whose precision is the
 reference's (``highest``: float32 products) or a control's.
+
+:func:`train_flops_per_step` is the architecture's operation count, by
+the conventions of ``chipbench/flops.py``.
 """
 
 from __future__ import annotations
 
+from fractions import Fraction
 from functools import partial
 from typing import Dict
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from chipbench import flops
 
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
@@ -243,3 +249,40 @@ def loss(cfg: Dict, params, tokens, labels, precision: str = "highest"):
     picked = jnp.take_along_axis(logits, labels[..., None], -1)[..., 0]
     return jnp.mean(lse - picked) \
         + float(cfg.get("router_aux_loss_coef", 0.0)) * balance
+
+
+# ---------------------------------------------------------------------------
+# the operation count
+# ---------------------------------------------------------------------------
+
+
+def active_params_per_token(cfg: Dict) -> Fraction:
+    """Weights a token multiplies: per layer the projections
+    ``d*q + 2*d*kv + q*d`` and a SwiGLU ``3*d*ff``, or for routed experts
+    the router ``d*E`` and ``top_k * held / E`` experts of ``3*d*ff``
+    (``held``: the file's ``num_local_experts``, ``E``: its published
+    count); the head ``d*V`` once."""
+    d, heads, kv_heads, hd = _dims(cfg)
+    q, kv = heads * hd, kv_heads * hd
+    ff = int(cfg["intermediate_size"])
+    per_layer = Fraction(d * q + 2 * d * kv + q * d)
+    held = int(cfg.get("num_local_experts") or 0)
+    if held:
+        experts = int(cfg.get("published", {}).get("num_local_experts", held))
+        top_k = int(cfg["num_experts_per_tok"])
+        per_layer += d * experts + Fraction(top_k * held, experts) * 3 * d * ff
+    else:
+        per_layer += 3 * d * ff
+    return int(cfg["num_hidden_layers"]) * per_layer \
+        + d * int(cfg["vocab_size"])
+
+
+def train_flops_per_step(cfg: Dict, batch: int, seq: int,
+                         causal: bool = True) -> int:
+    """Operations of one training step; ``causal=False`` counts the
+    scores over the whole square, as :func:`loss` computes them."""
+    _, heads, _, hd = _dims(cfg)
+    scores = flops.score_flops(heads * hd, heads * hd, seq, causal) \
+        * int(cfg["num_hidden_layers"])
+    return int(flops.TRAIN_OPS_PER_WEIGHT * active_params_per_token(cfg)
+               * batch * seq) + batch * scores

@@ -17,8 +17,10 @@ and the plain reference follows the checked steps to decide ``correct``.
 With ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` the window runs under the profiler and the result carries
 the cell's per-layer metrics, the device's busy and window seconds, and a
-breakdown.  The last line of standard output is the result as JSON; the
-last lines of standard error give each compared number beside its limit.
+breakdown; the trace's operations take their scopes from the compiled
+step's text, read in set-up.  The last line of standard output is the
+result as JSON; the last lines of standard error give each compared
+number beside its limit.
 
 Exits non-zero, printing no result, when JAX finds no TPU, fewer or more
 chips than the cell asks for, or no program beside the benchmark.
@@ -88,6 +90,10 @@ def load_cell(root: str, name: str):
     traffic = _read_json(os.path.join(here, "traffic",
                                       work["traffic"] + ".json"))
     settings = _read_json(os.path.join(here, "cells", name + ".json"))
+    if int(traffic.get("chips", work["chips"])) != int(work["chips"]):
+        raise Refused(f"traffic {work['traffic']!r} is drawn for "
+                      f"{traffic['chips']} chips, the cell asks for "
+                      f"{work['chips']}")
 
     def applies(metric):
         return name in metric.get("workloads", [name])
@@ -131,13 +137,15 @@ def read_metrics(root: str, metrics, run):
 def use_compile_cache(root: str) -> str:
     """JAX's persistent compile cache, every program kept: the directory
     that ``JAX_COMPILATION_CACHE_DIR`` names, else a fixed path in the
-    checkout."""
+    checkout.  The key takes in the programs' metadata, so that a program
+    loaded from the cache carries the scopes of the code that runs."""
     import jax
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or \
         os.path.join(root, WORK_DIR, "jax_cache")
     jax.config.update("jax_compilation_cache_dir", path)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
 
 
@@ -250,11 +258,11 @@ def checked_steps(rt, b1: float):
             "final": lambda: tree({"flat_params": flats})}
 
 
-def plan_lines(rt, batch):
+def plan_lines(rt, step_text):
     plan = rt.plan
     log(f"[plan] {len(plan.forward)} pull / {len(plan.backward)} push "
         f"segments, forward {plan.forward}, backward {plan.backward}")
-    counts = hlo.pulls_and_pushes(rt.compiled_step_text(batch))
+    counts = hlo.pulls_and_pushes(step_text)
     log(f"[hlo] compiled per step: {counts['pulls']} pulls "
         f"({counts['pull_bytes']} B), {counts['pushes']} pushes "
         f"({counts['push_bytes']} B), {counts['small_all_reduces']} "
@@ -262,10 +270,8 @@ def plan_lines(rt, batch):
     return counts
 
 
-def reference_check(root, cell, seed, tokens, labels, program):
-    model = _load_module(os.path.join(root, "chipbench", "reference",
-                                      cell.config["reference"] + ".py"),
-                         "chipbench_reference_" + cell.config["reference"])
+def reference_check(cell, seed, tokens, labels, program):
+    model = flops.reference(cell.config["reference"])
     ref = check.reference_steps(model, cell.config, cell.settings["optimizer"],
                                 seed, tokens[:check.CHECK_STEPS],
                                 labels[:check.CHECK_STEPS], cell.chips)
@@ -323,7 +329,8 @@ def main(argv=None, *, root: str = ROOT, require_tpu: bool = True,
     program = checked_steps(rt, float(cell.settings["optimizer"]["b1"]))
     log(f"[check] program losses {program['losses']}")
     phase("checked steps run")
-    plan_lines(rt, batches[0])
+    step_text = rt.compiled_step_text(batches[0])
+    plan_lines(rt, step_text)
     phase("compiled step read")
     tokens_per_step = tokens.shape[1] * tokens.shape[2]
 
@@ -384,7 +391,7 @@ def main(argv=None, *, root: str = ROOT, require_tpu: bool = True,
     del rt, batches
     gc.collect()
     t_ref = time.perf_counter()
-    values = reference_check(root, cell, seed32, tokens, labels, program)
+    values = reference_check(cell, seed32, tokens, labels, program)
     log(f"[reference] {time.perf_counter() - t_ref!r} s")
     limits = {k: float(v) for k, v in cell.settings["limits"].items()}
     failed = sum(not math.isfinite(x) for x in losses)
@@ -402,7 +409,8 @@ def main(argv=None, *, root: str = ROOT, require_tpu: bool = True,
               "count": len(devices), "memory_peak_bytes": peak}
     result = {"correct": correct, "attempted": len(walls), "failed": failed}
     if args.trace:
-        run.trace = trace_lib.load(trace_lib.find_xplane(trace_dir))
+        run.trace = trace_lib.load(trace_lib.find_xplane(trace_dir),
+                                   step_text)
         shutil.rmtree(trace_dir, ignore_errors=True)
         result["metrics"] = read_metrics(root, cell.per_layer, run)
         busy = [trace_lib.length(trace_lib.busy(run.trace, d))

@@ -2,10 +2,10 @@
 
 What the chip's compiler refuses, it refuses here at no chip time: the
 ZeRO ``dynacomm`` step of ``granite-3-2b.l4`` under the
-``zipf-8x512-per-chip`` traffic on four chips (global batch 32 x 512, the
+``zipf-32x512-dp4`` traffic on four chips (global batch 32 x 512, the
 plan its runtime draws), given the described devices as its mesh and
-shapes as its state.  This is the step of the four-chip cell that
-``PERF.md`` keeps for later.  Compiling takes one to two minutes.
+shapes as its state.  This is the step of the cell
+``granite-3-2b.l4.zero.4chip``.  Compiling takes one to two minutes.
 """
 
 import json
@@ -44,7 +44,7 @@ def test_four_chip_step_compiles_for_v5e(topo):
 
     with open(os.path.join(BENCH, "configs", "granite-3-2b.l4.json")) as f:
         arch = run.arch_config(json.load(f))
-    with open(os.path.join(BENCH, "traffic", "zipf-8x512-per-chip.json")) as f:
+    with open(os.path.join(BENCH, "traffic", "zipf-32x512-dp4.json")) as f:
         traffic = json.load(f)
     batch = data.global_batch(traffic, CHIPS)
     seq = int(traffic["seq"])

@@ -88,6 +88,26 @@ def test_refuses_another_chip_count(root, capsys):
     assert "{" not in capsys.readouterr().out
 
 
+def test_each_pair_of_config_and_traffic_once():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        work = json.load(f)["workloads"]
+    pairs = [(w["config"], w["traffic"]) for w in work]
+    assert len(set(pairs)) == len(pairs)
+
+
+def test_refuses_traffic_drawn_for_other_chips(tmp_path):
+    root = make_root(str(tmp_path))
+    assert run.load_cell(root, "tiny-dense.4chip").traffic["chips"] == 4
+    path = os.path.join(root, "chipbench", "traffic",
+                        "tiny-2x16-per-chip.json")
+    with open(path) as f:
+        traffic = json.load(f)
+    with open(path, "w") as f:
+        json.dump(dict(traffic, chips=4), f)
+    with pytest.raises(run.Refused, match="drawn for 4 chips"):
+        run.load_cell(root, "tiny-dense.1chip")
+
+
 def test_command_fails_without_chip_or_program(tmp_path):
     """The command as the benchmark names it: on this CPU it exits
     non-zero and prints no result, and so it does in a tree that holds

@@ -128,3 +128,23 @@ def test_load_keeps_the_program_spans_of_an_xplane(tmp_path):
                                940.0)]]
     run = types.SimpleNamespace(trace=trace)
     assert read("dispatch_ms", run) == pytest.approx(40e-6)
+
+
+def test_load_joins_the_compiled_steps_scopes(tmp_path):
+    from jax.profiler import ProfileData
+    path = tmp_path / "host.xplane.pb"
+    path.write_bytes(ProfileData.text_proto_to_serialized_xspace(XSPACE))
+    step_text = (
+        '  %fusion.1 = f32[8]{0} fusion(f32[8]{0} %p), kind=kLoop, '
+        'calls=%fc.1, metadata={op_type="exp" '
+        'op_name="jit(step)/zero.fwd.L1/exp" stack_frame_id=2}\n'
+        '  ROOT %fusion.7 = f32[8]{0} fusion(f32[8]{0} %fusion.1), '
+        'kind=kLoop, calls=%fc.2, metadata={op_name="jit(step)/zero.opt"}\n')
+    assert trace_lib.load(str(path)).scopes == {}
+    trace = trace_lib.load(str(path), step_text)
+    # only the operations the trace holds
+    assert trace.scopes == {"%fusion.1": "jit(step)/zero.fwd.L1/exp"}
+    run = types.SimpleNamespace(trace=trace)
+    # device 0 runs %fusion.1 over 720 ns in 2 steps
+    assert read("fwd_ms", run) == pytest.approx(360e-6)
+    assert read("optimizer_ms", run) is None

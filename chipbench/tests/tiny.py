@@ -24,11 +24,14 @@ TINY_RUN = {"embedding_multiplier": 8.0, "attention_multiplier": 0.25}
 TINY_ARCH = {"num_layers": 2, "d_model": 64, "num_heads": 4,
              "num_kv_heads": 2, "head_dim": 16, "vocab_size": 256}
 CELLS = {"tiny-dense.1chip": ("tiny-dense", "tiny-2x16-per-chip", 1),
-         "tiny-dense.4chip": ("tiny-dense", "tiny-2x16-per-chip", 4),
-         "tiny-moe.4chip": ("tiny-moe", "tiny-2x16-per-chip", 4)}
+         "tiny-dense.4chip": ("tiny-dense", "tiny-8x16-dp4", 4),
+         "tiny-moe.4chip": ("tiny-moe", "tiny-8x16-dp4", 4)}
+#: each tiny mix shrinks the real mix it stands for
+TRAFFIC_OF = {"tiny-2x16-per-chip": "zipf-8x512-per-chip",
+              "tiny-8x16-dp4": "zipf-32x512-dp4"}
 #: each tiny cell checks against the limits of the real cell it stands for
 LIMITS_OF = {"tiny-dense.1chip": "granite-3-2b.l4.zero.1chip",
-             "tiny-dense.4chip": "granite-3-2b.l4.zero.1chip",
+             "tiny-dense.4chip": "granite-3-2b.l4.zero.4chip",
              "tiny-moe.4chip": "granite-moe-1b-a400m.l4.zero.1chip"}
 
 
@@ -63,11 +66,10 @@ def make_root(dest: str) -> str:
             cfg["departures"][key]["run"] = value
         _dump(cfg, os.path.join(here, "configs", name + ".json"))
 
-    traffic = _load(os.path.join(here, "traffic",
-                                 "zipf-8x512-per-chip.json"))
-    traffic.update(name="tiny-2x16-per-chip", batch_per_chip=2, seq=16,
-                   ring=4)
-    _dump(traffic, os.path.join(here, "traffic", "tiny-2x16-per-chip.json"))
+    for tiny, real in TRAFFIC_OF.items():
+        traffic = _load(os.path.join(here, "traffic", real + ".json"))
+        traffic.update(name=tiny, batch_per_chip=2, seq=16, ring=4)
+        _dump(traffic, os.path.join(here, "traffic", tiny + ".json"))
 
     bench["configs"] = [
         dict(bench["configs"][0], name="tiny-dense",

@@ -11,22 +11,31 @@
   asynchronous operation (a copy, a collective) is one event from its
   start to its done;
 * ``host``: the host's named spans on the thread that drives the steps,
-  ``(name, start_ns, end_ns)``.
+  ``(name, start_ns, end_ns)``;
+* ``scopes``: for each operation's HLO name (``%fusion.213``), its scope
+  path, the ``op_name`` of the instruction's metadata in the compiled
+  step's text (``jit(step)/shard_map/zero.bwd.L1/jvp(moe.dispatch)/lt``).
+  The trace's own events carry HLO text without metadata, so the path is
+  joined to them by name.
 
 Operation names are shortened from the HLO text the trace carries to
 ``%name opcode kind result-type``.  A :class:`Trace` can also be read
 from JSON, so a small hand-made one serves as a test fixture.  Interval
-arithmetic for the readers is here too.
+arithmetic for the readers is here too, and the split of the device's
+busy time by scope (:func:`owned_time`, :func:`scope_ms`).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import glob
+import heapq
 import json
 import os
 import re
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+from chipbench.hlo import op_names
 
 Interval = Tuple[float, float]
 Op = Tuple[str, float, float]
@@ -46,6 +55,7 @@ class Trace:
     devices: List[List[Op]]
     async_ops: List[List[Op]]
     host: List[Op]
+    scopes: Dict[str, str] = dataclasses.field(default_factory=dict)
 
     @property
     def window_s(self) -> float:
@@ -58,7 +68,8 @@ class Trace:
                    devices=[[tuple(o) for o in ops] for ops in d["devices"]],
                    async_ops=[[tuple(o) for o in ops]
                               for ops in d["async_ops"]],
-                   host=[tuple(o) for o in d["host"]])
+                   host=[tuple(o) for o in d["host"]],
+                   scopes=d.get("scopes", {}))
 
 
 def find_xplane(log_dir: str) -> str:
@@ -105,7 +116,14 @@ def _events(line, shorten=False):
         yield name, start, start + float(e.duration_ns)
 
 
-def load(path: str) -> Trace:
+def hlo_name(op_name: str) -> str:
+    """``%fusion.49`` of ``%fusion.49 fusion kLoop f32[8]``."""
+    return op_name.split(" ", 1)[0]
+
+
+def load(path: str, step_text: Optional[str] = None) -> Trace:
+    """The trace at ``path``; ``step_text``, the compiled step's HLO text,
+    gives the operations their scopes."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     lines: Dict[str, Dict[int, List[Op]]] = {OPS_LINE: {}, ASYNC_LINE: {}}
@@ -132,12 +150,24 @@ def load(path: str) -> Trace:
         return [o for o in ops if o[2] > window[0] and o[1] < window[1]]
 
     devices = sorted(lines[OPS_LINE])
-    return Trace(window=window, steps=len(steps),
-                 devices=[inside(lines[OPS_LINE][d]) for d in devices],
-                 async_ops=[inside(lines[ASYNC_LINE].get(d, []))
-                            for d in devices],
-                 host=sorted((o for o in inside(host) if o[0] != STEP_SPAN),
-                             key=lambda o: o[1]))
+    trace = Trace(window=window, steps=len(steps),
+                  devices=[inside(lines[OPS_LINE][d]) for d in devices],
+                  async_ops=[inside(lines[ASYNC_LINE].get(d, []))
+                             for d in devices],
+                  host=sorted((o for o in inside(host) if o[0] != STEP_SPAN),
+                              key=lambda o: o[1]))
+    if step_text:
+        join_scopes(trace, step_text)
+    return trace
+
+
+def join_scopes(trace: Trace, step_text: str) -> None:
+    """Fill ``trace.scopes`` for the operations it holds, from the
+    compiled step's HLO text (``hlo.op_names``)."""
+    names = {hlo_name(o[0]) for ops in trace.devices + trace.async_ops
+             for o in ops}
+    trace.scopes = {k: v for k, v in op_names(step_text).items()
+                    if k in names}
 
 
 # ---------------------------------------------------------------------------
@@ -204,6 +234,92 @@ def busy(trace: Trace, device: int) -> List[Interval]:
     included."""
     ops = [(a, b) for _, a, b in trace.devices[device]]
     return union(ops + collective_intervals(trace, device), trace.window)
+
+
+def owned_time(trace: Trace, device: int) -> Dict[str, float]:
+    """The device's busy time (:func:`busy`) split among its operations,
+    in ns by operation name: each instant belongs to the operation that
+    started last among those running on the compute line, or where none
+    runs, to the collective in flight that started last.  The values add
+    up to the busy time."""
+    events = [(a, b, 1, name) for name, a, b in trace.devices[device]]
+    events += [(a, b, 0, name) for name, a, b in trace.async_ops[device]
+               if is_collective(name)]
+    lo, hi = trace.window
+    events = sorted((max(a, lo), min(b, hi), rank, name)
+                    for a, b, rank, name in events if min(b, hi) > max(a, lo))
+    cuts = sorted({t for a, b, _, _ in events for t in (a, b)})
+    out: Dict[str, float] = {}
+    running: List[Tuple] = []      # (-rank, -start, end, name): owner first
+    i = 0
+    for t0, t1 in zip(cuts, cuts[1:]):
+        while i < len(events) and events[i][0] <= t0:
+            a, b, rank, name = events[i]
+            heapq.heappush(running, (-rank, -a, b, name))
+            i += 1
+        while running and running[0][2] <= t0:
+            heapq.heappop(running)
+        if running:
+            name = running[0][3]
+            out[name] = out.get(name, 0.0) + (t1 - t0)
+    return out
+
+
+_WRAPPED = re.compile(r"^[\w\-]+\((.*)\)$")
+
+
+def scope_path(trace: Trace, name: str) -> List[str]:
+    """The scopes of the operation ``name``, outermost first, each with
+    JAX's transformation wrappers taken off (``transpose(jvp(moe.combine))``
+    is ``moe.combine``); empty where the compiled step has none for it."""
+    out = []
+    for part in trace.scopes.get(hlo_name(name), "").split("/"):
+        m = _WRAPPED.match(part)
+        while m:
+            part = m.group(1)
+            m = _WRAPPED.match(part)
+        if part:
+            out.append(part)
+    return out
+
+
+#: a ZeRO re-pull is pull work
+_PARTS = {"regather": "pull"}
+
+
+def step_part(path: Sequence[str]) -> str:
+    """The part of the ZeRO step that a scope path lies in, by its
+    outermost ``zero.*`` scope: ``fwd``, ``bwd``, ``opt``, ``pull``
+    (``zero.pull.*`` and ``zero.regather.*``), ``push``; ``unscoped``
+    under none."""
+    for scope in path:
+        if scope.startswith("zero."):
+            part = scope.split(".")[1]
+            return _PARTS.get(part, part)
+    return "unscoped"
+
+
+def scope_ms(trace: Optional[Trace],
+             select: Callable[[List[str]], bool]) -> Optional[float]:
+    """Milliseconds a step, mean over devices, of the busy time owned
+    (:func:`owned_time`) by operations whose scope path ``select`` takes;
+    None where the trace has no scopes or no operation is taken."""
+    if trace is None or not trace.devices or not trace.scopes:
+        return None
+    total, found = 0.0, False
+    for d in range(len(trace.devices)):
+        for name, ns in owned_time(trace, d).items():
+            if select(scope_path(trace, name)):
+                total += ns
+                found = True
+    if not found:
+        return None
+    return total / len(trace.devices) / trace.steps * 1e-6
+
+
+def part_ms(trace: Optional[Trace], part: str) -> Optional[float]:
+    """:func:`scope_ms` of one part of the step (:func:`step_part`)."""
+    return scope_ms(trace, lambda path: step_part(path) == part)
 
 
 # ---------------------------------------------------------------------------
