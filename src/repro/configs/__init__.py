@@ -12,12 +12,13 @@ from repro.configs.granite_3_2b import CONFIG as _granite2b
 from repro.configs.grok_1_314b import CONFIG as _grok
 from repro.configs.gemma2_2b import CONFIG as _gemma2
 from repro.configs.recurrentgemma_2b import CONFIG as _rgemma
+from repro.configs.ouro_2_6b import CONFIG as _ouro
 
 ARCHITECTURES = {
     cfg.name: cfg
     for cfg in (
         _granite_moe, _xlstm, _llava, _gemma3, _hubert,
-        _gemma7b, _granite2b, _grok, _gemma2, _rgemma,
+        _gemma7b, _granite2b, _grok, _gemma2, _rgemma, _ouro,
     )
 }
 

@@ -55,6 +55,14 @@ class ArchConfig:
     rope_theta: float = 10000.0
     norm_eps: float = 1e-6
     tie_embeddings: bool = True
+    embed_scale: bool = True                 # embedding times sqrt(d_model)
+
+    # looped stack (Ouro, arXiv:2510.25741): the blocks run ``loop_steps``
+    # times on one set of weights; after each pass the state goes through
+    # the final norm, and a head and an exit gate read it
+    loop_steps: int = 1
+    sandwich_norm: bool = False              # norms after attn/MLP as well
+    exit_entropy_weight: float = 0.0         # beta of the exit objective
 
     # capability flags for shape selection
     encoder_only: bool = False
@@ -67,6 +75,8 @@ class ArchConfig:
             object.__setattr__(self, "layer_pattern", ("global_attn",))
         if self.num_heads % max(self.num_kv_heads, 1) != 0:
             raise ValueError(f"{self.name}: heads not divisible by kv heads")
+        if self.loop_steps < 1:
+            raise ValueError(f"{self.name}: loop_steps must be >= 1")
 
     # ------------------------------------------------------------------
     def layer_kinds(self) -> Tuple[LayerKind, ...]:
@@ -76,6 +86,10 @@ class ArchConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def looped(self) -> bool:
+        return self.loop_steps > 1
 
     @property
     def q_dim(self) -> int:

@@ -40,6 +40,13 @@ class LayerCosts:
     dt_bwd: optional distinct Δt for the backward (push) direction — an
         asymmetric link (parameter-server downlink vs uplink) pays different
         setup costs per direction.  ``None`` means symmetric (= ``dt``).
+    loop: compute between the forward and the backward phase with nothing
+        to send — a looped stack's passes after its first, forward and
+        backward.  fc/bc are one application's costs: the first pass's
+        forward overlaps the pulls and its backward the pushes, as for a
+        stack run once, so every push's ready time moves by ``loop`` and
+        neither phase's optimal segmentation changes; only
+        :func:`iteration_time` carries it.
     """
 
     pt: np.ndarray
@@ -48,6 +55,7 @@ class LayerCosts:
     gt: np.ndarray
     dt: float
     dt_bwd: float | None = None
+    loop: float = 0.0
 
     def __post_init__(self):
         for name in ("pt", "fc", "bc", "gt"):
@@ -63,6 +71,8 @@ class LayerCosts:
             raise ValueError("dt must be non-negative")
         if self.dt_bwd is not None and self.dt_bwd < 0:
             raise ValueError("dt_bwd must be non-negative")
+        if self.loop < 0:
+            raise ValueError("loop must be non-negative")
 
     @property
     def num_layers(self) -> int:
@@ -102,6 +112,7 @@ class LayerCosts:
             gt=self.gt * comm,
             dt=self.dt if dt is None else dt,
             dt_bwd=self.dt_bwd if dt is None else dt_bwd,
+            loop=self.loop * compute,
         )
 
     def compressed(self, *, gt_ratio: float = 1.0, pt_ratio: float = 1.0,
@@ -127,6 +138,7 @@ class LayerCosts:
             gt=self.gt * gt_ratio,
             dt=self.dt,
             dt_bwd=self.dt_push + dt_bwd_extra,
+            loop=self.loop,
         )
 
 
@@ -254,8 +266,10 @@ def iteration_time(costs: LayerCosts,
                    fwd_segments: Sequence[Segment],
                    bwd_segments: Sequence[Segment]) -> float:
     """Total iteration time: forward phase then backward phase (eq. 3 chains
-    them — bc_L cannot start before fc_L ends)."""
-    return forward_time(costs, fwd_segments) + backward_time(costs, bwd_segments)
+    them — bc_L cannot start before fc_L ends), with a looped stack's
+    further passes between them."""
+    return forward_time(costs, fwd_segments) + costs.loop \
+        + backward_time(costs, bwd_segments)
 
 
 # ---------------------------------------------------------------------------

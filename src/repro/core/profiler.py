@@ -34,6 +34,7 @@ class LayerProfile:
     flops_fwd: float
     flops_bwd: float | None = None     # default: 2x forward (dL/dx + dL/dw)
     grad_bytes: float | None = None    # default: == param_bytes
+    applications: int = 1              # per step; > 1 in a looped stack
 
     @property
     def bwd(self) -> float:
@@ -52,7 +53,9 @@ def costs_from_profiles(profiles: Sequence[LayerProfile],
 
     ``compute_flops_per_s`` overrides the compute rate (needed for the edge
     regime, where `EdgeNetworkModel` has no compute side — the paper's Xeon
-    workers); for `TPUSystemModel` it defaults to peak*mfu.
+    workers); for `TPUSystemModel` it defaults to peak*mfu.  fc/bc are one
+    application's; a layer applied more than once a step (a looped stack)
+    adds its further applications' compute to ``LayerCosts.loop``.
     """
     pbytes = np.array([p.param_bytes for p in profiles], dtype=np.float64)
     gbytes = np.array([p.gbytes for p in profiles], dtype=np.float64)
@@ -69,7 +72,9 @@ def costs_from_profiles(profiles: Sequence[LayerProfile],
         bc = net.compute_time(f_bwd)
     else:
         raise ValueError("edge regime requires compute_flops_per_s")
-    return LayerCosts(pt=pt, fc=fc, bc=bc, gt=gt, dt=net.dt)
+    again = np.array([p.applications - 1 for p in profiles], np.float64)
+    return LayerCosts(pt=pt, fc=fc, bc=bc, gt=gt, dt=net.dt,
+                      loop=float(np.sum(again * (fc + bc))))
 
 
 # ---------------------------------------------------------------------------

@@ -37,10 +37,11 @@ class IterationTimeline:
     backward_events: Tuple[Event, ...]
     forward_time: float
     backward_time: float
+    loop_time: float = 0.0    # a looped stack's passes between the phases
 
     @property
     def total(self) -> float:
-        return self.forward_time + self.backward_time
+        return self.forward_time + self.loop_time + self.backward_time
 
     def breakdown(self, phase: str) -> PhaseBreakdown:
         events = self.forward_events if phase == "forward" else self.backward_events
@@ -95,7 +96,8 @@ def simulate_iteration(costs: LayerCosts,
                        bwd_segments: Sequence[Segment]) -> IterationTimeline:
     f_events, f_t = simulate_forward(costs, fwd_segments)
     b_events, b_t = simulate_backward(costs, bwd_segments)
-    return IterationTimeline(tuple(f_events), tuple(b_events), f_t, b_t)
+    return IterationTimeline(tuple(f_events), tuple(b_events), f_t, b_t,
+                             costs.loop)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -35,16 +35,29 @@ The step is built with ``shard_map`` so the collectives above are the
 *only* all-gathers / reduce-scatters in the compiled HLO —
 ``tests/test_dist.py`` asserts the counts against the plan.
 
+A looped stack (``cfg.loop_steps`` T > 1) applies each block's pulled
+weights T times and exits after every pass (``models/model.py``).  Every
+application's input is saved; the backward walks the passes T..1, the
+blocks top-down inside each, recomputing each application from its input
+(an optimization barrier keeps XLA from holding the forward's copies of
+all T·L applications instead).  A block's gradient is the sum of its T
+VJPs, so no bucket is pushed before pass 1's backward, which follows the
+plan as a stack run once does; the head's gradient sums its T exits.
+
 Each part of the step runs under a ``jax.named_scope`` that the profiler
 attaches to its device ops: ``zero.pull.b{i}``, ``zero.fwd.L{l}``,
 ``zero.regather.b{i}``, ``zero.bwd.L{l}``, ``zero.push.b{i}`` (bucket
-``i`` of the plan, sched layer ``l``) and ``zero.opt``.  Scopes are
-metadata only: the compiled instructions are the same without them.
-Holding leaves, the pull, push and re-pull scopes hold no operation.
+``i`` of the plan, sched layer ``l``) and ``zero.opt``; in a looped
+stack, ``loop.t{t}`` inside a block's ``zero.fwd``/``zero.bwd`` scope for
+pass ``t``, and ``loop.exit`` on the exits (final norm, head, gate) and
+the exit objective.  Scopes are metadata only: the compiled instructions
+are the same without them.  Holding leaves, the pull, push and re-pull
+scopes hold no operation.
 """
 
 from __future__ import annotations
 
+import contextlib
 import copy
 import dataclasses
 from typing import Any, Dict, List, Optional
@@ -278,6 +291,13 @@ class ZeroTrainer:
             labels = jnp.concatenate([pad, labels], axis=1)
         return model_lib.cross_entropy(logits, labels)
 
+    def _apply_exit(self, final_tree, embed_tree, x, batch, t):
+        """A looped stack's exit after pass ``t``: ``(h_t, per-token
+        losses, gate logits)``, no gate after the last pass."""
+        return model_lib.loop_exit(
+            self.cfg, {"embed": embed_tree, "final": final_tree}, x,
+            batch["labels"], gate=t < self.cfg.loop_steps)
+
     # ------------------------------------------------------------------
     # the train step
     # ------------------------------------------------------------------
@@ -301,9 +321,13 @@ class ZeroTrainer:
 
     def _local_step(self, state, batch):
         Ls, kinds, layout = self.num_layers, self._kinds, self._layout
+        T = self.cfg.loop_steps
         shards = list(state["flat_params"])
         res_local = state.get("residuals")     # local views: (1, padded_l)
         new_res = list(res_local) if res_local is not None else None
+
+        def loop_scope(name):
+            return jax.named_scope(name) if T > 1 else contextlib.nullcontext()
 
         # ---- pull phase: one all-gather per forward bucket --------------
         full: Dict[int, Any] = {}
@@ -311,19 +335,37 @@ class ZeroTrainer:
             with jax.named_scope(f"zero.pull.b{i}"):
                 full.update(layout.pull(shards, bucket))
 
-        # ---- forward, saving each layer's input activation --------------
-        acts: Dict[int, jnp.ndarray] = {}
+        # ---- forward, saving each layer application's input -------------
+        # A looped stack applies the pulled blocks T times (pass t's input
+        # of layer l is acts[t, l]) and exits after every pass.
+        acts: Dict[Any, jnp.ndarray] = {}
+        exits: List[Any] = []          # per pass: losses (and gate logits)
         aux = jnp.zeros((), jnp.float32)
         with jax.named_scope("zero.fwd.L0"):
             h = self._apply_embed(full[0], batch)
-        for l in range(1, Ls - 1):
-            acts[l] = h
-            with jax.named_scope(f"zero.fwd.L{l}"):
-                h, a = self._apply_block(full[l], h, kinds[l - 1])
-                aux = aux + a
-        acts[Ls - 1] = h
-        with jax.named_scope(f"zero.fwd.L{Ls - 1}"):
-            ce = self._apply_final(full[Ls - 1], full[0], h, batch)
+        for t in range(1, T + 1):
+            for l in range(1, Ls - 1):
+                acts[t, l] = h
+                with jax.named_scope(f"zero.fwd.L{l}"), \
+                        loop_scope(f"loop.t{t}"):
+                    h, a = self._apply_block(full[l], h, kinds[l - 1])
+                    aux = aux + a
+            acts[t, Ls - 1] = h
+            if T > 1:
+                with jax.named_scope(f"zero.fwd.L{Ls - 1}"), \
+                        jax.named_scope("loop.exit"):
+                    h, *out = self._apply_exit(full[Ls - 1], full[0], h,
+                                               batch, t)
+                    exits.append(out)
+
+        def objective(outs):
+            return model_lib.exit_objective(self.cfg, outs, batch["labels"])
+
+        with jax.named_scope(f"zero.fwd.L{Ls - 1}"), loop_scope("loop.exit"):
+            if T > 1:
+                ce = objective(exits)
+            else:
+                ce = self._apply_final(full[Ls - 1], full[0], h, batch)
             loss_local = ce + self.aux_weight * aux
 
         # ---- ZeRO-3: re-pull mid-layer buckets for the backward ---------
@@ -345,32 +387,84 @@ class ZeroTrainer:
         grad_parts: List[Optional[List[jnp.ndarray]]] = [None] * Ls
         embed_from_head = None     # tied-head contribution to the embedding
         ct_h = None                # cotangent w.r.t. the current activation
+
+        def exit_vjp(t, p_final):
+            """Pass t's exit: (final grads, embedding grads, cotangent of
+            the pass's last block output)."""
+            _, vjp = jax.vjp(
+                lambda pf, pe, hh: self._apply_exit(pf, pe, hh, batch, t),
+                p_final, full[0], acts[t, Ls - 1])
+            ct_out = jnp.zeros_like(acts[t, Ls - 1]) if t == T else ct_h
+            return vjp((ct_out, *ct_exits[t - 1]))
+
+        def block_vjp(t, l, p_l):
+            kind = kinds[l - 1]
+            args = (p_l, acts[t, l])
+            if T > 1:
+                # recompute from the saved input: merged with the forward,
+                # XLA keeps all T·L applications' intermediates (2e10 B at
+                # the Ouro cell's size, against 1.4e10)
+                args = jax.lax.optimization_barrier(args)
+            _, vjp = jax.vjp(
+                lambda p, hh, _k=kind: self._apply_block(p, hh, _k), *args)
+            return vjp((ct_h, aux_ct))
+
+        # A looped stack's passes T..2 first: each layer's gradient is the
+        # sum over its T applications, so no bucket is complete, and none
+        # pushed, before pass 1's backward below.
+        summed: Dict[int, Any] = {}
+
+        def add(l, g):
+            """Layer l's gradient summed over its applications so far."""
+            summed[l] = g if l not in summed else \
+                jax.tree_util.tree_map(jnp.add, g, summed[l])
+            return summed[l]
+
+        if T > 1:
+            with jax.named_scope(f"zero.bwd.L{Ls - 1}"), \
+                    jax.named_scope("loop.exit"):
+                _, vjp = jax.vjp(objective, exits)
+                (ct_exits,) = vjp(one)
+        for t in range(T, 1, -1):
+            with jax.named_scope(f"zero.bwd.L{Ls - 1}"), \
+                    jax.named_scope("loop.exit"):
+                g_final, g_embed_head, ct_h = exit_vjp(
+                    t, regathered.get(Ls - 1, full[Ls - 1]))
+                add(Ls - 1, g_final)
+                add(0, g_embed_head)
+            for l in range(Ls - 2, 0, -1):
+                with jax.named_scope(f"zero.bwd.L{l}"), \
+                        jax.named_scope(f"loop.t{t}"):
+                    g_block, ct_h = block_vjp(t, l,
+                                              regathered.get(l, full[l]))
+                    add(l, g_block)
+
         for i, bucket in enumerate(self.plan.backward):
             bucket_grads: Dict[int, Any] = {}
             for l in bucket:       # descending layer order within the bucket
                 p_l = regathered.get(l, full[l])
                 with jax.named_scope(f"zero.bwd.L{l}"):
-                    if l == Ls - 1:
+                    if l == Ls - 1 and T > 1:
+                        with jax.named_scope("loop.exit"):
+                            g_final, embed_from_head, ct_h = exit_vjp(1, p_l)
+                            bucket_grads[l] = add(l, g_final)
+                    elif l == Ls - 1:
                         _, vjp = jax.vjp(
                             lambda pf, pe, hh: self._apply_final(pf, pe, hh,
                                                                  batch),
-                            p_l, full[0], acts[l])
+                            p_l, full[0], acts[1, l])
                         g_final, embed_from_head, ct_h = vjp(one)
                         bucket_grads[l] = g_final
                     elif l == 0:
                         _, vjp = jax.vjp(
                             lambda pe: self._apply_embed(pe, batch), p_l)
                         (g_embed,) = vjp(ct_h)
-                        bucket_grads[l] = jax.tree_util.tree_map(
-                            jnp.add, g_embed, embed_from_head)
+                        bucket_grads[l] = add(l, jax.tree_util.tree_map(
+                            jnp.add, g_embed, embed_from_head))
                     else:
-                        kind = kinds[l - 1]
-                        _, vjp = jax.vjp(
-                            lambda p, hh, _k=kind: self._apply_block(p, hh,
-                                                                     _k),
-                            p_l, acts[l])
-                        g_block, ct_h = vjp((ct_h, aux_ct))
-                        bucket_grads[l] = g_block
+                        with loop_scope("loop.t1"):
+                            g_block, ct_h = block_vjp(1, l, p_l)
+                            bucket_grads[l] = add(l, g_block)
             with jax.named_scope(f"zero.push.b{i}"):
                 res_in = ({l: res_local[l][0] for l in bucket}
                           if res_local is not None else None)

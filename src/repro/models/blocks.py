@@ -1,5 +1,9 @@
 """Per-layer block: init / apply dispatch over the config's layer kinds.
 
+Pre-norm: ``x + mixer(norm1(x))`` then ``x + mlp(norm2(x))``; with
+``cfg.sandwich_norm`` each branch's output is normed too (``post_norm1``,
+``post_norm2``), as Ouro's blocks are.
+
 Every block is addressable individually — DynaComm schedules transmissions
 layer-by-layer, so the model deliberately exposes `init_block` / `apply_block`
 instead of a fused scan-only stack.  (A `lax.scan` fast path exists in
@@ -22,6 +26,8 @@ from repro.models.moe import apply_moe, init_moe_params
 def init_block(key, cfg: ArchConfig, kind: LayerKind, dtype=jnp.float32):
     ks = jax.random.split(key, 3)
     p: dict = {"norm1": jnp.zeros((cfg.d_model,), dtype)}
+    if cfg.sandwich_norm:
+        p["post_norm1"] = jnp.zeros((cfg.d_model,), dtype)
     if kind in ("global_attn", "local_attn"):
         p["attn"] = attention.init_attn_params(ks[0], cfg, dtype)
     elif kind == "mlstm":
@@ -35,6 +41,8 @@ def init_block(key, cfg: ArchConfig, kind: LayerKind, dtype=jnp.float32):
 
     if cfg.d_ff > 0:
         p["norm2"] = jnp.zeros((cfg.d_model,), dtype)
+        if cfg.sandwich_norm:
+            p["post_norm2"] = jnp.zeros((cfg.d_model,), dtype)
         if cfg.is_moe:
             p["moe"] = init_moe_params(ks[1], cfg, dtype)
         else:
@@ -78,6 +86,8 @@ def apply_block(params, x: jnp.ndarray, cfg: ArchConfig, kind: LayerKind, *,
                                          state=cache)
     else:
         raise ValueError(kind)
+    if cfg.sandwich_norm:
+        out = rms_norm(out, params["post_norm1"], cfg.norm_eps)
     x = x + out
 
     if cfg.d_ff > 0:
@@ -86,5 +96,7 @@ def apply_block(params, x: jnp.ndarray, cfg: ArchConfig, kind: LayerKind, *,
             out2, aux = apply_moe(params["moe"], h2, cfg)
         else:
             out2 = apply_mlp(params["mlp"], h2, cfg.activation)
+        if cfg.sandwich_norm:
+            out2 = rms_norm(out2, params["post_norm2"], cfg.norm_eps)
         x = x + out2
     return x, new_cache, aux

@@ -1,5 +1,8 @@
 """Analytic per-sched-layer FLOP counts → LayerProfile vectors.
 
+A looped stack's blocks and head are profiled once per application, with
+``applications`` the loop count (see ``costs_from_profiles``).
+
 These feed (a) the DynaComm scheduler's cost vectors in analytic mode and
 (b) the roofline's MODEL_FLOPS (6·N·D dense / 6·N_active·D MoE) sanity term.
 Forward FLOPs are matmul-dominated counts (2·M·N·K per matmul); backward
@@ -97,6 +100,7 @@ def layer_profiles(cfg: ArchConfig, shape: InputShape,
     pbytes = sched_layer_bytes(cfg, param_dtype)
     kinds = cfg.layer_kinds()
 
+    loops = cfg.loop_steps
     profs = [LayerProfile(name="embed", param_bytes=pbytes[0],
                           flops_fwd=2.0 * b * t * cfg.d_model)]
     for i, kind in enumerate(kinds):
@@ -104,10 +108,13 @@ def layer_profiles(cfg: ArchConfig, shape: InputShape,
             name=f"block{i}:{kind}",
             param_bytes=pbytes[1 + i],
             flops_fwd=block_forward_flops(cfg, kind, b, t, kv_len, shape.mode),
+            applications=loops,
         ))
     head_flops = 2.0 * b * t * cfg.d_model * cfg.vocab_size
+    if cfg.looped:
+        head_flops += 2.0 * b * t * cfg.d_model        # the exit gate
     profs.append(LayerProfile(name="head", param_bytes=pbytes[-1],
-                              flops_fwd=head_flops))
+                              flops_fwd=head_flops, applications=loops))
     return profs
 
 

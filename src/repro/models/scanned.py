@@ -71,6 +71,9 @@ def forward_scanned(cfg: ArchConfig, sp: Dict[str, Any],
     drifts to replicated-batch layouts inside the stack.
     """
     assert mode in ("train", "prefill")
+    if cfg.looped:
+        raise NotImplementedError(f"{cfg.name}: a looped stack runs on the "
+                                  f"per-layer path (models/model.py)")
     pattern = cfg.layer_pattern
     n_groups, rem = group_count(cfg)
 

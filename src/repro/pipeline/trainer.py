@@ -80,6 +80,10 @@ class PipelineTrainer:
     transfer_chunks: int = 1
 
     def __post_init__(self):
+        if self.cfg.looped:
+            raise NotImplementedError(
+                f"{self.cfg.name}: a looped stack's passes span every stage; "
+                f"it trains on the ZeRO path")
         self.num_layers = model_lib.num_sched_layers(self.cfg)
         if not 1 <= self.num_stages <= self.num_layers:
             raise ValueError(

@@ -19,15 +19,20 @@ from repro.models import model as model_lib
 def pad_caches(cfg: ArchConfig, caches: List[Any], max_len: int) -> List[Any]:
     """Grow global-attention KV caches to max_len (decode writes past t)."""
     from repro.models.attention import KVCache
-    out = []
-    for kind, c in zip(cfg.layer_kinds(), caches):
-        if kind == "global_attn" and isinstance(c, KVCache) \
-                and c.k.shape[1] < max_len:
+
+    def grow(c):
+        if isinstance(c, KVCache) and c.k.shape[1] < max_len:
             pad = max_len - c.k.shape[1]
             c = KVCache(
                 k=jnp.pad(c.k, ((0, 0), (0, pad), (0, 0), (0, 0))),
                 v=jnp.pad(c.v, ((0, 0), (0, pad), (0, 0), (0, 0))),
                 pos=c.pos)
+        return c
+
+    out = []
+    for kind, c in zip(cfg.layer_kinds(), caches):
+        if kind == "global_attn":          # a looped stack: one a pass
+            c = [grow(x) for x in c] if cfg.looped else grow(c)
         out.append(c)
     return out
 

@@ -220,6 +220,62 @@ class TestSimulator:
 
 
 # ---------------------------------------------------------------------------
+# a looped stack: one application's costs per layer, the passes between
+# ---------------------------------------------------------------------------
+
+class TestLoopedStack:
+    """The planner takes one application's fc/bc per block and head; a
+    looped stack's further passes only shift every push's ready time."""
+
+    @staticmethod
+    def _costs(seed, loops):
+        from repro.core import EdgeNetworkModel, LayerProfile, \
+            costs_from_profiles
+        rng = np.random.default_rng(seed)
+        L = 10
+        profiles = [LayerProfile(name=f"l{i}",
+                                 param_bytes=float(rng.uniform(1e5, 5e7)),
+                                 flops_fwd=float(rng.uniform(1e9, 1e11)),
+                                 applications=1 if i == 0 else loops)
+                    for i in range(L)]
+        return costs_from_profiles(profiles, net=EdgeNetworkModel(),
+                                   compute_flops_per_s=1e12)
+
+    @pytest.mark.parametrize("seed,loops", [(0, 2), (1, 4), (2, 4), (3, 7)])
+    def test_segments_invariant_time_carries_the_passes(self, seed, loops):
+        once, looped = self._costs(seed, 1), self._costs(seed, loops)
+        for name in ("pt", "fc", "bc", "gt"):
+            np.testing.assert_array_equal(getattr(once, name),
+                                          getattr(looped, name))
+        f, b = dp_forward(looped), dp_backward(looped)
+        assert f.segments == dp_forward(once).segments
+        assert b.segments == dp_backward(once).segments
+        assert len(f.segments) > 1 or len(b.segments) > 1   # not trivial
+        # the first layer (the embedding) runs once
+        extra = (loops - 1) * (np.sum(looped.fc[1:]) + np.sum(looped.bc[1:]))
+        decision = (f.segments, b.segments)
+        assert evaluate(looped, decision)["total"] - \
+            evaluate(once, decision)["total"] == pytest.approx(extra,
+                                                               rel=1e-12)
+        assert simulate_iteration(looped, *decision).total == \
+            pytest.approx(evaluate(looped, decision)["total"], rel=1e-12)
+
+    def test_looped_profiles(self):
+        import dataclasses
+        from repro.configs import get_config
+        from repro.configs.base import InputShape
+        from repro.models.profiles import layer_profiles
+        cfg = dataclasses.replace(get_config("ouro-2.6b"), num_layers=4)
+        shape = InputShape("train_512", 512, 8, "train")
+        profs = layer_profiles(cfg, shape)
+        assert [p.applications for p in profs] == [1, 4, 4, 4, 4, 4]
+        once = layer_profiles(dataclasses.replace(cfg, loop_steps=1), shape)
+        assert all(p.applications == 1 for p in once)
+        # the exit gate adds one output column to the head
+        assert profs[-1].flops_fwd - once[-1].flops_fwd == 2.0 * 8 * 512 * 2048
+
+
+# ---------------------------------------------------------------------------
 # strategy registry + bucket plans
 # ---------------------------------------------------------------------------
 

@@ -91,7 +91,7 @@ class TestArchSmoke:
 
 
 DECODE_ARCHS = ["granite-3-2b", "gemma2-2b", "gemma3-4b", "xlstm-350m",
-                "recurrentgemma-2b", "llava-next-34b"]
+                "recurrentgemma-2b", "llava-next-34b", "ouro-2.6b"]
 
 
 @pytest.mark.parametrize("arch", DECODE_ARCHS)
@@ -195,6 +195,7 @@ EXPECT = {
     "grok-1-314b": (64, 6144, 48, 8, 32768, 131072),
     "gemma2-2b": (26, 2304, 8, 4, 9216, 256000),
     "recurrentgemma-2b": (26, 2560, 10, 1, 7680, 256000),
+    "ouro-2.6b": (48, 2048, 16, 16, 5632, 49152),
 }
 
 
@@ -220,7 +221,7 @@ def test_param_counts_near_model_cards():
     targets = {"grok-1-314b": 314, "llava-next-34b": 34, "gemma-7b": 8.5,
                "gemma3-4b": 4, "gemma2-2b": 2.6, "recurrentgemma-2b": 2.7,
                "granite-3-2b": 2.5, "granite-moe-1b-a400m": 1.3,
-               "hubert-xlarge": 1.0, "xlstm-350m": 0.45}
+               "hubert-xlarge": 1.0, "xlstm-350m": 0.45, "ouro-2.6b": 2.6}
     for arch, tgt in targets.items():
         n = param_count(get_config(arch)) / 1e9
         assert abs(n - tgt) / tgt < 0.25, f"{arch}: {n:.2f}B vs {tgt}B"
