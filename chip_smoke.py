@@ -217,29 +217,24 @@ def phase_ps_int8(arch, config, zero_first_loss: float) -> None:
 
 
 def push_and_pull_counts(hlo: str):
-    """(all-gathers, pushes) in a compiled step.  XLA's TPU backend
-    compiles a reduce-scatter as an all-reduce of the whole operand and a
-    slice, so a push is a reduce-scatter or an all-reduce above the
-    scalar threshold (the loss mean is the one scalar all-reduce)."""
-    from repro.analysis.conformance import SMALL_COLLECTIVE_BYTES
-    from repro.analysis.hlo import collective_summary
-    summary = collective_summary(hlo)
-    big_all_reduces = [b for _, b in summary["all-reduce"]
-                       if b > SMALL_COLLECTIVE_BYTES]
+    """(pull, push) segments in a compiled step: the bucket scopes that
+    hold a collective (``repro.analysis.segment_counts``).  A segment of
+    sharded leaves moves one collective per leaf, and XLA's TPU backend
+    compiles many of the pushes as fusions without metadata; the native
+    reduce-scatters of each push bucket carry its scope."""
+    from repro.analysis.hlo import collective_counts, segment_counts
+    counts = collective_counts(hlo)
     print(f"[zero-4] compiled collectives: "
-          f"{len(summary['all-gather'])} all-gather, "
-          f"{len(summary['reduce-scatter'])} reduce-scatter, "
-          f"{len(summary['all-reduce'])} all-reduce "
-          f"({len(big_all_reduces)} above {SMALL_COLLECTIVE_BYTES} B)")
-    return (len(summary["all-gather"]),
-            len(summary["reduce-scatter"]) + len(big_all_reduces))
+          + ", ".join(f"{counts[k]} {k}" for k in
+                      ("all-gather", "reduce-scatter", "all-reduce")))
+    return segment_counts(hlo)
 
 
 def phase_four_chips(arch, strategies=("dynacomm", "sequential"),
                      batch: int = BATCH, seq: int = SEQ):
-    """ZeRO over the 4-device data axis under two bucket plans: one
-    all-gather per pull segment and one push per push segment, and the
-    same losses."""
+    """ZeRO over the 4-device data axis under two bucket plans: one pull
+    per pull segment and one push per push segment, and the same
+    losses."""
     from repro.runtime import build_runtime
     losses = {}
     for strategy in strategies:

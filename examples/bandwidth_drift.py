@@ -73,9 +73,10 @@ def main():
     print("\nre-scheduling history:")
     shown = set()
     for e in rt.events:
-        ag, rs = dyn.hlo_counts(e.plan)
+        pulls, pushes = dyn.hlo_counts(e.plan)
         print(f"  epoch {e.epoch:3d}: {len(e.plan.forward)} pull / "
-              f"{len(e.plan.backward)} push buckets (hlo {ag} ag / {rs} rs)  "
+              f"{len(e.plan.backward)} push buckets "
+              f"(hlo {pulls} pull / {pushes} push)  "
               f"{'RE-SEGMENTED' if e.plan_changed else 'unchanged'}"
               f"{' via step cache' if e.plan_changed and not e.retraced else ''}"
               f"  sched {e.scheduling_seconds * 1e3:.2f} ms "

@@ -72,9 +72,10 @@ def main():
         print(f"  step {done:4d}  epoch {rt.trainer.epoch}  "
               f"loss {losses[-1]:.4f}")
     for e in rt.events:
-        ag, rs = rt.trainer.hlo_counts(e.plan)
+        pulls, pushes = rt.trainer.hlo_counts(e.plan)
         print(f"  epoch {e.epoch}: {len(e.plan.forward)} pull / "
-              f"{len(e.plan.backward)} push segments (hlo {ag} ag/{rs} rs) "
+              f"{len(e.plan.backward)} push segments (hlo {pulls} pull/"
+              f"{pushes} push) "
               f"{'re-segmented' if e.plan_changed else 'unchanged'}, "
               f"sched {e.scheduling_seconds * 1e3:.2f} ms, "
               f"hidden={e.overhead_hidden}")

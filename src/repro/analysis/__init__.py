@@ -22,6 +22,7 @@ stay usable in import-light contexts.
 """
 
 from repro.analysis.conformance import (expected_ag_bytes,
+                                        expected_ar_bytes,
                                         expected_rs_bytes,
                                         independent_wire_bytes,
                                         segment_wire_bytes, verify_cache,
@@ -33,16 +34,19 @@ from repro.analysis.findings import (Finding, findings_to_json,
                                      render_findings)
 from repro.analysis.hlo import (COLLECTIVES, DTYPE_BYTES, HloInstruction,
                                 HloModule, collective_counts,
-                                collective_summary, parse_hlo, type_bytes)
+                                collective_summary, parse_hlo,
+                                segment_counts, type_bytes)
 from repro.analysis.lints import (LINT_CODES, LintConfig, lint_file,
                                   lint_paths, lint_source)
 
 __all__ = [
     "COLLECTIVES", "DTYPE_BYTES", "Finding", "HloInstruction", "HloModule",
     "LINT_CODES", "LintConfig", "collective_counts", "collective_summary",
-    "expected_ag_bytes", "expected_rs_bytes", "findings_to_json",
+    "expected_ag_bytes", "expected_ar_bytes", "expected_rs_bytes",
+    "findings_to_json",
     "independent_wire_bytes", "lint_file", "lint_paths", "lint_source",
-    "parse_hlo", "render_findings", "segment_wire_bytes", "type_bytes",
+    "parse_hlo", "render_findings", "segment_counts", "segment_wire_bytes",
+    "type_bytes",
     "verify_cache", "verify_fleet_membership", "verify_no_collectives",
     "verify_push_ledger", "verify_schedule", "verify_wire_model",
 ]

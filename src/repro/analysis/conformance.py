@@ -1,12 +1,12 @@
 """Schedule-conformance verification over compiled HLO (layer 1).
 
 DynaComm's structural claim is that the compiled step contains *exactly*
-the collectives the DP decision prescribes: one all-gather (parameter
-pull) per forward bucket, one reduce-scatter (gradient push) per
-backward bucket, each moving exactly the ``FlatSpec`` flat-buffer bytes
-— and nothing else crossing replicas.  :func:`verify_schedule` checks a
-compiled HLO dump against a :class:`~repro.core.buckets.BucketPlan` and
-the trainer's specs; :func:`verify_cache` audits a
+the collectives the DP decision prescribes — each forward bucket's
+parameter pull, each backward bucket's gradient push, each moving
+exactly the bytes of the state layout — and nothing else crossing
+replicas.  :func:`verify_schedule` checks a compiled HLO dump against a
+:class:`~repro.core.buckets.BucketPlan`, the trainer's specs and its
+state layout; :func:`verify_cache` audits a
 :class:`~repro.runtime.replan.PlanStepCache` (one compilation per
 distinct plan); :func:`verify_wire_model` and
 :func:`verify_push_ledger` prove the compressed wire-byte accounting
@@ -14,11 +14,18 @@ exact against an *independent* reimplementation of the compressor byte
 formulas.
 
 Expected operand bytes (empirically pinned against XLA's partitioner,
-see the golden fixtures):
+see the golden fixtures), by ``ZeroTrainer.layout``:
 
-* all-gather of forward bucket ``b`` operates on the concatenated local
-  shards — ``4 * sum(padded_l // axis_size for l in b)`` bytes;
-* reduce-scatter of backward bucket ``b`` operates on the stacked
+* ``leaves`` (no compressor): every leaf is split on the first dimension
+  the axis size divides (re-derived here, :func:`_leaf_dim`).  A forward
+  bucket's pull is one all-gather per split leaf of its layers, on the
+  local shard (``4 * size / axis_size`` bytes); a backward bucket's push
+  one reduce-scatter per split leaf, on the whole gradient (``4 * size``)
+  and one all-reduce per leaf held whole (``4 * size``);
+* ``flat`` (a compressor): the all-gather of forward bucket ``b``
+  operates on the concatenated local shards —
+  ``4 * sum(padded_l // axis_size for l in b)`` bytes — and the
+  reduce-scatter of backward bucket ``b`` on the stacked
   ``(axis_size, shard)`` gradient — ``4 * sum(padded_l for l in b)``
   bytes (compressed pushes roundtrip to f32 *before* the collective, so
   HLO operands stay f32 — wire compression is verified at the byte-model
@@ -40,7 +47,8 @@ from repro.analysis.findings import Finding
 from repro.analysis.hlo import ModuleOrText, _as_module, collective_summary
 
 __all__ = [
-    "expected_ag_bytes", "expected_rs_bytes", "independent_wire_bytes",
+    "expected_ag_bytes", "expected_ar_bytes", "expected_rs_bytes",
+    "independent_wire_bytes",
     "segment_wire_bytes", "verify_schedule", "verify_no_collectives",
     "verify_cache", "verify_wire_model", "verify_push_ledger",
     "verify_fleet_membership",
@@ -61,28 +69,77 @@ SMALL_COLLECTIVE_BYTES = 1024
 # expected byte math
 # ---------------------------------------------------------------------------
 
+def _leaf_dim(shape: Sequence[int], axis_size: int) -> Optional[int]:
+    """The dimension a ``leaves`` state splits a leaf on: the first that
+    ``axis_size`` divides, ``None`` where none does."""
+    return next((d for d, n in enumerate(shape) if n % axis_size == 0), None)
+
+
+def _leaf_bytes(spec: Any) -> List[Tuple[int, bool]]:
+    """``(f32 bytes, split)`` of each leaf of a sched layer."""
+    return [(4 * math.prod(shape),
+             _leaf_dim(shape, spec.axis_size) is not None)
+            for shape in spec.shapes]
+
+
+def _flat(compressor: Optional[Any]) -> bool:
+    """Whether the trainer holds the flat wire: only a compressor that
+    compresses does (``ZeroTrainer`` drops a ``"none"`` scheme)."""
+    return getattr(compressor, "scheme", "none") != "none"
+
+
+def _pull_bytes(specs: Sequence[Any], bucket: Sequence[int],
+                flat: bool) -> List[int]:
+    """All-gather operand bytes of one bucket's pull."""
+    if flat:
+        return [4 * sum(specs[l].padded // specs[l].axis_size
+                        for l in bucket)]
+    return [n // specs[l].axis_size for l in bucket
+            for n, split in _leaf_bytes(specs[l]) if split]
+
+
+def _mid_buckets(plan: Any, num_layers: int) -> List[Sequence[int]]:
+    """The backward buckets ZeRO-3 re-pulls: those with a middle layer."""
+    return [b for b in plan.backward
+            if any(0 < l < num_layers - 1 for l in b)]
+
+
 def expected_ag_bytes(specs: Sequence[Any], plan: Any, *,
-                      zero3: bool = False) -> List[int]:
-    """Expected all-gather operand bytes, one entry per gather.
+                      zero3: bool = False,
+                      compressor: Optional[Any] = None) -> List[int]:
+    """Expected all-gather operand bytes, one entry per gather, in the
+    layout the ``compressor`` picks (module docstring).
 
     With ``zero3`` every backward bucket containing a middle layer
-    re-pulls its full bucket (one extra gather of the same byte shape as
-    a forward gather of that bucket)."""
-    def bucket_bytes(bucket):
-        return 4 * sum(specs[l].padded // specs[l].axis_size for l in bucket)
-
-    out = [bucket_bytes(b) for b in plan.forward]
+    re-pulls its full bucket (the gathers of a forward pull of that
+    bucket once more)."""
+    buckets = list(plan.forward)
     if zero3:
-        num_layers = len(specs)
-        out += [bucket_bytes(b) for b in plan.backward
-                if any(0 < l < num_layers - 1 for l in b)]
-    return out
+        buckets += _mid_buckets(plan, len(specs))
+    flat = _flat(compressor)
+    return [n for b in buckets for n in _pull_bytes(specs, b, flat)]
 
 
-def expected_rs_bytes(specs: Sequence[Any], plan: Any) -> List[int]:
-    """Expected reduce-scatter operand bytes, one entry per backward
-    bucket (the stacked ``(axis_size, shard)`` gradient)."""
-    return [4 * sum(specs[l].padded for l in b) for b in plan.backward]
+def expected_rs_bytes(specs: Sequence[Any], plan: Any, *,
+                      compressor: Optional[Any] = None) -> List[int]:
+    """Expected reduce-scatter operand bytes: one entry per backward
+    bucket (the stacked ``(axis_size, shard)`` gradient) on the flat
+    wire, one per split leaf in the leaves layout."""
+    if _flat(compressor):
+        return [4 * sum(specs[l].padded for l in b) for b in plan.backward]
+    return [n for b in plan.backward for l in b
+            for n, split in _leaf_bytes(specs[l]) if split]
+
+
+def expected_ar_bytes(specs: Sequence[Any], plan: Any, *,
+                      compressor: Optional[Any] = None) -> List[int]:
+    """Expected all-reduce operand bytes of the pushes: one per leaf held
+    whole in the leaves layout (its gradient is summed, not scattered);
+    none on the flat wire."""
+    if _flat(compressor):
+        return []
+    return [n for b in plan.backward for l in b
+            for n, split in _leaf_bytes(specs[l]) if not split]
 
 
 def independent_wire_bytes(compressor: Optional[Any],
@@ -132,11 +189,13 @@ def verify_schedule(hlo: ModuleOrText, plan: Any, specs: Sequence[Any], *,
                     context: str = "") -> List[Finding]:
     """Check one compiled step's HLO against its ``BucketPlan``.
 
-    Returns an empty list iff the module contains exactly one all-gather
-    per forward bucket (plus zero3 re-gathers) and one reduce-scatter
-    per backward bucket, with operand bytes matching the ``FlatSpec``
-    byte math as a multiset, and no other cross-replica collective above
-    the scalar-loss threshold.  With a single-device ``axis_size`` XLA
+    The ``compressor`` picks the state layout, as it does in the trainer
+    (flat wire with one, leaves without).  Returns an empty list iff the
+    module contains exactly the pulls' all-gathers (plus zero3
+    re-gathers) and the pushes' reduce-scatters and all-reduces of that
+    layout (module docstring), with operand bytes matching its byte math
+    as a multiset, and no other cross-replica collective above the
+    scalar-loss threshold.  With a single-device ``axis_size`` XLA
     elides the collectives entirely, so only the stray-collective check
     runs.  The wire-byte model (compression exactness) is checked by
     :func:`verify_wire_model`, appended here when a compressor is given.
@@ -146,9 +205,13 @@ def verify_schedule(hlo: ModuleOrText, plan: Any, specs: Sequence[Any], *,
     summary = collective_summary(hlo)
     axis_size = specs[0].axis_size if len(specs) else 1
 
+    layout = "flat" if _flat(compressor) else "leaves"
+    exp_ar: List[int] = []
     if axis_size > 1:
-        exp_ag = expected_ag_bytes(specs, plan, zero3=zero3)
-        exp_rs = expected_rs_bytes(specs, plan)
+        exp_ag = expected_ag_bytes(specs, plan, zero3=zero3,
+                                   compressor=compressor)
+        exp_rs = expected_rs_bytes(specs, plan, compressor=compressor)
+        exp_ar = expected_ar_bytes(specs, plan, compressor=compressor)
         obs_ag = [b for _, b in summary["all-gather"]]
         obs_rs = [b for _, b in summary["reduce-scatter"]]
 
@@ -157,7 +220,7 @@ def verify_schedule(hlo: ModuleOrText, plan: Any, specs: Sequence[Any], *,
                 code="SCHED-AG-COUNT",
                 message=f"{len(obs_ag)} all-gathers compiled, plan "
                         f"prescribes {len(exp_ag)} "
-                        f"({len(plan.forward)} forward buckets"
+                        f"({len(plan.forward)} forward buckets, {layout}"
                         + (", zero3 re-gathers included)" if zero3 else ")"),
                 detail={"expected": len(exp_ag), "observed": len(obs_ag),
                         **ctx}))
@@ -165,7 +228,8 @@ def verify_schedule(hlo: ModuleOrText, plan: Any, specs: Sequence[Any], *,
             findings.append(Finding(
                 code="SCHED-RS-COUNT",
                 message=f"{len(obs_rs)} reduce-scatters compiled, plan "
-                        f"prescribes {len(exp_rs)} backward buckets",
+                        f"prescribes {len(exp_rs)} ({len(plan.backward)} "
+                        f"backward buckets, {layout})",
                 detail={"expected": len(exp_rs), "observed": len(obs_rs),
                         **ctx}))
 
@@ -177,7 +241,7 @@ def verify_schedule(hlo: ModuleOrText, plan: Any, specs: Sequence[Any], *,
                 findings.append(Finding(
                     code=code,
                     message=f"{kind} operand bytes do not match the "
-                            f"FlatSpec byte math: missing {missing}, "
+                            f"{layout} byte math: missing {missing}, "
                             f"unexpected {extra}",
                     detail={"expected": sorted(exp),
                             "observed": sorted(obs), **ctx}))
@@ -191,8 +255,11 @@ def verify_schedule(hlo: ModuleOrText, plan: Any, specs: Sequence[Any], *,
                         f"%{instr.name}) — the plan prescribes none",
                 detail={"opcode": kind, "name": instr.name,
                         "bytes": nbytes, **ctx}))
+    pushed_whole = Counter(exp_ar)      # the leaves' summed gradients
     for instr, nbytes in summary["all-reduce"]:
-        if nbytes > small_collective_bytes:
+        if pushed_whole[nbytes]:
+            pushed_whole[nbytes] -= 1
+        elif nbytes > small_collective_bytes:
             findings.append(Finding(
                 code="SCHED-STRAY-COLLECTIVE",
                 message=f"all-reduce of {nbytes} operand bytes "
@@ -266,8 +333,8 @@ def verify_wire_model(specs: Sequence[Any], plan: Any, compressor: Any, *,
 def verify_cache(cache: Any, *, specs: Optional[Sequence[Any]] = None,
                  zero3: bool = False, context: str = "") -> List[Finding]:
     """Retrace audit of a ``PlanStepCache``: exactly one compilation per
-    distinct ``BucketPlan``, and each cached step's collective counts
-    match its plan's bucket counts."""
+    distinct ``BucketPlan``, and each cached step's compiled pull and
+    push segments match its plan's bucket counts."""
     findings: List[Finding] = []
     ctx = {"context": context} if context else {}
     plans = cache.plans
@@ -284,9 +351,8 @@ def verify_cache(cache: Any, *, specs: Optional[Sequence[Any]] = None,
         n_ag, n_rs = cache.hlo_counts(plan)
         exp_ag = len(plan.forward)
         if zero3:
-            num_layers = max(max(b) for b in plan.forward) + 1
-            exp_ag += sum(1 for b in plan.backward
-                          if any(0 < l < num_layers - 1 for l in b))
+            exp_ag += len(_mid_buckets(
+                plan, max(max(b) for b in plan.forward) + 1))
         exp_rs = len(plan.backward)
         # one device: XLA either elides the single-replica collectives
         # or compiles them as degenerate ops — both shapes are conformant
@@ -296,7 +362,7 @@ def verify_cache(cache: Any, *, specs: Optional[Sequence[Any]] = None,
             findings.append(Finding(
                 code="SCHED-CACHE-COUNTS",
                 message=f"cached step for plan {plan} compiled "
-                        f"{n_ag} all-gathers / {n_rs} reduce-scatters, "
+                        f"{n_ag} pull / {n_rs} push segments, "
                         f"expected {exp_ag} / {exp_rs}"
                         + (" (or 0 / 0 elided)" if single_device else ""),
                 detail={"expected": [exp_ag, exp_rs],

@@ -17,6 +17,12 @@ Collective accounting rules fixed here (previously subtly wrong):
 * tuple-typed operands (and tuple-typed defs a bare operand resolves to)
   sum **all** leaves.
 
+A DynaComm segment is counted by the scope its collectives carry in
+their ``op_name`` metadata (``zero.pull.b{i}``, ``zero.regather.b{i}``,
+``zero.push.b{i}``), not by the collectives themselves: a segment of
+sharded parameter leaves moves one collective per leaf
+(:func:`segment_counts`).
+
 Pure stdlib on purpose: parsing an HLO dump must not import jax, so the
 lint/verify CLI and the golden-fixture tests stay import-light.
 """
@@ -30,6 +36,7 @@ from typing import Dict, Iterable, List, Optional, Tuple, Union
 __all__ = [
     "COLLECTIVES", "DTYPE_BYTES", "HloInstruction", "HloModule",
     "parse_hlo", "type_bytes", "collective_counts", "collective_summary",
+    "segment_counts",
 ]
 
 DTYPE_BYTES = {
@@ -48,6 +55,8 @@ _DEF_RE = re.compile(r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*")
 _OPCODE_RE = re.compile(r"\s+(?P<opcode>[\w\-]+)\((?P<rest>.*)$")
 _LEAF_RE = re.compile(r"\b([a-z0-9]+)\[([\d,]*)\]")
 _NAME_RE = re.compile(r"^%?([\w.\-]+)$")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+_SEGMENT_RE = re.compile(r"(?:^|/)zero\.(pull|regather|push)\.b(\d+)(?:/|$)")
 
 
 def type_bytes(type_str: str) -> int:
@@ -129,6 +138,7 @@ class HloInstruction:
     operands: Tuple[str, ...]      # raw operand tokens, attrs stripped
     line: int                      # 1-based line number in the dump
     is_root: bool = False
+    op_name: str = ""              # the metadata's scope path, if any
 
     @property
     def base_opcode(self) -> str:
@@ -204,10 +214,12 @@ def parse_hlo(text: str) -> HloModule:
         if not m:
             continue
         operands = tuple(_split_top_level(_operand_region(m.group("rest"))))
+        op_name = _OP_NAME_RE.search(line)
         instr = HloInstruction(
             name=d.group("name"), result_type=typed[0],
             opcode=m.group("opcode"), operands=operands, line=lineno,
-            is_root=line.lstrip().startswith("ROOT"))
+            is_root=line.lstrip().startswith("ROOT"),
+            op_name=op_name.group(1) if op_name else "")
         instructions.append(instr)
         by_name[instr.name] = instr
     return HloModule(instructions=tuple(instructions), by_name=by_name)
@@ -240,3 +252,21 @@ def collective_summary(module_or_text: ModuleOrText
     for instr in module.collectives():
         out[instr.base_opcode].append((instr, module.operand_bytes(instr)))
     return out
+
+
+def segment_counts(module_or_text: ModuleOrText) -> Tuple[int, int]:
+    """``(pulls, pushes)``: the distinct segment scopes that hold a
+    collective of their kind — ``zero.pull.b{i}`` and
+    ``zero.regather.b{i}`` an all-gather, ``zero.push.b{i}`` a
+    reduce-scatter or all-reduce — by each collective's ``op_name``.
+    However many collectives a segment moves, it counts once; a step with
+    no collective (one device) reads ``(0, 0)``."""
+    kinds = {"pull": ("all-gather",), "regather": ("all-gather",),
+             "push": ("reduce-scatter", "all-reduce")}
+    seen = set()
+    for instr in _as_module(module_or_text).collectives():
+        m = _SEGMENT_RE.search(instr.op_name)
+        if m and instr.base_opcode in kinds[m.group(1)]:
+            seen.add(m.groups())
+    pulls = sum(1 for part, _ in seen if part != "push")
+    return pulls, len(seen) - pulls

@@ -6,8 +6,8 @@
 (or, for the dynamic regimes, runs just past a re-plan boundary so the
 ``PlanStepCache`` holds real compiled steps), and checks
 
-* the compiled HLO against the active ``BucketPlan`` + ``FlatSpec``
-  byte math (:func:`~repro.analysis.conformance.verify_schedule`);
+* the compiled HLO against the active ``BucketPlan`` and the state
+  layout's byte math (:func:`~repro.analysis.conformance.verify_schedule`);
 * the compiled-step cache: one compilation per distinct plan
   (:func:`~repro.analysis.conformance.verify_cache`);
 * the compressed wire-byte accounting, exact to the integer

@@ -1,36 +1,37 @@
-"""Flat-buffer collectives: one ring collective per DynaComm segment.
+"""Bucket collectives: the pull and push of a DynaComm segment.
 
-A *sched layer*'s parameter pytree is packed into a single padded 1-D
-float32 buffer (``FlatSpec`` records the layout), so that a DynaComm
-transmission segment — a contiguous group of sched layers — becomes exactly
-one ``all-gather`` (the paper's parameter *pull*) or one ``reduce-scatter``
-(the gradient *push*) on the data axis, no matter how many tensors the
-segment contains.
+A *sched layer*'s parameter pytree is described by a ``FlatSpec`` (its
+leaves' shapes, dtypes and byte counts).  The ZeRO trainer
+(``repro.dist.zero``) holds its state in one of two layouts, and each has
+its pair of collectives here:
 
-Layout convention: every per-layer buffer is padded to a multiple of the
-data-axis size, stored sharded as ``(padded // axis,)`` per device.  To pull
-a bucket, the per-layer shards are concatenated and all-gathered once; row
-``i`` of the gathered ``(axis, S)`` result is device ``i``'s slice, so each
-layer's full buffer is recovered by slicing columns and flattening rows.
-The push is the exact transpose: per-layer full gradients are reshaped to
-``(axis, padded // axis)``, concatenated along columns, and reduce-scattered
-once along rows.
+* **leaves** (no compressor): the parameter leaves in their natural
+  shapes, each sharded over the data axis on one dimension
+  (:func:`shard_dim`: the first the axis size divides; a leaf that none
+  divides is held whole on every device).  A segment's pull
+  (:func:`gather_bucket`) all-gathers each of its leaves along that
+  dimension, and its push (:func:`reduce_scatter_bucket`)
+  reduce-scatters each leaf's gradient along it (sums a whole leaf's).
+  No leaf changes its shape or tiling on the way.
+* **flat** (a compressor models the PS wire; the flat wire is the
+  compressor's alone): the pytree packed into one padded 1-D float32
+  buffer, stored sharded as ``(padded // axis,)`` per device, because the
+  quantize and top-k kernels work on lane-dense flat blocks.  A segment
+  becomes exactly one ``all-gather`` (:func:`gather_flat_bucket`: row
+  ``i`` of the gathered ``(axis, S)`` result is device ``i``'s slice, so
+  each layer's buffer is recovered by slicing columns and flattening
+  rows) or one ``reduce-scatter`` of the compressed per-layer buffers
+  reshaped to ``(axis, padded // axis)`` and concatenated along columns
+  (:func:`compressed_reduce_scatter_bucket`).
 
-``gather_bucket`` / ``reduce_scatter_bucket`` must run inside ``shard_map``
-(they issue ``jax.lax`` collectives over a named axis).
-
-The flat buffer is the *wire format* of a segment, and the ZeRO trainer
-(``repro.dist.zero``) holds its state in it only where a wire exists: more
-than one device on the data axis, or a compressor modelling the PS wire.
-On a one-device axis with no compressor the state holds the parameter
-leaves instead and none of these functions runs — packing there would be
-a relayout of every weight each step for nothing.
+The bucket collectives must run inside ``shard_map`` (they issue
+``jax.lax`` collectives over a named axis).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Dict, List, Sequence, Tuple
+from typing import Any, Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -58,6 +59,21 @@ class FlatSpec:
     @property
     def shard_size(self) -> int:
         return self.padded // self.axis_size
+
+    @property
+    def dims(self) -> Tuple[Optional[int], ...]:
+        """Per leaf, the dimension it is sharded on in the leaves layout
+        (:func:`shard_dim`)."""
+        return tuple(shard_dim(s, self.axis_size) for s in self.shapes)
+
+
+def shard_dim(shape: Sequence[int], axis_size: int) -> Optional[int]:
+    """The dimension a leaf of ``shape`` is split on over a data axis of
+    ``axis_size`` devices: the first that ``axis_size`` divides, chosen by
+    shape alone; ``None`` where none does (a scalar, or a leaf such as a
+    ``(1,)`` bias on more than one device), and the leaf is held whole on
+    every device."""
+    return next((d for d, n in enumerate(shape) if n % axis_size == 0), None)
 
 
 def make_flat_spec(tree: Any, axis_size: int) -> FlatSpec:
@@ -143,15 +159,60 @@ def _check_bucket(specs: Sequence[FlatSpec], bucket: Sequence[int],
                          f"sharded over the same axis")
 
 
-def gather_bucket(shards: Sequence[jnp.ndarray], specs: Sequence[FlatSpec],
-                  bucket: Sequence[int], axis_name: str) -> Dict[int, Any]:
-    """Pull one bucket with a single ``all-gather``.
+def gather_bucket(shards: Mapping[int, Sequence[jnp.ndarray]],
+                  specs: Sequence[FlatSpec], bucket: Sequence[int],
+                  axis_name: str) -> Dict[int, Any]:
+    """Pull one bucket of sharded leaves: each leaf ``all-gather``-ed
+    along its :func:`shard_dim` (``tiled``, so it comes back in its
+    natural shape); a leaf held whole is used as it is.
+
+    ``shards[l]`` is layer ``l``'s local leaf shards in ``tree_flatten``
+    order.  Returns ``{layer_id: full parameter pytree}`` for every layer
+    in ``bucket``.
+    """
+    _check_bucket(specs, bucket, "gather_bucket")
+    out: Dict[int, Any] = {}
+    for l in bucket:
+        leaves = [x if d is None else
+                  jax.lax.all_gather(x, axis_name, axis=d, tiled=True)
+                  for x, d in zip(shards[l], specs[l].dims)]
+        out[l] = jax.tree_util.tree_unflatten(specs[l].treedef, leaves)
+    return out
+
+
+def reduce_scatter_bucket(grads: Dict[int, Any], specs: Sequence[FlatSpec],
+                          bucket: Sequence[int], axis_name: str
+                          ) -> Dict[int, List[jnp.ndarray]]:
+    """Push one bucket of sharded leaves: each leaf's gradient
+    ``reduce-scatter``-ed along its :func:`shard_dim`; a leaf held whole
+    is ``psum``-ed.
+
+    ``grads[l]`` is the *full* (per-device) gradient pytree of layer
+    ``l``; the result maps each layer to this device's summed share of
+    each leaf, in ``tree_flatten`` order (caller divides by the axis size
+    for the mean).
+    """
+    _check_bucket(specs, bucket, "reduce_scatter_bucket")
+    out: Dict[int, List[jnp.ndarray]] = {}
+    for l in bucket:
+        out[l] = [jax.lax.psum(g, axis_name) if d is None else
+                  jax.lax.psum_scatter(g, axis_name, scatter_dimension=d,
+                                       tiled=True)
+                  for g, d in zip(jax.tree_util.tree_leaves(grads[l]),
+                                  specs[l].dims)]
+    return out
+
+
+def gather_flat_bucket(shards: Sequence[jnp.ndarray],
+                       specs: Sequence[FlatSpec], bucket: Sequence[int],
+                       axis_name: str) -> Dict[int, Any]:
+    """Pull one bucket of flat buffers with a single ``all-gather``.
 
     ``shards[l]`` is layer ``l``'s local ``(padded_l // axis,)`` slice.
     Returns ``{layer_id: full parameter pytree}`` for every layer in
     ``bucket``.
     """
-    _check_bucket(specs, bucket, "gather_bucket")
+    _check_bucket(specs, bucket, "gather_flat_bucket")
     cols = [shards[l] for l in bucket]
     concat = cols[0] if len(cols) == 1 else jnp.concatenate(cols)
     gathered = jax.lax.all_gather(concat, axis_name)      # (axis, sum shards)
@@ -161,32 +222,6 @@ def gather_bucket(shards: Sequence[jnp.ndarray], specs: Sequence[FlatSpec],
         w = specs[l].shard_size
         full = gathered[:, off:off + w].reshape(-1)        # (padded_l,)
         out[l] = unflatten_tree(full, specs[l])
-        off += w
-    return out
-
-
-def reduce_scatter_bucket(grads: Dict[int, Any], specs: Sequence[FlatSpec],
-                          bucket: Sequence[int], axis_name: str
-                          ) -> Dict[int, jnp.ndarray]:
-    """Push one bucket with a single ``reduce-scatter``.
-
-    ``grads[l]`` is the *full* (per-device) gradient pytree of layer ``l``;
-    the result maps each layer to this device's summed ``(padded_l // axis,)``
-    gradient shard (caller divides by the axis size for the mean).
-    """
-    _check_bucket(specs, bucket, "reduce_scatter_bucket")
-    axis_size = specs[bucket[0]].axis_size
-    rows = [flatten_tree(grads[l], specs[l]).reshape(axis_size, -1)
-            for l in bucket]
-    concat = rows[0] if len(rows) == 1 else jnp.concatenate(rows, axis=1)
-    summed = jax.lax.psum_scatter(concat, axis_name, scatter_dimension=0,
-                                  tiled=True)              # (1, sum shards)
-    flat = summed.reshape(-1)
-    out: Dict[int, jnp.ndarray] = {}
-    off = 0
-    for l in bucket:
-        w = specs[l].shard_size
-        out[l] = flat[off:off + w]
         off += w
     return out
 

@@ -20,9 +20,10 @@ the two halves — ``repro.core`` decides, ``repro.dist.zero`` executes — and
   in :class:`repro.runtime.replan.ReplanMixin`, shared with the PS-regime
   driver (``repro.ps.dynamic``).
 
-Because the ZeRO state layout (one ``FlatSpec`` flat buffer per sched
-layer, or the parameter leaves on a one-device axis) depends only on the
-mesh and the compressor, states carry across plan swaps unchanged, and
+Because the ZeRO state layout (the parameter leaves, sharded on the
+data axis, or with a compressor one ``FlatSpec`` flat buffer per sched
+layer) depends only on the mesh and the compressor, states carry across
+plan swaps unchanged, and
 the loss trajectory of a dynamic run is bit-identical to running the same
 plan sequence statically (asserted by ``tests/test_dynamic.py``).
 """

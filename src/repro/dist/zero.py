@@ -4,36 +4,44 @@ The TPU-native adaptation of the paper's pull/push procedures: a
 ``BucketPlan`` (from ``repro.core.buckets``) drives a data-parallel training
 step in which
 
-* where a wire exists — the ``data`` axis has more than one device, or a
-  ``compressor`` models the PS wire — parameters live sharded as one padded
-  flat float32 buffer per sched layer (``state["flat_params"][l]`` has
-  global shape ``(spec.padded,)`` split over the ``data`` axis — ZeRO:
-  optimizer state and master weights are never replicated).  That buffer
-  is the wire format of a DynaComm segment:
-* the forward phase launches **exactly one all-gather per forward bucket**
-  (the paper's parameter pull of a transmission segment);
-* the backward phase launches **exactly one reduce-scatter per backward
-  bucket** (the gradient push), walking layers top-down with per-layer VJPs
-  so bucket boundaries are real program structure, not a post-hoc rewrite;
+* the state is the model's float32 parameter leaves in their natural
+  shapes, sched layer by sched layer (each layer's ``tree_flatten``
+  order; ``state["flat_params"]`` is that list), each leaf sharded over
+  the ``data`` axis on one dimension (``collectives.shard_dim``: the first
+  the axis size divides, chosen by shape alone) — ZeRO: master weights
+  and optimizer state are split, not replicated.  A leaf no dimension
+  divides (a ``(1,)`` bias; every leaf on an axis size that divides no
+  width) is held whole on every device, its gradient summed and its
+  update computed alike everywhere;
+* the forward phase pulls each forward bucket (the paper's parameter pull
+  of a transmission segment) by all-gathering its leaves along their
+  dimensions, together under the bucket's scope;
+* the backward phase pushes each backward bucket (the gradient push) by
+  reduce-scattering its leaves' gradients along the same dimensions,
+  walking layers top-down with per-layer VJPs so bucket boundaries are
+  real program structure, not a post-hoc rewrite;
 * with ``zero3=True`` the gathered weights are *not* kept alive across the
   forward/backward boundary: every backward bucket that contains a middle
-  layer re-pulls its parameters with one extra all-gather (first/last sched
-  layers are exempt — the head is hot at the fwd→bwd boundary and the
-  embedding VJP needs no weights).
+  layer re-pulls its parameters (first/last sched layers are exempt — the
+  head is hot at the fwd→bwd boundary and the embedding VJP needs no
+  weights).
 
-Where no wire exists — one device on the ``data`` axis and no compressor —
-a wire format is pure cost (a relayout of every weight into and out of a
-1-D buffer each step), so the state holds the parameter leaves themselves:
-``state["flat_params"]`` is the model's float32 leaves in their natural
-shapes, sched layer by sched layer (each layer's ``tree_flatten`` order).
-A bucket's pull is then its layers' leaves, its push the VJP's leaf
-gradients, and ZeRO-3's re-pull has nothing to do.  The two layouts share
-the step's structure and no packing logic; the mesh and the compressor
-pick one (``ZeroTrainer.layout``: ``"flat"`` or ``"leaves"``).
+No leaf changes shape or tiling on the way, so no relayout sits between
+the state, the wire and the compute.  On a one-device axis a shard is the
+whole leaf: pull and push issue no collective, and ZeRO-3 has nothing to
+re-pull.
+
+The flat wire is the compressor's alone: where a ``compressor`` models
+the PS wire, its quantize and top-k kernels work on lane-dense flat
+blocks, so the state is one padded flat float32 buffer per sched layer
+(global shape ``(spec.padded,)`` split over the ``data`` axis), a
+bucket's pull one all-gather and its push one compressed reduce-scatter.
+The compressor picks the layout (``ZeroTrainer.layout``: ``"leaves"`` or
+``"flat"``); the two share the step's structure and no packing logic.
 
 The step is built with ``shard_map`` so the collectives above are the
-*only* all-gathers / reduce-scatters in the compiled HLO —
-``tests/test_dist.py`` asserts the counts against the plan.
+*only* cross-device collectives in the compiled HLO, each under its
+bucket's scope — ``tests/test_dist.py`` asserts them against the plan.
 
 A looped stack (``cfg.loop_steps`` T > 1) applies each block's pulled
 weights T times and exits after every pass (``models/model.py``).  Every
@@ -51,7 +59,7 @@ attaches to its device ops: ``zero.pull.b{i}``, ``zero.fwd.L{l}``,
 stack, ``loop.t{t}`` inside a block's ``zero.fwd``/``zero.bwd`` scope for
 pass ``t``, and ``loop.exit`` on the exits (final norm, head, gate) and
 the exit objective.  Scopes are metadata only: the compiled instructions
-are the same without them.  Holding leaves, the pull, push and re-pull
+are the same without them.  On one device, the pull, push and re-pull
 scopes hold no operation.
 """
 
@@ -60,6 +68,7 @@ from __future__ import annotations
 import contextlib
 import copy
 import dataclasses
+import warnings
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -70,7 +79,8 @@ from repro.configs.base import ArchConfig
 from repro.core.buckets import BucketPlan, flat_layer_order
 from repro.dist.collectives import (FlatSpec, compressed_reduce_scatter_bucket,
                                     flatten_tree, gather_bucket,
-                                    make_flat_spec, reduce_scatter_bucket,
+                                    gather_flat_bucket, make_flat_spec,
+                                    reduce_scatter_bucket, shard_dim,
                                     unflatten_tree)
 from repro.models import blocks as blocks_lib
 from repro.models import model as model_lib
@@ -78,14 +88,15 @@ from repro.optim import Optimizer
 
 
 class _FlatLayout:
-    """A wire exists: one padded 1-D f32 buffer per sched layer, sharded
-    over the data axis; a bucket's pull is one all-gather and its push one
-    (optionally compressed) reduce-scatter."""
+    """A compressor models the PS wire: one padded 1-D f32 buffer per
+    sched layer, sharded over the data axis; a bucket's pull is one
+    all-gather and its push one compressed reduce-scatter."""
 
     name = "flat"
+    wire = True
 
     def __init__(self, specs: List[FlatSpec], axis_name: str,
-                 compressor: Optional[Any]):
+                 compressor: Any):
         self.specs, self.axis_name = specs, axis_name
         self.compressor = compressor
 
@@ -97,32 +108,47 @@ class _FlatLayout:
                 for f, s in zip(params, self.specs)]
 
     def pull(self, params, bucket) -> Dict[int, Any]:
-        return gather_bucket(params, self.specs, bucket, self.axis_name)
+        return gather_flat_bucket(params, self.specs, bucket, self.axis_name)
 
     def push(self, grads, bucket, residuals):
         """``({layer: [its mean gradient shard]}, new residuals or None)``."""
-        if self.compressor is None:
-            # resolved in this module's namespace when called, so a
-            # replaced ``zero.reduce_scatter_bucket`` takes effect
-            pushed = reduce_scatter_bucket(grads, self.specs, bucket,
-                                           self.axis_name)
-            res_out = None
-        else:
-            pushed, res_out = compressed_reduce_scatter_bucket(
-                grads, self.specs, bucket, self.axis_name, self.compressor,
-                residuals=residuals)
+        pushed, res_out = compressed_reduce_scatter_bucket(
+            grads, self.specs, bucket, self.axis_name, self.compressor,
+            residuals=residuals)
         axis_size = self.specs[bucket[0]].axis_size       # sum → mean
         return {l: [g / axis_size] for l, g in pushed.items()}, res_out
 
 
+def _warn_held_whole(specs: List[FlatSpec]) -> None:
+    """Say what ZeRO does not split: a leaf no dimension of which the
+    axis size divides keeps its weights and moments whole on every
+    device, and its gradient is summed, not scattered."""
+    axis = specs[0].axis_size
+    whole = [size for spec in specs
+             for size, dim in zip(spec.sizes, spec.dims) if dim is None]
+    if whole:
+        leaves = sum(spec.num_leaves for spec in specs)
+        total = sum(spec.total for spec in specs)
+        warnings.warn(
+            f"{len(whole)} of {leaves} parameter leaves "
+            f"({4 * sum(whole)} of {4 * total} f32 bytes) are held whole "
+            f"on each of the {axis} devices of the data axis: no "
+            f"dimension of theirs is a multiple of {axis}", stacklevel=5)
+
+
 class _LeafLayout:
-    """No wire (one device, no compressor): the state is the parameter
-    leaves, sched layer by sched layer; pull and push move nothing."""
+    """No compressor: the state is the parameter leaves, sched layer by
+    sched layer, each sharded on its ``shard_dim``.  A bucket's pull
+    all-gathers its leaves and its push reduce-scatters their gradients;
+    on one device (``wire`` false) pull and push move nothing."""
 
     name = "leaves"
 
-    def __init__(self, specs: List[FlatSpec]):
-        self.specs = specs
+    def __init__(self, specs: List[FlatSpec], axis_name: str):
+        self.specs, self.axis_name = specs, axis_name
+        self.wire = specs[0].axis_size > 1
+        if self.wire:
+            _warn_held_whole(specs)
         self._starts = [0]
         for spec in specs:
             self._starts.append(self._starts[-1] + spec.num_leaves)
@@ -130,18 +156,34 @@ class _LeafLayout:
     def init(self, trees) -> List[jnp.ndarray]:
         return [x for t in trees for x in jax.tree_util.tree_leaves(t)]
 
+    def _leaves(self, params, l: int) -> List[Any]:
+        return params[self._starts[l]:self._starts[l + 1]]
+
     def _tree(self, params, l: int) -> Any:
-        leaves = params[self._starts[l]:self._starts[l + 1]]
-        return jax.tree_util.tree_unflatten(self.specs[l].treedef, leaves)
+        return jax.tree_util.tree_unflatten(self.specs[l].treedef,
+                                            self._leaves(params, l))
 
     def to_trees(self, params) -> List[Any]:
         return [self._tree(params, l) for l in range(len(self.specs))]
 
     def pull(self, params, bucket) -> Dict[int, Any]:
-        return {l: self._tree(params, l) for l in bucket}
+        if not self.wire:
+            return {l: self._tree(params, l) for l in bucket}
+        return gather_bucket({l: self._leaves(params, l) for l in bucket},
+                             self.specs, bucket, self.axis_name)
 
     def push(self, grads, bucket, residuals):
-        return {l: jax.tree_util.tree_leaves(grads[l]) for l in bucket}, None
+        """``({layer: [each leaf's mean gradient shard]}, None)``."""
+        if not self.wire:
+            return {l: jax.tree_util.tree_leaves(grads[l])
+                    for l in bucket}, None
+        # resolved in this module's namespace when called, so a replaced
+        # ``zero.reduce_scatter_bucket`` takes effect
+        pushed = reduce_scatter_bucket(grads, self.specs, bucket,
+                                       self.axis_name)
+        axis_size = self.specs[bucket[0]].axis_size       # sum → mean
+        return {l: [g / axis_size for g in gs]
+                for l, gs in pushed.items()}, None
 
 
 @dataclasses.dataclass
@@ -175,13 +217,13 @@ class ZeroTrainer:
             for tree in model_lib.sched_layer_trees(shapes)]
         self._kinds = self.cfg.layer_kinds()
         self._layout = (
-            _LeafLayout(self.specs)
-            if self.axis_size == 1 and self.compressor is None else
+            _LeafLayout(self.specs, self.axis_name)
+            if self.compressor is None else
             _FlatLayout(self.specs, self.axis_name, self.compressor))
 
     @property
     def layout(self) -> str:
-        """``"leaves"`` (one device, no compressor: no wire) or ``"flat"``."""
+        """``"leaves"`` (no compressor) or ``"flat"`` (a compressor)."""
         return self._layout.name
 
     # ------------------------------------------------------------------
@@ -202,8 +244,8 @@ class ZeroTrainer:
     def with_plan(self, plan: BucketPlan) -> "ZeroTrainer":
         """Same trainer driving a different bucket plan.
 
-        The state layout (``FlatSpec`` per sched layer, flat buffers or
-        leaves) depends only on the architecture, the axis size and the
+        The state layout (``FlatSpec`` per sched layer, leaves or flat
+        buffers) depends only on the architecture, the axis size and the
         compressor, never on the plan — so states carry across plan swaps
         unchanged.  Shares the already-computed specs instead of re-running
         ``eval_shape``.
@@ -236,12 +278,19 @@ class ZeroTrainer:
         return state
 
     def _state_layout(self, shapes, one_d, replicated, residual):
-        """Map state leaves to shardings/specs: arrays (flat buffers, or
-        leaves on a one-device axis) split on the data axis, scalars
-        replicated, the error-feedback residuals (2-D, one row per device)
-        explicitly."""
-        out = {k: jax.tree_util.tree_map(
-                   lambda s: one_d if s.ndim >= 1 else replicated, v)
+        """Map state leaves to shardings: each array split over the data
+        axis on its ``shard_dim`` (``one_d`` is the sharding on dimension
+        0), arrays no dimension divides and scalars ``replicated``, the
+        error-feedback residuals (2-D, one row per device) ``residual``."""
+        def place(s):
+            d = shard_dim(s.shape, self.axis_size)
+            if d is None:
+                return replicated
+            if d == 0:
+                return one_d
+            return NamedSharding(one_d.mesh, P(*([None] * d), *one_d.spec))
+
+        out = {k: jax.tree_util.tree_map(place, v)
                for k, v in shapes.items() if k != "residuals"}
         if "residuals" in shapes:
             out["residuals"] = [residual for _ in shapes["residuals"]]
@@ -305,8 +354,8 @@ class ZeroTrainer:
     def build_train_step(self):
         """Returns jit-able ``step(state, batch) -> (state, mean_loss)``."""
         state_shapes = jax.eval_shape(self._make_state, jax.random.PRNGKey(0))
-        state_specs = self._state_layout(
-            state_shapes, P(self.axis_name), P(), P(self.axis_name, None))
+        state_specs = jax.tree_util.tree_map(
+            lambda s: s.spec, self._state_shardings(state_shapes))
 
         def step(state, batch):
             batch_specs = jax.tree_util.tree_map(
@@ -329,7 +378,7 @@ class ZeroTrainer:
         def loop_scope(name):
             return jax.named_scope(name) if T > 1 else contextlib.nullcontext()
 
-        # ---- pull phase: one all-gather per forward bucket --------------
+        # ---- pull phase: one pull per forward bucket ---------------------
         full: Dict[int, Any] = {}
         for i, bucket in enumerate(self.plan.forward):
             with jax.named_scope(f"zero.pull.b{i}"):
@@ -372,16 +421,16 @@ class ZeroTrainer:
         # The barrier keeps the re-gather a distinct program point from the
         # forward pull (so the forward copies are dead after their last
         # forward use and the re-gather cannot be folded into them).
-        # Holding leaves, the state is the full weights: nothing to re-pull.
+        # On one device the state is the full weights: nothing to re-pull.
         regathered: Dict[int, Any] = {}
-        if self.zero3 and layout.name == "flat":
+        if self.zero3 and layout.wire:
             barred = list(jax.lax.optimization_barrier(tuple(shards)))
             for i, bucket in enumerate(self.plan.backward):
                 if any(0 < l < Ls - 1 for l in bucket):
                     with jax.named_scope(f"zero.regather.b{i}"):
                         regathered.update(layout.pull(barred, bucket))
 
-        # ---- backward: per-layer VJPs, one reduce-scatter per bucket ----
+        # ---- backward: per-layer VJPs, one push per bucket --------------
         one = jnp.ones((), jnp.float32)
         aux_ct = jnp.asarray(self.aux_weight, jnp.float32)
         grad_parts: List[Optional[List[jnp.ndarray]]] = [None] * Ls

@@ -186,8 +186,8 @@ def _print_events(rt) -> None:
         else:                                # sync RescheduleEvent
             extra = ""
             if hasattr(rt.trainer, "hlo_counts"):
-                ag, rs = rt.trainer.hlo_counts(e.plan)
-                extra = f" (hlo {ag} ag / {rs} rs)"
+                pulls, pushes = rt.trainer.hlo_counts(e.plan)
+                extra = f" (hlo {pulls} pull / {pushes} push)"
             print(f"epoch {e.epoch:3d} step {e.step:4d}: "
                   f"{len(e.plan.forward)} pull / {len(e.plan.backward)} "
                   f"push segments{extra}  "

@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro.analysis.hlo import segment_counts
 from repro.configs import INPUT_SHAPES, get_config
 from repro.core import (LayerCosts, TPUSystemModel, costs_from_profiles,
                         evaluate, plan_from_decision, schedule)
@@ -109,6 +110,7 @@ def main():
             rec.update({
                 "hlo_all_gathers": coll["_counts"]["all-gather"],
                 "hlo_reduce_scatters": coll["_counts"]["reduce-scatter"],
+                "hlo_segments": segment_counts(hlo),
                 "coll_bytes_per_device":
                     sum(v for k, v in coll.items() if not k.startswith("_")),
                 "temp_bytes": getattr(mem, "temp_size_in_bytes", None),

@@ -17,9 +17,9 @@ layer-wise decomposition whenever the topology shifts:
   wall-clock timings of the jitted applies (re-measured every
   ``remeasure_every`` topology epochs) and are rescaled to each worker's
   compute rate — so the per-worker decompositions track real compute
-  drift, not just the analytic model.  The ZeRO/PS state layout (one
-  ``FlatSpec`` flat buffer per sched layer, or the parameter leaves on a
-  one-device axis without a compressor) is plan-independent, so
+  drift, not just the analytic model.  The ZeRO/PS state layout (the
+  parameter leaves, or with a compressor one ``FlatSpec`` flat buffer
+  per sched layer) is plan-independent, so
   states carry across swaps and the loss trajectory is bit-identical to
   statically running each epoch's plan (asserted by
   ``tests/test_dynamic.py``).

@@ -8,11 +8,12 @@ transmission per segment); the server applies the summed gradients and
 all workers observe the new version at the barrier.
 
 On the device mesh this maps exactly onto the bucketed ZeRO step: place
-server shard *s*'s partition of every layer buffer on worker device *s*
-(server shards co-located with workers, the standard sharded-PS
-deployment), and a segment pull **is** one ``all-gather``, a segment push
-**is** one ``reduce-scatter``, and the server-side optimizer apply **is**
-the sharded update on local partitions.  ``PSTrainer`` therefore drives a
+server shard *s*'s partition of every layer's weights on worker device
+*s* (server shards co-located with workers, the standard sharded-PS
+deployment), and a segment pull **is** the ``all-gather`` of its
+weights, a segment push **is** the ``reduce-scatter`` of their
+gradients, and the server-side optimizer apply **is** the sharded update
+on local partitions.  ``PSTrainer`` therefore drives a
 contained :class:`repro.dist.zero.ZeroTrainer` for the compiled data path
 — which makes sync-mode losses *bit-identical* to the ZeRO trainer by
 construction (asserted by ``tests/test_ps.py``) — and layers the PS
@@ -20,10 +21,10 @@ semantics on top: per-topology scheduling (per-worker fc/bc, per-link
 asymmetric pt/gt/Δt), per-segment transfer accounting against the
 topology's links, and the PS timeline view.
 
-The compiled HLO carries exactly ``len(plan.forward)`` all-gathers and
-``len(plan.backward)`` reduce-scatters — one pull + one push per segment,
-2 transfers per (forward, backward) segment pair — for every scheduling
-strategy.
+The compiled HLO carries exactly ``len(plan.forward)`` pulls and
+``len(plan.backward)`` pushes, each under its bucket's scope — one pull +
+one push per segment, 2 transfers per (forward, backward) segment pair —
+for every scheduling strategy (``repro.analysis.segment_counts``).
 """
 
 from __future__ import annotations
@@ -134,8 +135,8 @@ class PSTrainer:
 
     @property
     def expected_transfers(self) -> Tuple[int, int]:
-        """(pulls, pushes) per iteration == (all-gathers, reduce-scatters)
-        in the compiled HLO: one of each per segment."""
+        """(pulls, pushes) per iteration == the compiled HLO's pull and
+        push segments: one of each per segment."""
         return (self.plan.num_forward_collectives,
                 self.plan.num_backward_collectives)
 

@@ -3,7 +3,7 @@
 Hoisted out of ``repro.dist.dynamic`` so both dynamic drivers share one
 implementation: each sched layer's forward apply and VJP is jitted and
 timed standalone against a :class:`repro.core.profiler.LayerTimingHook`.
-The ZeRO and PS trainers share the flat-buffer state layout, so the same
+The ZeRO and PS trainers share the state layout, so the same
 routine measures either — the PS driver additionally rescales the host
 timings to each worker's compute rate
 (:meth:`repro.ps.topology.PSTopology.topology_costs_measured`).

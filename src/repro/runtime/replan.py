@@ -7,7 +7,8 @@ PR 2 grew a compiled-step cache and reschedule-event bookkeeping inside
 * :class:`PlanStepCache` — ``BucketPlan``-keyed AOT compiled-step cache:
   each distinct plan is traced and compiled exactly once
   (``.lower().compile()``), revisits are dictionary lookups, and per-plan
-  HLO collective counts are kept for the structural assertions;
+  compiled pull/push segment counts are kept for the structural
+  assertions;
 * :class:`RescheduleEvent` — one scheduling pass (paper Table I
   bookkeeping: scheduling wall time + the overhead-hidden check against
   the Δt + gt¹ idle window);
@@ -33,6 +34,7 @@ import jax
 import numpy as np
 
 from repro.analysis.hlo import collective_counts as _collective_counts
+from repro.analysis.hlo import segment_counts
 from repro.checkpoint.ckpt import load_checkpoint, save_checkpoint
 from repro.core.buckets import BucketPlan
 
@@ -96,7 +98,8 @@ class PlanStepCache:
         return tuple(self._steps)
 
     def hlo_counts(self, plan: BucketPlan) -> Tuple[int, int]:
-        """(#all-gathers, #reduce-scatters) of a cached plan's step."""
+        """(pull, push) segments compiled in a cached plan's step
+        (``repro.analysis.hlo.segment_counts``)."""
         if plan not in self._hlo:
             raise KeyError(f"plan {plan} has no compiled step yet")
         return self._hlo[plan]
@@ -124,7 +127,7 @@ class PlanStepCache:
         self.traces += 1
         compiled = jax.jit(build_step()).lower(state, batch).compile()
         text = compiled.as_text()
-        self._hlo[plan] = hlo_collective_counts(text)
+        self._hlo[plan] = segment_counts(text)
         self._hlo_text[plan] = text
         while len(self._hlo_text) > self.hlo_retention:
             self._hlo_text.popitem(last=False)
@@ -177,8 +180,7 @@ class ReplanMixin:
         return self._cache.hlo_evictions
 
     def hlo_counts(self, plan: Optional[BucketPlan] = None) -> Tuple[int, int]:
-        """(#all-gathers, #reduce-scatters) of a cached plan's compiled
-        step."""
+        """(pull, push) segments compiled in a cached plan's step."""
         return self._cache.hlo_counts(self._plan if plan is None else plan)
 
     # -- the shared loop body -------------------------------------------

@@ -1,13 +1,15 @@
 """Subprocess helper: a looped stack (Ouro) on the ZeRO trainer over four
-devices, where the state is the flat wire (``flat`` layout).  Prints one
-JSON line the parent asserts on:
+devices, where the state is the parameter leaves sharded over the data
+axis.  Prints one JSON line the parent asserts on:
 
 1. one step of the tiny looped model (T = 2) gives the loss and updated
    parameters of ``jax.value_and_grad`` of ``train_loss`` on the global
    batch (SGD, so the update is the gradient);
-2. the compiled step has one all-gather per forward bucket and one
-   reduce-scatter per backward bucket: the T applications of a block
-   share its pull, and its T gradients one push;
+2. the compiled step's collectives by the bucket scope in their
+   ``op_name``: each forward bucket's pull all-gathers its split leaves,
+   each backward bucket's push reduce-scatters them and all-reduces the
+   leaves held whole (the exit gate's ``(1,)`` bias): the T applications
+   of a block share its pull, and its T gradients one push;
 3. the lowered programs of a stack run once (granite-3-2b reduced, ZeRO-2
    and ZeRO-3) by their SHA-256.
 """
@@ -25,12 +27,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.analysis import collective_counts
 from repro.configs import get_config
 from repro.core import plan_from_decision, random_costs, schedule
 from repro.dist.zero import ZeroTrainer
 from repro.models import num_sched_layers, train_loss
 from repro.optim import adamw, sgd
+from zero_trainer_check import by_scope, expected_scopes
 
 
 def tiny_ouro(loops=2):
@@ -59,7 +61,7 @@ def main():
     state = tr.init_state(jax.random.PRNGKey(0))
     params = tr.params_from_state(state)
     step = jax.jit(tr.build_train_step())
-    counts = collective_counts(step.lower(state, batch).compile().as_text())
+    scopes = by_scope(step.lower(state, batch).compile().as_text())
     state, loss = step(state, batch)
     ref_loss, grads = jax.jit(jax.value_and_grad(
         lambda p: train_loss(cfg, p, batch, aux_weight=0.0)))(params)
@@ -69,8 +71,8 @@ def main():
         "layout": tr.layout, "loss": float(loss), "ref_loss": float(ref_loss),
         "param_gap": max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(
             jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want))),
-        "fwd_buckets": len(plan.forward), "ag": counts["all-gather"],
-        "bwd_buckets": len(plan.backward), "rs": counts["reduce-scatter"]}
+        "fwd_buckets": len(plan.forward), "bwd_buckets": len(plan.backward),
+        "scopes": scopes, "expected_scopes": expected_scopes(tr, plan)}
 
     dense = get_config("granite-3-2b").reduced()
     shapes = {"tokens": jax.ShapeDtypeStruct((8, 16), jnp.int32),

@@ -7,9 +7,10 @@ on:
    ``ZeroTrainer`` on the same ``BucketPlan`` (the PS sync path *is* the
    co-located sharded-PS deployment of the ZeRO step);
 2. **transfer structure** — per strategy, the compiled HLO carries
-   exactly one all-gather (pull) per forward segment and one
-   reduce-scatter (push) per backward segment: total transfers ==
-   2 collectives per (pull, push) segment pair;
+   exactly one pull per forward segment and one push per backward
+   segment (each the collectives of its leaves, counted by the bucket
+   scope in their ``op_name``): total transfers == 2 per (pull, push)
+   segment pair;
 3. **consensus scheduling** — the heterogeneous topology's consensus plan
    minimizes the synchronous straggler makespan over the per-worker
    candidates.
@@ -35,14 +36,14 @@ from repro.models import num_sched_layers
 from repro.models.profiles import layer_profiles
 from repro.optim import adamw
 from repro.ps import PSTopology, PSTrainer, asymmetric_link
-from repro.runtime.replan import hlo_collective_counts
+from repro.analysis import segment_counts
 
 B, T, STEPS = 8, 32, 3
 
 
 def hlo_counts(step, state, batch):
-    hlo = step.lower(state, batch).compile().as_text()
-    return hlo_collective_counts(hlo)
+    """(pull, push) segments compiled: bucket scopes holding collectives."""
+    return segment_counts(step.lower(state, batch).compile().as_text())
 
 
 def main():
@@ -68,7 +69,7 @@ def main():
                        topology=topo)
         state = ps.init_state(jax.random.PRNGKey(0))
         step = jax.jit(ps.build_train_step())
-        ag, rs = hlo_counts(step, state, pipe.batch(0))
+        pulled, pushed = hlo_counts(step, state, pipe.batch(0))
         losses = []
         for i in range(STEPS):
             state, loss = step(state, pipe.batch(i))
@@ -86,7 +87,7 @@ def main():
         pulls, pushes = ps.expected_transfers
         out["strategies"][strat] = {
             "fwd_segments": pulls, "bwd_segments": pushes,
-            "ag": ag, "rs": rs,
+            "pulled": pulled, "pushed": pushed,
             "losses": losses, "zero_losses": zlosses,
             "makespan": makespan,
         }

@@ -6,9 +6,10 @@ Forges ``<devices>`` host devices, then for the reduced dense and MoE
 configs (and the dense one under ZeRO-3) builds the ``zero`` runtime and
 reports, as one JSON line:
 
-* the plan (buckets, sched layers), the state layout, and every
-  ``zero.*`` / ``moe.*`` name component found in the compiled step's
-  ``op_name`` metadata;
+* the plan (buckets, sched layers), the state layout, the data axis's
+  devices, and every ``zero.*`` / ``moe.*`` name component found in the
+  compiled step's ``op_name`` metadata (under ZeRO-3, the step's as JAX
+  hands it to XLA);
 * the non-trivial instructions of the entry computation, as JAX hands the
   step to XLA, whose ``op_name`` carries no ``zero.*`` scope;
 * two steps' losses, and the same with ``jax.named_scope`` stubbed out,
@@ -110,7 +111,11 @@ def check(arch, zero3=False, trace=False):
            "backward": [list(b) for b in rt.plan.backward],
            "sched_layers": rt.trainer.num_layers,
            "layout": rt.trainer.layout,
-           "scopes": scopes(compiled),
+           "devices": rt.trainer.axis_size,
+           # under ZeRO-3 the CPU compiler merges each re-pull with its
+           # forward twin (the TPU compiler keeps them apart,
+           # tests/test_chip_compile.py): read the scopes JAX hands it
+           "scopes": scopes(lowered if zero3 else compiled),
            "unscoped": [[op, name] for op, name in entry_instructions(lowered)
                         if op not in TRIVIAL and "zero." not in name],
            "counts": opcode_counts(compiled),

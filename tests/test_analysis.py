@@ -14,11 +14,13 @@ from types import SimpleNamespace
 import pytest
 
 from repro.analysis import (collective_counts, collective_summary,
-                            lint_paths, lint_source, parse_hlo, type_bytes,
+                            lint_paths, lint_source, parse_hlo,
+                            segment_counts, type_bytes,
                             verify_cache, verify_fleet_membership,
                             verify_no_collectives, verify_push_ledger,
                             verify_schedule, verify_wire_model)
 from repro.analysis.conformance import (INT8_TILE, expected_ag_bytes,
+                                        expected_ar_bytes,
                                         expected_rs_bytes,
                                         independent_wire_bytes,
                                         segment_wire_bytes)
@@ -91,6 +93,31 @@ class TestHloParser:
         assert [b for _, b in summary["all-reduce"]] == [4 * 2 * 2]
         done = [i for i in mod.instructions if i.is_async_done]
         assert len(done) == 3 and not any(i.is_collective for i in done)
+
+    def test_segment_counts_by_scope(self):
+        """A segment counts once however many collectives it moves, by
+        the scope in their ``op_name``; a collective of the other kind,
+        or under no segment scope, counts for none."""
+        def op(name, kind, scope):
+            return (f"  %{name} = f32[8]{{0}} {kind}(f32[4]{{0}} %p), "
+                    f"metadata={{op_name=\"jit(step)/shard_map/{scope}\"}}")
+        text = "\n".join([
+            "HloModule m", "ENTRY %e (p: f32[4]) {",
+            op("ag.1", "all-gather", "zero.pull.b0/all_gather"),
+            op("ag.2", "all-gather", "zero.pull.b0/all_gather"),
+            op("ag.3", "all-gather-start", "zero.pull.b1/all_gather"),
+            op("ag.4", "all-gather", "zero.regather.b0/all_gather"),
+            op("rs.1", "reduce-scatter", "zero.push.b0/reduce_scatter"),
+            op("rs.2", "reduce-scatter", "zero.push.b0/reduce_scatter"),
+            op("ar.1", "all-reduce", "zero.push.b1/psum"),
+            op("ar.2", "all-reduce", "psum"),
+            op("ag.5", "all-gather", "zero.push.b2/all_gather"),
+            op("ag.6", "all-gather", "zero.fwd.L1/all_gather"),
+            "  ROOT %p = f32[4] parameter(0)", "}"])
+        assert parse_hlo(text).by_name["ag.1"].op_name == \
+            "jit(step)/shard_map/zero.pull.b0/all_gather"
+        assert segment_counts(text) == (3, 2)
+        assert segment_counts(fixture("inline_operands.txt")) == (0, 0)
 
     def test_tpu_layouts_parsed(self):
         # TPU layouts carry parentheses ({0:T(1024)}, {1,0:T(8,128)(2,1)}),
@@ -175,13 +202,96 @@ def synth_hlo(specs, plan, *, zero3=False, extra_lines=()):
     return "\n".join(lines)
 
 
+def fake_leaf_specs(num_layers, axis_size=2):
+    """FlatSpec stand-ins with leaf shapes, for the leaves layout: per
+    layer a matrix split on dimension 0, one whose rows the axis does not
+    divide (split on dimension 1), and a ``(1,)`` bias held whole."""
+    specs = []
+    for l in range(num_layers):
+        shapes = ((8 * (l + 1), 4), (3, 2 * (l + 2)), (1,))
+        specs.append(SimpleNamespace(
+            shapes=shapes, axis_size=axis_size,
+            total=sum(a * b for a, b in shapes[:2]) + 1))
+    return specs
+
+
+def _f32(shape):
+    return f"f32[{','.join(map(str, shape))}]"
+
+
+def synth_leaves_hlo(specs, plan, *, zero3=False, extra_lines=()):
+    """Module text with exactly the collectives a leaves state's pulls and
+    pushes issue: an all-gather of each split leaf's shard per pull, a
+    reduce-scatter of each split leaf's gradient and an all-reduce of
+    each whole one per push."""
+    axis = specs[0].axis_size
+    lines = ["HloModule synth", "", "ENTRY %main.1 (p: f32[1,1]) {"]
+    n = 0
+
+    def split(shape):
+        d = next((d for d, k in enumerate(shape) if k % axis == 0), None)
+        if d is None:
+            return None
+        return d, tuple(k // axis if i == d else k
+                        for i, k in enumerate(shape))
+
+    def gather(bucket):
+        nonlocal n
+        for l in bucket:
+            for shape in specs[l].shapes:
+                if split(shape) is None:
+                    continue
+                d, shard = split(shape)
+                n += 1
+                lines.append(
+                    f"  %all-gather.{n} = {_f32(shape)} all-gather("
+                    f"{_f32(shard)} %p.{n}), replica_groups={{{{0,1}}}}, "
+                    f"dimensions={{{d}}}")
+
+    for bucket in plan.forward:
+        gather(bucket)
+    if zero3:
+        for bucket in plan.backward:
+            if any(0 < l < len(specs) - 1 for l in bucket):
+                gather(bucket)
+    for bucket in plan.backward:
+        for l in bucket:
+            for shape in specs[l].shapes:
+                n += 1
+                if split(shape) is None:
+                    lines.append(
+                        f"  %all-reduce.{n} = {_f32(shape)} all-reduce("
+                        f"{_f32(shape)} %g.{n}), to_apply=%sum")
+                    continue
+                d, shard = split(shape)
+                lines.append(
+                    f"  %reduce-scatter.{n} = {_f32(shard)} reduce-scatter("
+                    f"{_f32(shape)} %g.{n}), replica_groups={{{{0,1}}}}, "
+                    f"dimensions={{{d}}}, to_apply=%sum")
+    lines.append("  %all-reduce.loss = f32[] all-reduce(f32[] %l), "
+                 "to_apply=%sum")
+    lines.extend(extra_lines)
+    lines.append("  ROOT %tuple.99 = f32[1,1] copy(f32[1,1] %p)")
+    lines.append("}")
+    return "\n".join(lines)
+
+
 def plan_for(strat, num_layers=8):
     costs = random_costs(num_layers, seed=0, dt=1e-3)
     f, b = schedule(costs, strat)
     return plan_from_decision(f, b, num_layers)
 
 
+def flat_wire():
+    """A compressor: the trainer holds the flat wire only with one, and
+    the conformance byte math follows it."""
+    from repro.compress.compressor import make_compressor
+    return make_compressor("int8")
+
+
 class TestConformance:
+    """The flat wire's byte math: one all-gather per forward bucket, one
+    reduce-scatter per backward bucket (a compressor's step)."""
 
     @pytest.mark.parametrize("strat", STRATEGIES)
     @pytest.mark.parametrize("zero3", [False, True],
@@ -190,7 +300,8 @@ class TestConformance:
         plan = plan_for(strat)
         specs = fake_specs(8)
         hlo = synth_hlo(specs, plan, zero3=zero3)
-        assert verify_schedule(hlo, plan, specs, zero3=zero3) == []
+        assert verify_schedule(hlo, plan, specs, zero3=zero3,
+                               compressor=flat_wire()) == []
 
     @pytest.mark.parametrize("strat", STRATEGIES)
     @pytest.mark.parametrize("scheme", ["int8", "topk"])
@@ -216,7 +327,8 @@ class TestConformance:
             forward=(plan.forward[0] + plan.forward[1],)
             + plan.forward[2:],
             backward=plan.backward)
-        findings = verify_schedule(hlo, corrupted, specs)
+        findings = verify_schedule(hlo, corrupted, specs,
+                                   compressor=flat_wire())
         assert findings
         assert {f.code for f in findings} <= {
             "SCHED-AG-COUNT", "SCHED-AG-BYTES"}
@@ -231,7 +343,8 @@ class TestConformance:
         tampered = hlo.replace(
             first_rs, first_rs.replace(f"[2,{shard}]", f"[2,{shard + 7}]"))
         assert tampered != hlo
-        codes = {f.code for f in verify_schedule(tampered, plan, specs)}
+        codes = {f.code for f in verify_schedule(tampered, plan, specs,
+                                                 compressor=flat_wire())}
         assert "SCHED-RS-BYTES" in codes
 
     def test_stray_collectives_flagged(self):
@@ -243,7 +356,7 @@ class TestConformance:
             "  %all-reduce.51 = f32[1,4096] all-reduce(f32[1,4096] %g.9), "
             "to_apply=%sum",
         ])
-        findings = verify_schedule(hlo, plan, specs)
+        findings = verify_schedule(hlo, plan, specs, compressor=flat_wire())
         assert [f.code for f in findings] == ["SCHED-STRAY-COLLECTIVE"] * 2
         flagged = {f.detail["opcode"] for f in findings}
         assert flagged == {"all-to-all", "all-reduce"}
@@ -276,17 +389,78 @@ class TestConformance:
     def test_expected_byte_math(self):
         plan = plan_for("ibatch", num_layers=6)
         specs = fake_specs(6, axis_size=2)
-        ag = expected_ag_bytes(specs, plan)
+        ag = expected_ag_bytes(specs, plan, compressor=flat_wire())
         assert len(ag) == len(plan.forward)
         assert ag[0] == 4 * sum(specs[l].padded // 2
                                 for l in plan.forward[0])
-        rs = expected_rs_bytes(specs, plan)
+        rs = expected_rs_bytes(specs, plan, compressor=flat_wire())
         assert len(rs) == len(plan.backward)
         assert rs[-1] == 4 * sum(specs[l].padded
                                  for l in plan.backward[-1])
-        extra = expected_ag_bytes(specs, plan, zero3=True)
+        extra = expected_ag_bytes(specs, plan, zero3=True,
+                                  compressor=flat_wire())
         mid = sum(1 for b in plan.backward if any(0 < l < 5 for l in b))
         assert len(extra) == len(plan.forward) + mid
+
+
+class TestLeavesConformance:
+    """The leaves layout's byte math: per split leaf one all-gather of its
+    shard in a pull and one reduce-scatter of its gradient in a push, an
+    all-reduce of each leaf held whole (a step with no compressor)."""
+
+    @pytest.mark.parametrize("strat", STRATEGIES)
+    @pytest.mark.parametrize("zero3", [False, True],
+                             ids=["zero", "zero3"])
+    def test_all_strategies_conform(self, strat, zero3):
+        plan = plan_for(strat)
+        specs = fake_leaf_specs(8)
+        hlo = synth_leaves_hlo(specs, plan, zero3=zero3)
+        assert verify_schedule(hlo, plan, specs, zero3=zero3) == []
+
+    def test_expected_byte_math(self):
+        plan = plan_for("ibatch", num_layers=6)
+        specs = fake_leaf_specs(6)
+        ag = expected_ag_bytes(specs, plan)
+        first = plan.forward[0]
+        # two split leaves a layer, each gathered from its half
+        assert ag[:2 * len(first)] == [
+            n for l in first for n in (4 * 8 * (l + 1) * 4 // 2,
+                                       4 * 3 * 2 * (l + 2) // 2)]
+        rs = expected_rs_bytes(specs, plan)
+        assert len(rs) == 2 * 6
+        assert sorted(rs) == sorted(
+            n for l in range(6) for n in (4 * 8 * (l + 1) * 4,
+                                          4 * 3 * 2 * (l + 2)))
+        assert expected_ar_bytes(specs, plan) == [4] * 6
+        assert expected_ar_bytes(specs, plan, compressor=flat_wire()) == []
+        mid = sum(1 for b in plan.backward if any(0 < l < 5 for l in b))
+        extra = expected_ag_bytes(specs, plan, zero3=True)
+        assert len(extra) == 2 * 6 + 2 * sum(
+            len(b) for b in plan.backward if any(0 < l < 5 for l in b))
+        assert mid >= 1
+
+    def test_flat_byte_math_does_not_pass_a_leaves_step(self):
+        plan = plan_for("dynacomm")
+        specs = fake_leaf_specs(8)
+        for l, spec in enumerate(specs):
+            spec.padded = -(-spec.total // 2) * 2
+        hlo = synth_leaves_hlo(specs, plan)
+        codes = {f.code for f in verify_schedule(hlo, plan, specs,
+                                                 compressor=flat_wire())}
+        assert {"SCHED-AG-COUNT", "SCHED-RS-COUNT"} <= codes
+
+    def test_missing_leaf_and_stray_all_reduce_flagged(self):
+        plan = plan_for("lbl")
+        specs = fake_leaf_specs(8)
+        hlo = synth_leaves_hlo(specs, plan, extra_lines=[
+            "  %all-reduce.51 = f32[1,4096] all-reduce(f32[1,4096] %g.9), "
+            "to_apply=%sum"])
+        first_ag = next(line for line in hlo.splitlines()
+                        if "all-gather(" in line)
+        hlo = hlo.replace(first_ag + "\n", "")
+        codes = [f.code for f in verify_schedule(hlo, plan, specs)]
+        assert sorted(codes) == ["SCHED-AG-BYTES", "SCHED-AG-COUNT",
+                                 "SCHED-STRAY-COLLECTIVE"]
 
 
 class TestWireModel:

@@ -4,7 +4,8 @@ set of weights, with an exit head and gate after every pass.
 On the CPU at a tiny size, with seeded random weights: the model's loss
 and gradients against the plain reference of ``chipbench/reference/
 ouro.py``; the ZeRO step against ``jax.value_and_grad`` of the model's
-loss on one device (``leaves``) and on four (``flat``, in a subprocess);
+loss on one device and on four (the state as parameter leaves, sharded on
+four, in a subprocess);
 the step's scopes; and, at T = 1, the programs of a stack run once as they
 were before the loop existed.
 """
@@ -36,7 +37,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 #: ``random_costs(seed=0, dt=1e-3)``), as they were before the loop: the
 #: one-device ZeRO step and ``value_and_grad`` of ``train_loss``; and the
 #: four-device ZeRO-2 and ZeRO-3 steps at batch 8 x 16
-#: (``helpers/looped_check.py``)
+#: (``helpers/looped_check.py``), as they lower with the state as
+#: sharded parameter leaves
 ONE_PASS = {
     "granite-3-2b": (
         "0f1c802a5c525a01", "6e383125ab4b33cc"),
@@ -45,9 +47,9 @@ ONE_PASS = {
 }
 ONE_PASS_FOUR_DEVICES = {
     "zero3=False":
-        "824bedd55887bf6b4774c8e30f1ddaf86638f1fc121a700469b4b91792484889",
+        "11219fc54a9687b4206a330e94aaf4f499898ab4a92a90a8c8e3d2c6e8ac943b",
     "zero3=True":
-        "ca9faab41b2aa635b9ca394a8bf524944f7a205b68c2e34b7eba8cdab72080a6",
+        "0a41b8b5f0b925cde97c88f88c0f307fd646805ec4fe9d739a11f941c49be54a",
 }
 
 
@@ -202,15 +204,22 @@ class TestFourDevices:
         return json.loads(proc.stdout.strip().splitlines()[-1])
 
     def test_flat_step_matches_value_and_grad(self, result):
+        """The four-device step, its state the parameter leaves sharded
+        over the data axis (no compressor, so no flat wire)."""
         r = result["looped"]
-        assert r["layout"] == "flat"
+        assert r["layout"] == "leaves"
         assert r["loss"] == pytest.approx(r["ref_loss"], rel=1e-6)
         assert r["param_gap"] < 1e-6
 
     def test_one_push_per_backward_bucket(self, result):
+        """One pull scope per forward bucket and one push scope per
+        backward bucket, each holding exactly its leaves' collectives."""
         r = result["looped"]
-        assert r["ag"] == r["fwd_buckets"]
-        assert r["rs"] == r["bwd_buckets"]
+        assert r["scopes"] == r["expected_scopes"], r
+        assert len([k for k in r["scopes"] if k.startswith("pull.")]) \
+            == r["fwd_buckets"]
+        assert len([k for k in r["scopes"] if k.startswith("push.")]) \
+            == r["bwd_buckets"]
 
     def test_one_pass_programs_are_unchanged(self, result):
         assert result["one_pass_sha256"] == ONE_PASS_FOUR_DEVICES

@@ -672,12 +672,13 @@ class TestPSTrainerMultiDevice:
             assert r["losses"] == r["zero_losses"], strat
 
     def test_one_pull_one_push_per_segment(self, result):
-        """HLO transfers == 2x segment count: one all-gather per forward
-        segment, one reduce-scatter per backward segment, all strategies."""
+        """HLO transfers == 2x segment count: one pull per forward
+        segment, one push per backward segment (each the collectives of
+        its leaves, under its own bucket scope), all strategies."""
         for strat, r in result["strategies"].items():
-            assert r["ag"] == r["fwd_segments"], (strat, r)
-            assert r["rs"] == r["bwd_segments"], (strat, r)
-            assert r["ag"] + r["rs"] == \
+            assert r["pulled"] == r["fwd_segments"], (strat, r)
+            assert r["pushed"] == r["bwd_segments"], (strat, r)
+            assert r["pulled"] + r["pushed"] == \
                 r["fwd_segments"] + r["bwd_segments"], (strat, r)
 
     def test_strategies_produce_distinct_segmentations(self, result):

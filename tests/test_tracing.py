@@ -8,9 +8,9 @@ The runtime marks the host's data fetch, dispatch and loss read with
 ``jax.profiler.TraceAnnotation`` spans (``repro.data``,
 ``repro.dispatch``, ``repro.sync``).  The checks run in a subprocess
 (``helpers/tracing_check.py``) on 1 and on 4 forged CPU devices, for the
-reduced dense and MoE configs and the dense one under ZeRO-3.  On one
-device the state is the parameter leaves: pull, push and re-pull do
-nothing there, and carry no scope.
+reduced dense and MoE configs and the dense one under ZeRO-3.  The state
+is the parameter leaves; on one device pull, push and re-pull do nothing,
+and carry no scope.
 """
 
 import json
@@ -45,7 +45,8 @@ def test_every_layer_and_bucket_has_its_scope(result, case):
         assert f"zero.fwd.L{l}" in scopes and f"zero.bwd.L{l}" in scopes
     assert "zero.opt" in scopes
     wire = {s for s in scopes if s.startswith(("zero.pull.", "zero.push."))}
-    if r["layout"] == "leaves":
+    assert r["layout"] == "leaves"
+    if r["devices"] == 1:
         # one device, no wire: pull and push are the leaves themselves
         assert not wire, wire
         return
@@ -61,8 +62,8 @@ def test_regather_scopes_under_zero3(result):
               if any(0 < l < r["sched_layers"] - 1 for l in b)]
     assert middle
     regathers = {s for s in r["scopes"] if s.startswith("zero.regather.")}
-    # holding leaves, the state is the full weights: nothing to re-pull
-    assert regathers == (set() if r["layout"] == "leaves" else
+    # on one device the state is the full weights: nothing to re-pull
+    assert regathers == (set() if r["devices"] == 1 else
                          {f"zero.regather.b{i}" for i in middle})
     assert not any(s.startswith("zero.regather.")
                    for s in result["dense"]["scopes"])
