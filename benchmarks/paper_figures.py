@@ -302,10 +302,10 @@ def dt_regime_ablation() -> List[Dict]:
 
 
 def dynamic_rescheduling() -> List[Dict]:
-    """Run-time loop payoff (the DynamicTrainer subsystem, cost-model view):
-    the uplink drops by ``drift``×, and we compare keeping the stale
-    10 Gbps-era decision against re-planning on the epoch boundary —
-    exactly what ``repro.dist.dynamic.DynamicTrainer`` automates.  The gap
+    """Run-time loop payoff (the ``zero`` runtime's re-planning loop,
+    cost-model view): the uplink drops by ``drift``×, and we compare
+    keeping the stale 10 Gbps-era decision against re-planning on the
+    epoch boundary — exactly what that loop automates.  The gap
     is the price of *not* being dynamic (paper Section IV-C motivation)."""
     rows = []
     for model in MODELS:

@@ -17,8 +17,8 @@ Three benches:
 * ``dynamic_ps_drift`` — the run-time loop's payoff in the PS regime:
   per-epoch uplink degradation over the paper's CNN cost tables,
   comparing each epoch's re-planned consensus makespan against freezing
-  the epoch-0 plan (the stale-plan penalty ``DynamicPSTrainer`` exists
-  to reclaim), plus the Table I scheduling-overhead-hidden check.
+  the epoch-0 plan (the stale-plan penalty the ``ps`` runtime's
+  re-planning loop exists to reclaim), plus the Table I scheduling-overhead-hidden check.
   CI publishes this bench as ``BENCH_dynamic_ps.json``.
 """
 

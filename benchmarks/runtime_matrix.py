@@ -12,13 +12,14 @@ under that regime's communication pattern and the speedup over the
 unoverlapped sequential baseline of the same regime:
 
 * ``local`` — pure compute, no communication (the floor);
-* ``zero`` / ``dynamic`` — single shared uplink, DynaComm vs sequential
-  decomposition (``dynamic`` priced after its mid-run bandwidth shift);
-* ``ps`` / ``dynamic-ps`` — heterogeneous PS fleet, consensus decision,
-  straggler makespan;
+* ``zero`` — single shared uplink, DynaComm vs sequential decomposition;
+  ``zero-shifted`` prices it after a mid-run bandwidth shift to
+  ``SHIFT_GBPS``, the plan its re-planning loop moves to;
+* ``ps`` — heterogeneous PS fleet, consensus decision, straggler
+  makespan; ``ps-shifted`` after every uplink shifts to ``SHIFT_GBPS``;
 * ``ps-async`` / ``dynamic-ps-async`` / ``fleet-async`` — per-worker
-  decisions, mean worker iteration (``fleet-async`` adds a 4x
-  straggler to the roster);
+  decisions, mean worker iteration (``dynamic-ps-async`` after the
+  uplink shift; ``fleet-async`` adds a 4x straggler to the roster);
 * ``pipeline`` — 4-stage balanced partition, 1F1B replay with
   DynaComm-segmented boundary transfers vs whole-tensor.
 """
@@ -35,7 +36,7 @@ FAMILIES = (
 )
 
 BANDWIDTH_GBPS = 1.0
-SHIFT_GBPS = 0.25            # the dynamic regimes' mid-run drift target
+SHIFT_GBPS = 0.25            # the shifted rows' mid-run drift target
 COMPUTE_FLOPS = 1e12
 STAGES = 4
 MICROBATCHES = 4
@@ -56,7 +57,7 @@ def _profiles(family: str, model: str):
     return layer_profiles(cfg, shape), act
 
 
-def _topology(straggler: bool = False):
+def _topology(straggler: bool = False, up_gbps: float = BANDWIDTH_GBPS):
     from repro.ps import PSTopology, asymmetric_link
     workers = 4
     flops = [COMPUTE_FLOPS if w < workers // 2 else COMPUTE_FLOPS / 2
@@ -65,14 +66,14 @@ def _topology(straggler: bool = False):
         flops[-1] = COMPUTE_FLOPS / 4
     return PSTopology(
         num_servers=2,
-        links=tuple(asymmetric_link(2e9, BANDWIDTH_GBPS * 1e9,
+        links=tuple(asymmetric_link(2e9, up_gbps * 1e9,
                                     rtt_s=0.01, setup_s=0.003)
                     for _ in range(workers)),
         worker_flops=tuple(flops))
 
 
 def _single_link_rows(family, model, profiles, net_gbps):
-    """zero/dynamic: one uplink, DynaComm vs sequential decomposition."""
+    """zero: one uplink, DynaComm vs sequential decomposition."""
     from repro.core import (EdgeNetworkModel, costs_from_profiles,
                             iteration_time, schedule)
     net = EdgeNetworkModel(bandwidth_bps=net_gbps * 1e9)
@@ -83,12 +84,14 @@ def _single_link_rows(family, model, profiles, net_gbps):
     return (iteration_time(costs, *dyn), iteration_time(costs, *seq))
 
 
-def _ps_rows(family, model, profiles, *, straggler=False):
+def _ps_rows(family, model, profiles, *, straggler=False,
+             up_gbps=BANDWIDTH_GBPS):
     """(consensus makespan, per-worker mean, sequential makespan)."""
     import numpy as np
 
     from repro.core import consensus_decision, iteration_time, schedule
-    topo = _topology(straggler=straggler).topology_costs(profiles)
+    topo = _topology(straggler=straggler,
+                     up_gbps=up_gbps).topology_costs(profiles)
     _, makespan = consensus_decision(topo, "dynacomm")
     _, seq_makespan = consensus_decision(topo, "sequential")
     per_worker = [iteration_time(c, *schedule(c, "dynacomm"))
@@ -148,13 +151,15 @@ def runtime_matrix() -> List[Dict]:
         row("zero", dyn, seq)
         dyn_s, seq_s = _single_link_rows(family, model, profiles,
                                          SHIFT_GBPS)
-        row("dynamic", dyn_s, seq_s, shifted_gbps=SHIFT_GBPS)
+        row("zero-shifted", dyn_s, seq_s, shifted_gbps=SHIFT_GBPS)
 
         mk, mean_w, seq_mk = _ps_rows(family, model, profiles)
         row("ps", mk, seq_mk)
         row("ps-async", mean_w, seq_mk)
-        row("dynamic-ps", mk, seq_mk, shifted_gbps=SHIFT_GBPS)
-        row("dynamic-ps-async", mean_w, seq_mk, shifted_gbps=SHIFT_GBPS)
+        mk_s, mean_s, seq_s = _ps_rows(family, model, profiles,
+                                       up_gbps=SHIFT_GBPS)
+        row("ps-shifted", mk_s, seq_s, shifted_gbps=SHIFT_GBPS)
+        row("dynamic-ps-async", mean_s, seq_s, shifted_gbps=SHIFT_GBPS)
         mk_f, mean_f, seq_f = _ps_rows(family, model, profiles,
                                        straggler=True)
         row("fleet-async", mean_f, seq_f, straggler_makespan=round(mk_f, 4))

@@ -4,7 +4,7 @@ The run-time loop of the paper (Section IV-C), end to end: a ~100M-param
 model trains under the DynaComm-bucketed ZeRO trainer while the edge
 uplink degrades from 10 Gbps to 1 Gbps at ``--shift-epoch``.  On the epoch
 boundary the profiler re-derives pt/gt/Δt from the new network condition,
-the DP re-plans, and the dynamic runtime swaps in the compiled step for
+the DP re-plans, and the ``zero`` runtime swaps in the compiled step for
 the new bucket plan (cached by plan, so a later recovery to 10 Gbps swaps
 back without re-tracing).  The whole regime — drifting network included —
 is one ``RuntimeConfig`` literal built through ``build_runtime``; the
@@ -52,7 +52,7 @@ def main():
           f"epoch {args.shift_epoch}")
 
     config = RuntimeConfig(
-        runtime="dynamic", arch=cfg.name, batch=args.batch, seq=args.seq,
+        runtime="zero", arch=cfg.name, batch=args.batch, seq=args.seq,
         schedule=ScheduleConfig(
             reschedule_every=args.steps_per_epoch,
             network=NetworkConfig(bandwidth_gbps=args.bw_gbps,
@@ -65,15 +65,14 @@ def main():
     while done < args.steps:
         losses = rt.fit(min(10, args.steps - done))
         done += len(losses)
-        print(f"step {done:4d}  epoch {rt.trainer.epoch}  "
+        print(f"step {done:4d}  epoch {rt.epoch}  "
               f"loss {losses[-1]:.4f}  buckets "
               f"{len(rt.plan.forward)}/{len(rt.plan.backward)}")
 
-    dyn, net = rt.trainer, rt.trainer.network
     print("\nre-scheduling history:")
     shown = set()
     for e in rt.events:
-        pulls, pushes = dyn.hlo_counts(e.plan)
+        pulls, pushes = rt.hlo_counts(e.plan)
         print(f"  epoch {e.epoch:3d}: {len(e.plan.forward)} pull / "
               f"{len(e.plan.backward)} push buckets "
               f"(hlo {pulls} pull / {pushes} push)  "
@@ -83,17 +82,17 @@ def main():
               f"hidden={e.overhead_hidden}")
         if e.plan not in shown:
             shown.add(e.plan)
-            costs = dyn.costs_for_epoch(e.epoch, None, None)
+            costs = rt.costs_for_epoch(e.epoch)
             # forward buckets back to the paper's 1-indexed segments
             segments = tuple((b[0] + 1, b[-1] + 1) for b in e.plan.forward)
-            bw = net.model_at(e.epoch).bandwidth_bps / 1e9
+            bw = rt.network.model_at(e.epoch).bandwidth_bps / 1e9
             print(f"  --- forward timeline at {bw:g} Gbps ---")
             for line in render_timeline(costs, segments,
                                         phase="forward").splitlines():
                 print(f"  {line}")
 
     changed = any(e.plan_changed for e in rt.events)
-    print(f"\nplans traced: {dyn.traces}  cache hits: {dyn.cache_hits}")
+    print(f"\nplans traced: {rt.traces}  cache hits: {rt.cache_hits}")
     print("schedule re-segmented under drift" if changed
           else "WARNING: decision did not change — try --worker-flops 1e9")
 

@@ -4,8 +4,8 @@ SSP wait-at-barrier vs stale-push rejection, and BSP push aggregation.
 Three acts:
 
 1. **run-time re-planning** — every worker's uplink degrades mid-training
-   (``--up-factor``× slower at ``--shift-epoch``).  The ``dynamic-ps``
-   runtime — one ``RuntimeConfig`` literal through ``build_runtime`` —
+   (``--up-factor``× slower at ``--shift-epoch``).  The ``ps`` runtime
+   — one ``RuntimeConfig`` literal through ``build_runtime`` —
    re-projects the topology's costs on each epoch boundary, re-runs the
    straggler-minimizing consensus decision, and swaps the compiled
    pull/push step from its plan-keyed AOT cache;
@@ -53,7 +53,7 @@ def main():
     # --- 1. re-planning across an uplink degradation -------------------
     n_dev = len(jax.devices())
     config = RuntimeConfig(
-        runtime="dynamic-ps", arch=args.arch, batch=args.batch,
+        runtime="ps", arch=args.arch, batch=args.batch,
         seq=args.seq, optimizer="adamw", lr=1e-3,
         schedule=ScheduleConfig(
             reschedule_every=args.steps_per_epoch,
@@ -69,18 +69,18 @@ def main():
     while done < args.steps:
         losses = rt.fit(min(4, args.steps - done))
         done += len(losses)
-        print(f"  step {done:4d}  epoch {rt.trainer.epoch}  "
+        print(f"  step {done:4d}  epoch {rt.epoch}  "
               f"loss {losses[-1]:.4f}")
     for e in rt.events:
-        pulls, pushes = rt.trainer.hlo_counts(e.plan)
+        pulls, pushes = rt.hlo_counts(e.plan)
         print(f"  epoch {e.epoch}: {len(e.plan.forward)} pull / "
               f"{len(e.plan.backward)} push segments (hlo {pulls} pull/"
               f"{pushes} push) "
               f"{'re-segmented' if e.plan_changed else 'unchanged'}, "
               f"sched {e.scheduling_seconds * 1e3:.2f} ms, "
               f"hidden={e.overhead_hidden}")
-    print(f"  traces {rt.trainer.traces} (one per distinct plan), cache "
-          f"hits {rt.trainer.cache_hits}\n")
+    print(f"  traces {rt.traces} (one per distinct plan), cache "
+          f"hits {rt.cache_hits}\n")
 
     # --- 2+3. throttles on the smoke CNN (library API: the factory is
     # arch-scoped; the CNN demos drive AsyncPSTrainer directly) ---------

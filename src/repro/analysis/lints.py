@@ -1,6 +1,6 @@
 """AST lints for repo-specific determinism hazards (layer 2).
 
-Five rules, each motivated by a class of bug this codebase has to stay
+Four rules, each motivated by a class of bug this codebase has to stay
 immune to (bit-identical losses across strategies, deterministic
 discrete-event replay, TPU/CPU kernel parity):
 
@@ -22,9 +22,6 @@ discrete-event replay, TPU/CPU kernel parity):
   through ``repro._compat.pallas.default_interpret``/
   ``resolve_interpret`` so the same code runs fused on TPU and
   interpreted elsewhere.
-* ``DEPRECATED-IMPORT`` — importing names that moved to
-  ``repro.runtime.replan`` from the ``repro.dist.dynamic`` /
-  ``repro.ps.dynamic`` alias paths.
 
 Suppression: append ``# noqa`` (all codes) or ``# noqa: DET-RANDOM``
 (specific codes, comma-separated) to the flagged line.
@@ -46,13 +43,7 @@ __all__ = ["LintConfig", "LINT_CODES", "lint_source", "lint_file",
            "lint_paths"]
 
 LINT_CODES = ("DET-RANDOM", "DET-WALL-CLOCK", "DET-DICT-ORDER",
-              "KERNEL-INTERPRET", "DEPRECATED-IMPORT")
-
-#: Names whose canonical home is ``repro.runtime.replan``.
-MOVED_REPLAN_NAMES = frozenset({
-    "PlanStepCache", "RescheduleEvent", "hlo_collective_counts",
-    "sequential_plan", "ReplanMixin"})
-DEPRECATED_ALIAS_MODULES = ("repro.dist.dynamic", "repro.ps.dynamic")
+              "KERNEL-INTERPRET")
 
 # numpy.random attributes that are explicit-seed constructions, not
 # draws from the hidden global state.
@@ -170,14 +161,6 @@ class _Linter(ast.NodeVisitor):
     def visit_ImportFrom(self, node: ast.ImportFrom) -> None:
         mod = node.module or ""
         names = {a.name: (a.asname or a.name) for a in node.names}
-        if mod in DEPRECATED_ALIAS_MODULES:
-            moved = sorted(set(names) & MOVED_REPLAN_NAMES)
-            if moved:
-                self.flag(
-                    "DEPRECATED-IMPORT", node,
-                    f"{', '.join(moved)} moved to repro.runtime.replan; "
-                    f"the {mod} alias path is a deprecation shim",
-                    module=mod, names=moved)
         if mod == "random":
             drawn = sorted(n for n in names if n not in _PY_RANDOM_SAFE)
             if drawn:

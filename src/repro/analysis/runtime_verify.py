@@ -3,8 +3,8 @@
 ``verify_runtime(config)`` is the engine behind
 ``python -m repro.analysis verify``: it builds the runtime via
 :func:`repro.runtime.build_runtime`, lowers/compiles one training step
-(or, for the dynamic regimes, runs just past a re-plan boundary so the
-``PlanStepCache`` holds real compiled steps), and checks
+(or, where ``zero``/``ps`` re-plan, runs just past a re-plan boundary so
+the ``PlanStepCache`` holds real compiled steps), and checks
 
 * the compiled HLO against the active ``BucketPlan`` and the state
   layout's byte math (:func:`~repro.analysis.conformance.verify_schedule`);
@@ -41,7 +41,7 @@ def verify_runtime(config: Any, *, steps: Optional[int] = None
     """Verify one ``RuntimeConfig``; returns ``(findings, info)``.
 
     ``steps`` overrides how many units of progress to run where running
-    is needed (dynamic regimes default to one step past the first
+    is needed (re-planning runs default to one step past the first
     re-plan boundary; async regimes to a couple of committed pushes).
     """
     from repro.runtime import build_runtime
@@ -50,9 +50,8 @@ def verify_runtime(config: Any, *, steps: Optional[int] = None
     if regime == "local":
         return _verify_local(rt)
     if regime in ("zero", "ps"):
-        return _verify_static(rt, config, steps)
-    if regime in ("dynamic", "dynamic-ps"):
-        return _verify_dynamic(rt, config, steps)
+        verify = _verify_dynamic if config.is_dynamic else _verify_static
+        return verify(rt, config, steps)
     if regime in ("ps-async", "dynamic-ps-async"):
         return _verify_async(rt, config, regime, steps)
     if regime == "fleet-async":
@@ -121,22 +120,21 @@ def _verify_dynamic(rt: Any, config: Any, steps: Optional[int]
     # least one (usually two) genuinely compiled plans
     n = steps if steps is not None else config.schedule.reschedule_every + 1
     rt.fit(n)
-    tr = rt.trainer
-    base = tr.base
-    compressor = getattr(tr, "compressor", None)
+    specs = rt.trainer.specs
+    compressor = rt.trainer.compressor
     zero3 = config.execution.zero3
-    findings = verify_cache(tr._cache, specs=base.specs, zero3=zero3,
+    findings = verify_cache(rt._cache, specs=specs, zero3=zero3,
                             context=f"{config.runtime} cache")
-    for i, plan in enumerate(tr.plans_seen):
+    for i, plan in enumerate(rt.plans_seen):
         # verify_schedule handles axis_size == 1 itself (XLA elides the
         # collectives; only stray + wire-model checks run)
         findings.extend(verify_schedule(
-            tr._cache.hlo_text(plan), plan, base.specs,
+            rt._cache.hlo_text(plan), plan, specs,
             compressor=compressor, zero3=zero3,
             context=f"{config.runtime} plan {i}"))
     return findings, _info(
-        config.runtime, steps_run=n, plans_seen=len(tr.plans_seen),
-        traces=tr.traces, cache_hits=tr.cache_hits,
+        config.runtime, steps_run=n, plans_seen=len(rt.plans_seen),
+        traces=rt.traces, cache_hits=rt.cache_hits,
         compression=getattr(compressor, "scheme", "none")
         if compressor else "none",
         checked=["schedule", "cache", "wire-model"])

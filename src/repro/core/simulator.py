@@ -156,7 +156,7 @@ class PSReplanTimeline:
 
     For each topology epoch, two simulations of one synchronous iteration:
     ``replanned`` uses the decision derived from that epoch's costs (what
-    ``repro.ps.dynamic.DynamicPSTrainer`` executes), ``frozen`` keeps the
+    the ``ps`` runtime's re-planning loop executes), ``frozen`` keeps the
     epoch-0 decision throughout (the plan-once baseline the paper's
     run-time loop exists to beat).  The gap is the stale-plan penalty."""
 

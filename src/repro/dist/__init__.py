@@ -4,9 +4,6 @@ the ZeRO trainer (paper pull/push procedures as real ring collectives)."""
 from repro.dist.collectives import (FlatSpec, flatten_tree, gather_bucket,
                                     make_flat_spec, reduce_scatter_bucket,
                                     unflatten_tree)
-from repro.dist.dynamic import DynamicTrainer
-from repro.runtime.replan import (RescheduleEvent, hlo_collective_counts,
-                                  sequential_plan)
 from repro.dist.sharding import (batch_shardings, cache_shardings,
                                  param_pspec, params_shardings)
 from repro.dist.zero import ZeroTrainer
@@ -16,6 +13,4 @@ __all__ = [
     "gather_bucket", "reduce_scatter_bucket",
     "param_pspec", "params_shardings", "batch_shardings", "cache_shardings",
     "ZeroTrainer",
-    "DynamicTrainer", "RescheduleEvent", "hlo_collective_counts",
-    "sequential_plan",
 ]

@@ -13,21 +13,20 @@ their asynchronous form):
 * ``local`` (default) — single-process jit training on whatever devices
   exist; reduced configs runnable on CPU.
 * ``zero`` — the DynaComm-bucketed ZeRO trainer over a 1-D data mesh,
-  schedule chosen by ``--strategy``; the plan is decided once at startup.
-* ``dynamic`` — the run-time loop (paper Section IV-C): re-plan every
-  ``--steps-per-epoch`` steps against the active network model, swap
-  compiled steps when the decision changes.  ``--bw-shift-gbps`` scripts
-  a bandwidth drift; ``--drift-detect`` re-schedules from *observed* step
-  times instead.
+  schedule chosen by ``--strategy``.  On a static network the plan is
+  decided once at startup; ``--bw-shift-gbps`` (a bandwidth drift),
+  ``--cost-source measured`` or ``--drift-detect`` (re-schedule from
+  *observed* step times) start the run-time loop (paper Section IV-C):
+  re-plan every ``--steps-per-epoch`` steps, swap compiled steps when the
+  decision changes.
 * ``ps`` — the parameter-server subsystem: ``--ps-servers`` shards behind
-  asymmetric ``--down-gbps``/``--up-gbps`` links, consensus-planned.
-  With ``--staleness k``: bounded-staleness asynchronous execution
-  (``--throttle reject|wait``; ``--aggregate`` commits same-version
-  pushes as one BSP step).
-* ``dynamic-ps`` — the run-time loop in the PS regime over a
-  time-varying topology (``--up-shift-gbps`` degrades every uplink at
-  ``--shift-epoch``); with ``--staleness k``, per-worker re-plans swapped
-  into the async event loop.
+  asymmetric ``--down-gbps``/``--up-gbps`` links, consensus-planned;
+  ``--up-shift-gbps`` degrades every uplink at ``--shift-epoch`` and the
+  plan follows per topology epoch.  With ``--staleness k``:
+  bounded-staleness asynchronous execution (``--throttle reject|wait``;
+  ``--aggregate`` commits same-version pushes as one BSP step), and with
+  ``--up-shift-gbps`` as well, per-worker re-plans swapped into the async
+  event loop (``dynamic-ps-async``).
 * ``fleet-async`` — elastic membership over the deterministic event
   engine: ``--fleet-schedule events.json`` scripts joins/leaves/failures/
   drift (a JSON list of fleet event dicts), each membership change
@@ -48,10 +47,10 @@ Examples::
         --reduced --runtime zero --strategy dynacomm --steps 50
     XLA_FLAGS=--xla_force_host_platform_device_count=4 \
     PYTHONPATH=src python -m repro.launch.train --arch granite-3-2b \
-        --reduced --runtime dynamic --steps 60 --steps-per-epoch 20 \
+        --reduced --runtime zero --steps 60 --steps-per-epoch 20 \
         --bw-gbps 10 --bw-shift-gbps 1 --shift-epoch 1
     PYTHONPATH=src python -m repro.launch.train \
-        --config examples/runtime_configs/dynamic_ps.json --steps 12
+        --config examples/runtime_configs/ps_uplink_shift.json --steps 12
 """
 
 from __future__ import annotations
@@ -71,13 +70,14 @@ from repro.runtime import (CompressionConfig, ExecutionConfig, FleetConfig,
 def config_from_flags(args) -> RuntimeConfig:
     """The argparse → RuntimeConfig mapping (the whole launcher logic)."""
     name = args.runtime
-    if args.staleness is not None and name in ("ps", "dynamic-ps"):
-        name += "-async"
+    if args.staleness is not None and name == "ps":
+        name = "ps-async" if args.up_shift_gbps is None \
+            else "dynamic-ps-async"
 
     network = topology = None
-    if name in ("zero", "dynamic", "pipeline"):
-        # pass the shift through even for 'zero': RuntimeConfig owns the
-        # "a drift needs the run-time loop" diagnostic
+    if name in ("zero", "pipeline"):
+        # pass the shift through even for 'pipeline': RuntimeConfig owns
+        # the "a drift needs the run-time loop" diagnostic
         network = NetworkConfig(
             bandwidth_gbps=args.bw_gbps,
             shift_gbps=args.bw_shift_gbps,
@@ -148,9 +148,9 @@ def config_from_flags(args) -> RuntimeConfig:
 
 def _print_plan(rt) -> None:
     """The ZeRO-driven regimes' plan and state layout, once at startup
-    (``leaves`` on a one-device axis with no compressor, else ``flat``)."""
-    trainer = getattr(rt, "trainer", None)
-    layout = getattr(getattr(trainer, "base", trainer), "layout", None)
+    (``leaves`` with no compressor, ``flat`` with one); a re-planning run
+    has no plan before its first step."""
+    layout = getattr(getattr(rt, "trainer", None), "layout", None)
     if layout is None:
         return
     plan = rt.plan
@@ -184,21 +184,17 @@ def _print_events(rt) -> None:
                   f"sched {e.scheduling_seconds * 1e3:.2f} ms "
                   f"hidden={e.overhead_hidden}")
         else:                                # sync RescheduleEvent
-            extra = ""
-            if hasattr(rt.trainer, "hlo_counts"):
-                pulls, pushes = rt.trainer.hlo_counts(e.plan)
-                extra = f" (hlo {pulls} pull / {pushes} push)"
+            pulls, pushes = rt.hlo_counts(e.plan)
             print(f"epoch {e.epoch:3d} step {e.step:4d}: "
                   f"{len(e.plan.forward)} pull / {len(e.plan.backward)} "
-                  f"push segments{extra}  "
+                  f"push segments (hlo {pulls} pull / {pushes} push)  "
                   f"{'re-segmented' if e.plan_changed else 'unchanged'}"
                   f"{' [cache hit]' if e.plan_changed and not e.retraced else ''}"
                   f"  sched {e.scheduling_seconds * 1e3:.2f} ms "
                   f"hidden={e.overhead_hidden}")
-    tr = getattr(rt, "trainer", None)
-    if tr is not None and hasattr(tr, "traces"):
-        print(f"[{rt.config.runtime}] traces {tr.traces}, "
-              f"cache hits {tr.cache_hits}")
+    if rt.config.regime in ("zero", "ps-sync") and rt.config.is_dynamic:
+        print(f"[{rt.config.runtime}] traces {rt.traces}, "
+              f"cache hits {rt.cache_hits}")
 
 
 def main() -> None:
@@ -214,17 +210,17 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true",
                     help="train the smoke-scale variant (CPU-friendly)")
     ap.add_argument("--runtime",
-                    choices=("local", "zero", "dynamic", "ps", "ps-async",
-                             "dynamic-ps", "dynamic-ps-async",
-                             "fleet-async", "pipeline"),
+                    choices=("local", "zero", "ps", "ps-async",
+                             "dynamic-ps-async", "fleet-async", "pipeline"),
                     default="local",
                     help="registry name; --staleness k still upgrades "
-                         "ps/dynamic-ps to their -async form")
+                         "ps to its -async form (dynamic-ps-async with "
+                         "--up-shift-gbps)")
     ap.add_argument("--strategy", default="dynacomm",
                     choices=("sequential", "lbl", "ibatch", "dynacomm"))
-    # scheduling knobs (zero + dynamic runtimes)
+    # scheduling knobs (zero + ps runtimes)
     ap.add_argument("--steps-per-epoch", type=int, default=20,
-                    help="re-scheduling interval of the dynamic runtimes")
+                    help="re-scheduling interval of the re-planning loop")
     ap.add_argument("--bw-gbps", type=float, default=10.0,
                     help="edge uplink bandwidth (Gbit/s)")
     ap.add_argument("--bw-shift-gbps", type=float, default=None,
@@ -241,8 +237,8 @@ def main() -> None:
     ap.add_argument("--cost-source", choices=("analytic", "measured"),
                     default="analytic")
     ap.add_argument("--drift-detect", action="store_true",
-                    help="dynamic runtime: also re-schedule when observed "
-                         "step times drift (EWMA detector)")
+                    help="zero/ps: also re-schedule when observed step "
+                         "times drift (EWMA detector)")
     # parameter-server knobs (ps runtimes)
     ap.add_argument("--ps-servers", type=int, default=2,
                     help="number of server shards")
@@ -264,8 +260,8 @@ def main() -> None:
                     help="async ps wait throttle: commit same-version "
                          "pushes as one BSP step")
     ap.add_argument("--up-shift-gbps", type=float, default=None,
-                    help="dynamic-ps: degrade every uplink to this "
-                         "bandwidth at --shift-epoch")
+                    help="ps: degrade every uplink to this bandwidth at "
+                         "--shift-epoch")
     ap.add_argument("--fleet-schedule", default=None,
                     help="fleet-async: JSON file holding a list of fleet "
                          "event dicts (time/kind/worker/...) to script "

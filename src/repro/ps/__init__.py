@@ -8,15 +8,16 @@ links, synchronously (``PSTrainer``, bit-identical to the ZeRO trainer)
 or asynchronously under a bounded staleness ``k`` (``AsyncPSTrainer``,
 with server-side rejection or SSP wait-at-barrier throttling).
 
-``TopologySchedule`` makes the fabric time-varying, and the
-``repro.ps.dynamic`` drivers re-derive the decomposition once per
-topology epoch — the paper's run-time loop in the PS regime.
+``TopologySchedule`` makes the fabric time-varying: the ``ps`` runtime's
+re-planning loop (synchronous) and ``repro.ps.dynamic``'s driver
+(asynchronous) re-derive the decomposition once per topology epoch — the
+paper's run-time loop in the PS regime.
 """
 
 from repro.ps.async_mode import (THROTTLES, AsyncPSTrainer, AsyncPushEvent,
                                  AsyncRunLog)
 from repro.ps.dynamic import (AsyncRescheduleEvent, DynamicAsyncPSTrainer,
-                              DynamicPSTrainer, profiles_from_specs)
+                              profiles_from_specs)
 from repro.ps.server import (PSServer, PushResult, StaleVersion,
                              TransferLedger)
 from repro.ps.topology import (LinkModel, PSTopology, TopologySchedule,
@@ -30,6 +31,5 @@ __all__ = [
     "PSServer", "PushResult", "StaleVersion", "TransferLedger",
     "PSTrainer",
     "THROTTLES", "AsyncPSTrainer", "AsyncPushEvent", "AsyncRunLog",
-    "AsyncRescheduleEvent", "DynamicAsyncPSTrainer", "DynamicPSTrainer",
-    "profiles_from_specs",
+    "AsyncRescheduleEvent", "DynamicAsyncPSTrainer", "profiles_from_specs",
 ]

@@ -1,81 +1,34 @@
-"""Run-time re-planning for the parameter-server subsystem.
+"""Run-time re-planning of the asynchronous parameter server.
 
-``repro.dist.dynamic.DynamicTrainer`` closed the paper's run-time loop for
-the flat ZeRO cluster; this module closes it for the paper's *actual*
-deployment topology.  A :class:`repro.ps.topology.TopologySchedule` makes
-the fabric time-varying — per-link bandwidth/RTT and per-worker compute
-rates shifting on epoch boundaries — and two drivers re-derive the
-layer-wise decomposition whenever the topology shifts:
+A :class:`repro.ps.topology.TopologySchedule` makes the fabric
+time-varying — per-link bandwidth/RTT and per-worker compute rates
+shifting on epoch boundaries.  :class:`DynamicAsyncPSTrainer` re-runs
+per-worker ``schedule_topology`` once per topology epoch — each worker
+gets its own decomposition, matched to its own link and compute rate —
+and swaps the plans (and the simulated-clock costs) into the resumable
+:class:`repro.ps.async_mode.AsyncPSTrainer` loop, under either throttle
+discipline (with optional BSP push aggregation).  Every re-plan records
+an :class:`AsyncRescheduleEvent` carrying the scheduling wall time and
+the paper's Table I overhead-hidden check against the topology's
+Δt + gt¹ idle window (the minimum over workers).
 
-* :class:`DynamicPSTrainer` (synchronous, compiled): once per topology
-  epoch, re-projects the active topology onto per-worker
-  ``TopologyCosts``, re-runs the straggler-minimizing
-  ``consensus_decision``, and swaps the compiled pull/push step from the
-  shared :class:`repro.runtime.replan.PlanStepCache` (one trace per
-  distinct plan, revisits are dictionary lookups).  With
-  ``cost_source="measured"``, per-layer fc/bc come from *measured*
-  wall-clock timings of the jitted applies (re-measured every
-  ``remeasure_every`` topology epochs) and are rescaled to each worker's
-  compute rate — so the per-worker decompositions track real compute
-  drift, not just the analytic model.  The ZeRO/PS state layout (the
-  parameter leaves, or with a compressor one ``FlatSpec`` flat buffer
-  per sched layer) is plan-independent, so
-  states carry across swaps and the loss trajectory is bit-identical to
-  statically running each epoch's plan (asserted by
-  ``tests/test_dynamic.py``).
-* :class:`DynamicAsyncPSTrainer` (asynchronous, event-driven): once per
-  topology epoch, re-runs per-worker ``schedule_topology`` — each worker
-  gets its own decomposition, matched to its own link and compute rate —
-  and swaps the plans (and the simulated-clock costs) into the resumable
-  :class:`repro.ps.async_mode.AsyncPSTrainer` loop, under either throttle
-  discipline (with optional BSP push aggregation).
-
-Every re-plan records a reschedule event carrying the scheduling wall
-time and the paper's Table I overhead-hidden check against the topology's
-Δt + gt¹ idle window (the minimum over workers — the re-plan must hide
-behind *every* worker's last in-flight gradient push); the event
-bookkeeping is shared with the ZeRO driver via
-:class:`repro.runtime.replan.ReplanMixin`.
+The synchronous PS re-plans through the ``ps`` runtime's loop
+(:class:`repro.runtime.replan.ReplanMixin`).
 """
 
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
-import numpy as np
-
-from repro.configs.base import ArchConfig, InputShape
 from repro.core.buckets import BucketPlan, plan_from_decision
 from repro.core.costmodel import TopologyCosts
 from repro.core.planner import AsyncPlanner, Planner
-from repro.core.profiler import LayerProfile, LayerTimingHook
+from repro.core.profiler import LayerProfile
 from repro.core.scheduler import TopologyScheduler
-from repro.models import model as model_lib
-from repro.models.profiles import layer_profiles
 from repro.optim import Optimizer
 from repro.ps.async_mode import AsyncPSTrainer, AsyncRunLog
 from repro.ps.topology import TopologySchedule, as_topology_schedule
-from repro.ps.worker import PSTrainer
-from repro.runtime.measure import measure_layer_times, measurement_due
-from repro.runtime.replan import ReplanMixin
-
-_MOVED = ("PlanStepCache", "RescheduleEvent", "hlo_collective_counts",
-          "sequential_plan")
-
-
-def __getattr__(name: str):
-    # deprecation shims mirroring repro.dist.dynamic: the re-planning
-    # machinery PR 4 grew here was hoisted to repro.runtime.replan
-    if name in _MOVED:
-        warnings.warn(
-            f"repro.ps.dynamic.{name} moved to repro.runtime.replan; "
-            f"this alias will be removed",
-            DeprecationWarning, stacklevel=2)
-        from repro.runtime import replan
-        return getattr(replan, name)
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def profiles_from_specs(specs, *, flops_per_param: float = 4.0
@@ -87,231 +40,6 @@ def profiles_from_specs(specs, *, flops_per_param: float = 4.0
     return tuple(LayerProfile(name=f"layer{l}", param_bytes=s.total * 4.0,
                               flops_fwd=flops_per_param * s.total)
                  for l, s in enumerate(specs))
-
-
-@dataclasses.dataclass
-class DynamicPSTrainer(ReplanMixin):
-    """Topology-epoch re-planning driver around :class:`PSTrainer` (sync).
-
-    ``topology`` may be a static :class:`PSTopology` or a
-    :class:`TopologySchedule`; the schedule's ``num_workers`` must equal
-    the mesh's ``axis_name`` size (one synchronous worker per device, and
-    workers cannot join or leave mid-run).
-
-    ``cost_source="measured"`` times this host's jitted per-layer applies
-    (``repro.runtime.measure``) every ``remeasure_every`` topology epochs
-    and projects the timings onto each worker by compute-rate scaling:
-    the measured vectors are taken to describe a worker running at
-    ``measure_ref_flops`` (default: the fleet's fastest rate), so worker
-    *w* sees them scaled by ``measure_ref_flops / worker_flops[w]`` while
-    pt/gt/Δt still come from its own links.
-    """
-
-    cfg: ArchConfig
-    mesh: Any
-    optimizer: Optimizer
-    topology: Any                  # PSTopology | TopologySchedule
-    steps_per_epoch: int
-    input_shape: InputShape
-    strategy: str = "dynacomm"
-    cost_source: str = "analytic"          # "analytic" | "measured"
-    measure_iters: int = 3
-    measure_warmup: int = 1
-    remeasure_every: int = 1      # epochs between fc/bc re-measurements;
-                                  # 0 = measure once
-    measure_ref_flops: Optional[float] = None
-    zero3: bool = False
-    axis_name: str = "data"
-    aux_weight: float = 0.01
-    compressor: Optional[Any] = None
-    async_planning: bool = False  # pre-plan epoch e+1 in e's idle window
-    plan_cache_size: int = 256    # memoized decisions kept (LRU)
-
-    def __post_init__(self):
-        if self.steps_per_epoch < 1:
-            raise ValueError(f"steps_per_epoch must be >= 1, got "
-                             f"{self.steps_per_epoch}")
-        if self.cost_source not in ("analytic", "measured"):
-            raise ValueError(f"cost_source must be 'analytic' or 'measured', "
-                             f"got {self.cost_source!r}")
-        if self.remeasure_every < 0:
-            raise ValueError(f"remeasure_every must be >= 0, got "
-                             f"{self.remeasure_every}")
-        self.topology: TopologySchedule = as_topology_schedule(self.topology)
-        planner_cls = AsyncPlanner if self.async_planning else Planner
-        self.planner = planner_cls(cache_size=self.plan_cache_size)
-        self.scheduler = TopologyScheduler(
-            strategy=self.strategy, reschedule_every=self.steps_per_epoch,
-            mode="consensus", planner=self.planner)
-        self.hook = LayerTimingHook(warmup=self.measure_warmup)
-        self._profiles = layer_profiles(self.cfg, self.input_shape)
-        Ls = model_lib.num_sched_layers(self.cfg)
-        seq = BucketPlan(forward=(tuple(range(Ls)),),
-                         backward=(tuple(range(Ls - 1, -1, -1)),))
-        self.base = PSTrainer(cfg=self.cfg, mesh=self.mesh, plan=seq,
-                              optimizer=self.optimizer,
-                              topology=self.topology.topology_at(0),
-                              zero3=self.zero3, axis_name=self.axis_name,
-                              aux_weight=self.aux_weight,
-                              compressor=self.compressor)
-        self.compressor = self.base.compressor   # "none" normalized away
-        self._init_replan()
-        self._step_idx = 0
-        self._costs: Optional[TopologyCosts] = None
-        self._measured_fc_bc: Optional[Tuple[np.ndarray, np.ndarray]] = None
-        self._measured_epoch = -1
-
-    # ------------------------------------------------------------------
-    # state / introspection
-    # ------------------------------------------------------------------
-
-    def init_state(self, key):
-        return self.base.init_state(key)
-
-    @property
-    def step_index(self) -> int:
-        return self._step_idx
-
-    @property
-    def epoch(self) -> int:
-        return self._step_idx // self.steps_per_epoch
-
-    @property
-    def planner_stats(self) -> Dict[str, float]:
-        """Memo-cache / async-planning counters (``PlannerStats``)."""
-        return self.planner.stats.as_dict()
-
-    def costs_for_epoch(self, epoch: int, state=None, batch=None, *,
-                        remeasure: bool = False) -> TopologyCosts:
-        """The active topology's per-worker cost projection.
-
-        Analytic by default.  With ``cost_source="measured"``, fc/bc come
-        from measured host timings rescaled per worker (see the class
-        docstring); ``state``/``batch`` are required whenever a (re-)
-        measurement is due — callers that only want the cached projection
-        (timeline views, tests) can omit them.
-        """
-        topo = self.topology.topology_at(epoch)
-        if self.cost_source == "analytic":
-            return topo.topology_costs(self._profiles,
-                                       compressor=self.compressor)
-        if measurement_due(self._measured_fc_bc, self._measured_epoch,
-                           epoch, self.remeasure_every, force=remeasure):
-            if state is None or batch is None:
-                # view accessors (timelines, tests) may read the cached
-                # projection without re-measuring; only the very first
-                # measurement has nothing to serve
-                if self._measured_fc_bc is None:
-                    raise ValueError(
-                        "cost_source='measured' needs state and batch for "
-                        "the first measurement")
-            else:
-                measure_layer_times(self.base._zero, self.hook, state,
-                                    batch, iters=self.measure_iters)
-                Ls = self.base.num_layers
-                self._measured_fc_bc = (self.hook.median("fc", Ls),
-                                        self.hook.median("bc", Ls))
-                self._measured_epoch = epoch
-        fc, bc = self._measured_fc_bc
-        return topo.topology_costs_measured(
-            self._profiles, fc=fc, bc=bc, ref_flops=self.measure_ref_flops,
-            compressor=self.compressor)
-
-    def timeline(self, epoch: Optional[int] = None):
-        """Per-worker timeline of the *active* plan against an epoch's
-        topology costs (current epoch by default)."""
-        from repro.core.buckets import decision_from_plan
-        from repro.core.simulator import simulate_ps_iteration
-        if self._plan is None:
-            raise ValueError("no active plan yet — run at least one step")
-        epoch = self.epoch if epoch is None else epoch
-        return simulate_ps_iteration(self.costs_for_epoch(epoch),
-                                     decision_from_plan(self._plan))
-
-    def replan_timeline(self):
-        """Re-planned vs frozen-epoch-0-plan makespans across the epochs
-        re-scheduled so far (:func:`core.simulator.simulate_ps_replan`) —
-        the stale-plan penalty this driver exists to reclaim."""
-        from repro.core.simulator import simulate_ps_replan
-        from repro.core.buckets import decision_from_plan
-        if not self.events:
-            raise ValueError("no reschedule events yet")
-        by_epoch = {e.epoch: e.plan for e in self.events}
-        epochs = sorted(by_epoch)
-        costs = [self.costs_for_epoch(e) for e in epochs]
-        decisions = [decision_from_plan(by_epoch[e]) for e in epochs]
-        return simulate_ps_replan(costs, decisions)
-
-    # ------------------------------------------------------------------
-    # the dynamic loop
-    # ------------------------------------------------------------------
-
-    def _maybe_reschedule(self, i: int, state, batch) -> None:
-        boundary = i % self.steps_per_epoch == 0
-        if boundary:
-            epoch = i // self.steps_per_epoch
-            self._costs = self.costs_for_epoch(epoch, state, batch)
-            # the compiled data path is topology-independent; the base
-            # trainer's accounting views (segment owners, transfer bytes,
-            # timelines) should reflect the active fabric
-            self.base.topology = self.topology.topology_at(epoch)
-        decision = self.scheduler.decision_for_iteration(self._costs)
-        # (``_step_fn is None`` off-boundary ⇒ loop state was just restored
-        # from a checkpoint: recompile the active plan, no scheduling event)
-        if not boundary and self._step_fn is not None:
-            return
-        plan = plan_from_decision(*decision, self.base.num_layers)
-        prev, retraced = self._activate_plan(
-            plan, lambda: self.base.with_plan(plan).build_train_step(),
-            state, batch)
-        if boundary:
-            self._record_reschedule(
-                step=i, epoch=i // self.steps_per_epoch, plan=plan,
-                prev=prev, retraced=retraced, scheduler=self.scheduler,
-                costs=self._costs)
-        if boundary and self.async_planning and \
-                self.cost_source == "analytic":
-            # Phase one of the async protocol: epoch e+1's analytic
-            # topology projection is a pure function of the epoch, so its
-            # per-worker DPs can run now in the Δt + gt¹ idle window and
-            # be collected at the next boundary.  Measured costs solve
-            # inline as before (the planner's sync fallback).
-            self.planner.submit_topology(
-                self.costs_for_epoch(i // self.steps_per_epoch + 1),
-                self.strategy)
-
-    def step(self, state, batch):
-        """One training step; re-plans on topology-epoch boundaries.
-        Returns ``(new_state, mean_loss)``."""
-        self._maybe_reschedule(self._step_idx, state, batch)
-        new_state, loss = self._step_fn(state, batch)
-        self._step_idx += 1
-        return new_state, loss
-
-    def run(self, state, batch_fn: Callable[[int], Any], num_steps: int, *,
-            log_every: int = 0):
-        """Drive ``num_steps`` steps with ``batch_fn(i) -> batch``.
-
-        Returns ``(state, losses)`` with one float loss per step."""
-        losses: List[float] = []
-        for i in range(num_steps):
-            state, loss = self.step(state, batch_fn(i))
-            losses.append(float(loss))
-            if log_every and (i + 1) % log_every == 0:
-                f, b = (len(self._plan.forward), len(self._plan.backward))
-                print(f"step {i + 1:4d}  epoch {self.epoch}  "
-                      f"loss {losses[-1]:.4f}  segments {f}/{b}")
-        return state, losses
-
-    # ------------------------------------------------------------------
-    # loop-state checkpointing — loop_state/save_loop_state come from
-    # ReplanMixin unchanged; the restore re-points the base trainer's
-    # accounting at the resumed epoch's topology
-    # ------------------------------------------------------------------
-
-    def restore_loop_state(self, path: str) -> None:
-        self._restore_loop_common(path)
-        self.base.topology = self.topology.topology_at(self.epoch)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -25,8 +25,8 @@ per homogeneous cluster.
 sequence of topologies indexed by epoch (mirroring
 ``core.netmodel.NetworkSchedule``) — an edge fleet whose uplinks degrade,
 whose devices throttle thermally, or whose membership is re-provisioned
-on epoch boundaries.  ``repro.ps.dynamic.DynamicPSTrainer`` re-plans
-against the active topology once per topology epoch.
+on epoch boundaries.  The ``ps`` runtime re-plans against the active
+topology once per topology epoch.
 """
 
 from __future__ import annotations

@@ -2,26 +2,38 @@
 
 ``repro.runtime`` is the layer that makes launchers, examples, and
 benchmarks *thin clients*: a frozen, JSON-round-trippable
-:class:`RuntimeConfig` names a registered runtime (``zero`` | ``dynamic``
-| ``ps`` | ``ps-async`` | ``dynamic-ps`` | ``dynamic-ps-async`` |
-``local``), and :func:`build_runtime` turns it into an object
-implementing the :class:`Trainer` protocol — ``fit`` / ``step`` /
-``events`` / ``timeline`` / ``ledger`` / ``save_state`` /
-``restore_state`` — regardless of which of the six underlying trainers
-executes underneath.  New regimes cost one ``@register_runtime`` entry,
-not a new hand-wired launcher branch.
+:class:`RuntimeConfig` names a registered runtime, one per execution
+topology —
 
-The run-time re-planning machinery the dynamic drivers share
-(:class:`PlanStepCache`, :class:`RescheduleEvent`, the Table I
-idle-window bookkeeping) lives here too, in :mod:`repro.runtime.replan`.
+=====================  ==================================================
+``local``              single-process jit training, no distribution layer
+``zero``               DynaComm-bucketed ZeRO over the data mesh
+``ps``                 synchronous parameter server, consensus plan
+``ps-async``           bounded-staleness asynchronous PS, one plan
+``dynamic-ps-async``   async PS, per-worker re-plans per topology epoch
+``fleet-async``        async PS over an elastic, churning fleet
+``pipeline``           stage-partitioned pipeline parallelism
+=====================  ==================================================
+
+— and :func:`build_runtime` turns it into an object implementing the
+:class:`Trainer` protocol — ``fit`` / ``step`` / ``events`` /
+``timeline`` / ``ledger`` / ``save_state`` / ``restore_state``.  ``zero``
+and ``ps`` re-plan at every epoch boundary whenever their schedule can
+change the plan (:attr:`RuntimeConfig.is_dynamic`); a static network is
+a one-knot schedule, planned once.  New regimes cost one
+``@register_runtime`` entry, not a new hand-wired launcher branch.
+
+The run-time re-planning loop (:class:`ReplanMixin`,
+:class:`PlanStepCache`, :class:`RescheduleEvent`, the Table I idle-window
+bookkeeping) lives in :mod:`repro.runtime.replan`.
 """
 
-from repro.runtime.config import (DYNAMIC_RUNTIMES, RUNTIME_REGIMES,
-                                  CompressionConfig, ExecutionConfig,
-                                  FleetConfig, FleetEventConfig,
-                                  MeasureConfig, NetworkConfig,
-                                  PipelineConfig, RuntimeConfig,
-                                  ScheduleConfig, TopologyConfig)
+from repro.runtime.config import (RUNTIME_REGIMES, CompressionConfig,
+                                  ExecutionConfig, FleetConfig,
+                                  FleetEventConfig, MeasureConfig,
+                                  NetworkConfig, PipelineConfig,
+                                  RuntimeConfig, ScheduleConfig,
+                                  TopologyConfig)
 from repro.runtime.protocol import EvalEvent, Trainer
 from repro.runtime.replan import (PlanStepCache, ReplanMixin,
                                   RescheduleEvent, hlo_collective_counts,
@@ -31,7 +43,7 @@ __all__ = [
     "RuntimeConfig", "ScheduleConfig", "ExecutionConfig", "MeasureConfig",
     "NetworkConfig", "TopologyConfig", "CompressionConfig",
     "FleetConfig", "FleetEventConfig", "PipelineConfig",
-    "RUNTIME_REGIMES", "DYNAMIC_RUNTIMES",
+    "RUNTIME_REGIMES",
     "Trainer", "EvalEvent",
     "PlanStepCache", "RescheduleEvent", "ReplanMixin",
     "hlo_collective_counts", "sequential_plan",

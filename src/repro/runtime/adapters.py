@@ -1,4 +1,4 @@
-"""Runtime adapters: the six trainers behind one ``Trainer`` protocol.
+"""Runtime adapters: the trainers behind one ``Trainer`` protocol.
 
 Each adapter owns everything a regime needs to run — mesh, optimizer,
 model/arch config, trainer, training state, transfer accounting — and
@@ -16,6 +16,7 @@ runtime fails loudly instead of silently misinterpreting buffers.
 
 from __future__ import annotations
 
+import time
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
@@ -28,6 +29,7 @@ from repro.configs.base import ArchConfig, InputShape
 from repro.runtime.config import (FleetConfig, NetworkConfig, RuntimeConfig,
                                   TopologyConfig)
 from repro.runtime.registry import register_runtime
+from repro.runtime.replan import ReplanMixin, sequential_plan
 
 # per-worker data streams of the async regimes stay disjoint by striding
 # the deterministic batch index (the convention every launcher used)
@@ -253,211 +255,203 @@ class LocalRuntime(RuntimeAdapter):
         self._opt_state = self._replace_like(self._opt_state, tree["opt"])
 
 
-@register_runtime("zero", description="DynaComm-bucketed ZeRO trainer, "
-                                      "plan decided once at startup")
-class ZeroRuntime(_CompiledRuntime):
-    """Profile → schedule → bucketed ZeRO trainer (static plan)."""
+class _PlannedRuntime(ReplanMixin, _CompiledRuntime):
+    """The synchronous regimes (zero, ps): one compiled step of the
+    active plan's trainer.
+
+    Where the schedule can change the plan (``config.is_dynamic``: a
+    network or topology with more than one knot, measured costs, or a
+    drift detector), the run-time loop of :class:`ReplanMixin` re-plans at
+    every epoch boundary and swaps compiled steps from its plan-keyed
+    cache.  Otherwise the plan is decided once here, from epoch 0's
+    analytic costs, and the step is the jitted ``build_train_step`` of its
+    trainer."""
 
     def __init__(self, config, arch, batch_fn):
         super().__init__(config, arch, batch_fn)
-        from repro.core import (DynaCommScheduler, costs_from_profiles,
-                                plan_from_decision)
-        from repro.dist.zero import ZeroTrainer
-        from repro.models import num_sched_layers
         from repro.models.profiles import layer_profiles
-        net = (config.schedule.network or NetworkConfig()).build()
-        self._costs = costs_from_profiles(
-            layer_profiles(arch, self.shape), net=net,
-            compute_flops_per_s=config.measure.compute_flops_per_s)
-        self.scheduler = DynaCommScheduler(
-            strategy=config.schedule.strategy,
-            reschedule_every=config.schedule.reschedule_every)
-        self._decision = self.scheduler.decision_for_iteration(self._costs)
-        plan = plan_from_decision(*self._decision, num_sched_layers(arch))
-        self.trainer = ZeroTrainer(
-            cfg=arch, mesh=_data_mesh(), plan=plan,
-            optimizer=config.build_optimizer(),
-            zero3=config.execution.zero3, aux_weight=config.aux_weight)
+        self._dynamic = config.is_dynamic
+        self._mesh = _data_mesh()
+        self._profiles = layer_profiles(arch, self.shape)
+
+    def _start(self, scheduler, make_trainer) -> None:
+        """Build the trainer of the first plan, its state and its step."""
+        from repro.core import plan_from_decision
+        from repro.core.planner import AsyncPlanner, Planner
+        from repro.models import num_sched_layers
+        sched = self.config.schedule
+        planner = None
+        if self._dynamic:
+            planner = (AsyncPlanner if sched.async_planning else Planner)(
+                cache_size=sched.plan_cache_size)
+            scheduler.planner = planner
+        self._init_replan(scheduler, planner)
+        L = num_sched_layers(self.arch)
+        if self._dynamic:
+            plan = sequential_plan(L)     # until the first step re-plans
+        else:
+            self._costs = self._price(0)
+            self._decision = scheduler.decision_for_iteration(self._costs)
+            plan = self._plan = plan_from_decision(*self._decision, L)
+        self.trainer = make_trainer(plan)
         self._state = self.trainer.init_state(
-            jax.random.PRNGKey(config.seed))
-        self._step_fn = jax.jit(self.trainer.build_train_step())
+            jax.random.PRNGKey(self.config.seed))
+        if not self._dynamic:
+            self._step_fn = jax.jit(self.trainer.build_train_step())
 
     @property
-    def plan(self):
-        return self.trainer.plan
+    def events(self):
+        return tuple(self._replans) + tuple(self._eval_events)
 
     def step(self, batch) -> float:
+        if self._dynamic:
+            self._replan(batch)
         # Hold the input state until the step has run (``old`` goes at
         # return): dropped while the step runs, its buffers (one per leaf
         # in a leaves state) are freed by the runtime after it, racing the
         # next step's output allocations for a higher, varying peak.
         old = self._state
+        t0 = time.perf_counter()
         with jax.profiler.TraceAnnotation("repro.dispatch"):
             self._state, loss = self._step_fn(old, batch)
-        self._account(self.trainer.specs, self.trainer.plan,
-                      self.trainer.axis_size)
+        tr = self.trainer
+        self._account(tr.specs, tr.plan, self._mesh.size, tr.compressor)
         self._data_idx += 1
         with jax.profiler.TraceAnnotation("repro.sync"):
-            return float(loss)
+            loss = float(loss)
+        if self.drift_detector is not None and \
+                self.drift_detector.update(time.perf_counter() - t0):
+            self._drift_pending = True       # re-plan before the next step
+        return loss
 
-    def timeline(self):
-        from repro.core import simulate_iteration
-        return simulate_iteration(self._costs, *self._decision)
-
-
-@register_runtime("dynamic", description="run-time loop: re-profile + "
-                                         "re-plan per epoch, swap compiled "
-                                         "steps")
-class DynamicRuntime(_CompiledRuntime):
-    """Epoch-boundary re-scheduling (paper Section IV-C) over ZeRO."""
-
-    def __init__(self, config, arch, batch_fn):
-        super().__init__(config, arch, batch_fn)
-        from repro.dist.dynamic import DynamicTrainer
-        detector = None
-        if config.schedule.drift_detect:
-            from repro.core import EwmaDriftDetector
-            detector = EwmaDriftDetector()
-        net = (config.schedule.network or NetworkConfig()).build()
-        self.trainer = DynamicTrainer(
-            cfg=arch, mesh=_data_mesh(),
-            optimizer=config.build_optimizer(), network=net,
-            steps_per_epoch=config.schedule.reschedule_every,
-            strategy=config.schedule.strategy, input_shape=self.shape,
-            cost_source=config.measure.cost_source,
-            compute_flops_per_s=config.measure.compute_flops_per_s,
-            measure_iters=config.measure.measure_iters,
-            measure_warmup=config.measure.measure_warmup,
-            remeasure_every=config.measure.remeasure_every,
-            drift_detector=detector, zero3=config.execution.zero3,
-            aux_weight=config.aux_weight,
-            async_planning=config.schedule.async_planning,
-            plan_cache_size=config.schedule.plan_cache_size)
-        self._state = self.trainer.init_state(
-            jax.random.PRNGKey(config.seed))
-
-    @property
-    def events(self):
-        return tuple(self.trainer.events) + tuple(self._eval_events)
-
-    @property
-    def plan(self):
-        return self.trainer.plan
-
-    def step(self, batch) -> float:
-        self._state, loss = self.trainer.step(self._state, batch)
-        self._account(self.trainer.base.specs, self.trainer.plan,
-                      self.trainer.base.axis_size)
-        self._data_idx += 1
-        return float(loss)
-
-    def timeline(self):
-        return self.trainer.timeline()
+    def compiled_step_text(self, batch) -> str:
+        if self._dynamic:
+            return self._cache.hlo_text(self._plan)
+        return super().compiled_step_text(batch)
 
     def save_state(self, path: str) -> None:
         super().save_state(path)
-        self.trainer.save_loop_state(path + ".loop")
+        if self._dynamic:
+            self.save_loop_state(path + ".loop")
 
     def restore_state(self, path: str) -> None:
         super().restore_state(path)
-        self.trainer.restore_loop_state(path + ".loop")
+        if self._dynamic:
+            self.restore_loop_state(path + ".loop")
 
 
-class _PSBase(_CompiledRuntime):
-    """Shared topology construction for the synchronous PS regimes."""
+@register_runtime("zero", description="DynaComm-bucketed ZeRO trainer; "
+                                      "re-plans per epoch when its network, "
+                                      "costs or step times can move the "
+                                      "plan")
+class ZeroRuntime(_PlannedRuntime):
+    """Profile → schedule → bucketed ZeRO trainer over a scalar edge
+    network (a one-knot schedule unless it shifts)."""
 
-    def _build_topology(self):
-        topo_cfg = self.config.schedule.topology or TopologyConfig()
-        return topo_cfg.build(default_workers=len(jax.devices()))
+    def __init__(self, config, arch, batch_fn):
+        super().__init__(config, arch, batch_fn)
+        from repro.core import DynaCommScheduler, as_schedule
+        from repro.dist.zero import ZeroTrainer
+        self.network = as_schedule(
+            (config.schedule.network or NetworkConfig()).build())
+        self._start(
+            DynaCommScheduler(
+                strategy=config.schedule.strategy,
+                reschedule_every=config.schedule.reschedule_every),
+            lambda plan: ZeroTrainer(
+                cfg=arch, mesh=self._mesh, plan=plan,
+                optimizer=config.build_optimizer(),
+                zero3=config.execution.zero3, aux_weight=config.aux_weight))
+
+    def _price(self, epoch: int, fc_bc=None):
+        """fc/bc analytic (or measured), pt/gt/Δt from the epoch's
+        network."""
+        from repro.core import LayerCosts, costs_from_profiles
+        from repro.models import sched_layer_bytes
+        net = self.network.model_at(epoch)
+        if fc_bc is None:
+            return costs_from_profiles(
+                self._profiles, net=net,
+                compute_flops_per_s=self.config.measure.compute_flops_per_s)
+        t = net.transfer_time(
+            np.asarray(sched_layer_bytes(self.arch), np.float64))
+        return LayerCosts(pt=t, fc=fc_bc[0], bc=fc_bc[1], gt=t, dt=net.dt)
+
+    def timeline(self):
+        from repro.core import decision_from_plan, simulate_iteration
+        if self._plan is None or self._costs is None:
+            return None
+        return simulate_iteration(self._costs,
+                                  *decision_from_plan(self._plan))
 
 
 @register_runtime("ps", description="synchronous parameter-server "
                                     "execution: consensus plan, one pull + "
-                                    "one push per segment")
-class PSRuntime(_PSBase):
-    """Sync PS: segmented pull/push on the mesh (== ZeRO bitwise)."""
+                                    "one push per segment, re-planned per "
+                                    "topology epoch when it can move")
+class PSRuntime(_PlannedRuntime):
+    """Sync PS: segmented pull/push on the mesh (== ZeRO bitwise) over a
+    PS topology (a one-knot schedule unless its uplinks shift).
+
+    With measured costs, this host's per-layer timings are taken to
+    describe the fleet's fastest worker, and worker *w* sees them scaled
+    by that rate over its own, while pt/gt/Δt still come from its links.
+    """
 
     def __init__(self, config, arch, batch_fn):
         super().__init__(config, arch, batch_fn)
-        from repro.ps import PSTrainer
-        self.trainer = PSTrainer.from_topology(
-            arch, _data_mesh(), self._build_topology(),
-            config.build_optimizer(), self.shape,
-            strategy=config.schedule.strategy,
-            compressor=config.compression.build(),
-            zero3=config.execution.zero3, aux_weight=config.aux_weight)
-        self._state = self.trainer.init_state(
-            jax.random.PRNGKey(config.seed))
-        self._step_fn = jax.jit(self.trainer.build_train_step())
+        from repro.core import TopologyScheduler
+        from repro.ps import PSTrainer, as_topology_schedule
+        topo_cfg = config.schedule.topology or TopologyConfig()
+        self.topology = as_topology_schedule(
+            topo_cfg.build(default_workers=self._mesh.size))
+        self.compressor = config.compression.build()
+        self._start(
+            TopologyScheduler(
+                strategy=config.schedule.strategy,
+                reschedule_every=config.schedule.reschedule_every,
+                mode="consensus"),
+            lambda plan: PSTrainer(
+                cfg=arch, mesh=self._mesh, plan=plan,
+                optimizer=config.build_optimizer(),
+                topology=self.topology.topology_at(0),
+                zero3=config.execution.zero3, aux_weight=config.aux_weight,
+                compressor=self.compressor))
 
-    @property
-    def plan(self):
-        return self.trainer.plan
+    def _price(self, epoch: int, fc_bc=None):
+        """The epoch's topology projected onto per-worker costs."""
+        topo = self.topology.topology_at(epoch)
+        if fc_bc is None:
+            return topo.topology_costs(self._profiles,
+                                       compressor=self.compressor)
+        return topo.topology_costs_measured(
+            self._profiles, fc=fc_bc[0], bc=fc_bc[1],
+            compressor=self.compressor)
 
-    def step(self, batch) -> float:
-        self._state, loss = self._step_fn(self._state, batch)
-        self._account(self.trainer.specs, self.trainer.plan,
-                      self.trainer.topology.num_workers,
-                      self.trainer.compressor)
-        self._data_idx += 1
-        return float(loss)
-
-    def timeline(self):
-        return self.trainer.timeline(self.shape)
-
-
-@register_runtime("dynamic-ps", description="run-time loop in the PS "
-                                            "regime: consensus re-plan per "
-                                            "topology epoch")
-class DynamicPSRuntime(_PSBase):
-    """Topology-epoch re-planning over the sync PS trainer."""
-
-    def __init__(self, config, arch, batch_fn):
-        super().__init__(config, arch, batch_fn)
-        from repro.ps import DynamicPSTrainer
-        self.trainer = DynamicPSTrainer(
-            cfg=arch, mesh=_data_mesh(),
-            optimizer=config.build_optimizer(),
-            topology=self._build_topology(),
-            steps_per_epoch=config.schedule.reschedule_every,
-            input_shape=self.shape, strategy=config.schedule.strategy,
-            zero3=config.execution.zero3, aux_weight=config.aux_weight,
-            compressor=config.compression.build(),
-            cost_source=config.measure.cost_source,
-            remeasure_every=config.measure.remeasure_every,
-            measure_iters=config.measure.measure_iters,
-            measure_warmup=config.measure.measure_warmup,
-            async_planning=config.schedule.async_planning,
-            plan_cache_size=config.schedule.plan_cache_size)
-        self._state = self.trainer.init_state(
-            jax.random.PRNGKey(config.seed))
-
-    @property
-    def events(self):
-        return tuple(self.trainer.events) + tuple(self._eval_events)
-
-    @property
-    def plan(self):
-        return self.trainer.plan
-
-    def step(self, batch) -> float:
-        self._state, loss = self.trainer.step(self._state, batch)
-        self._account(self.trainer.base.specs, self.trainer.plan,
-                      self.trainer.base.topology.num_workers,
-                      self.trainer.compressor)
-        self._data_idx += 1
-        return float(loss)
+    def _enter_epoch(self, epoch: int) -> None:
+        # the compiled data path is topology-independent; the trainer's
+        # accounting views (segment owners, transfer bytes, timelines)
+        # follow the active fabric
+        self.trainer.topology = self.topology.topology_at(epoch)
 
     def timeline(self):
-        return None if self.trainer.plan is None else self.trainer.timeline()
+        if self._plan is None or self._costs is None:
+            return None
+        return self.trainer.timeline_from_costs(self._costs)
 
-    def save_state(self, path: str) -> None:
-        super().save_state(path)
-        self.trainer.save_loop_state(path + ".loop")
-
-    def restore_state(self, path: str) -> None:
-        super().restore_state(path)
-        self.trainer.restore_loop_state(path + ".loop")
+    def replan_timeline(self):
+        """Re-planned vs frozen-epoch-0-plan makespans across the epochs
+        re-scheduled so far (:func:`core.simulator.simulate_ps_replan`) —
+        the stale-plan penalty the run-time loop exists to reclaim."""
+        from repro.core.buckets import decision_from_plan
+        from repro.core.simulator import simulate_ps_replan
+        if not self._replans:
+            raise ValueError("no reschedule events yet")
+        by_epoch = {e.epoch: e.plan for e in self._replans}
+        epochs = sorted(by_epoch)
+        return simulate_ps_replan(
+            [self.costs_for_epoch(e) for e in epochs],
+            [decision_from_plan(by_epoch[e]) for e in epochs])
 
 
 class _AsyncBase(RuntimeAdapter):

@@ -2,12 +2,13 @@
 
 One serialized description shared by launchers, examples, benchmarks, and
 checkpoints: a :class:`RuntimeConfig` names a registered runtime (see
-``repro.runtime.registry``) plus three nested blocks —
+``repro.runtime.registry``) plus nested blocks —
 
-* :class:`ScheduleConfig` — what the scheduler re-plans against: the
+* :class:`ScheduleConfig` — what the scheduler plans against: the
   strategy, the re-plan interval, drift detection, and either a scalar
-  edge :class:`NetworkConfig` (ZeRO regimes) or a :class:`TopologyConfig`
-  (PS regimes), both optionally time-varying;
+  edge :class:`NetworkConfig` (``zero``, ``pipeline``) or a
+  :class:`TopologyConfig` (the ``ps`` runtimes), both optionally
+  time-varying;
 * :class:`ExecutionConfig` — how plans execute: ``zero`` (bucketed ZeRO
   collectives), ``ps-sync`` (consensus plan, one pull + one push per
   segment), or ``ps-async`` (bounded-staleness event loop with a
@@ -15,6 +16,11 @@ checkpoints: a :class:`RuntimeConfig` names a registered runtime (see
 * :class:`MeasureConfig` — where fc/bc come from: deterministic analytic
   profiles or measured :class:`~repro.core.profiler.LayerTimingHook`
   wall times, re-measured every ``remeasure_every`` re-plan epochs.
+
+Whether ``zero`` and ``ps`` run the run-time re-planning loop follows
+from the configuration, not the name (:attr:`RuntimeConfig.is_dynamic`):
+a shifting network or topology, measured costs, or a drift detector
+start it; a one-knot analytic schedule is planned once.
 
 ``to_json`` → ``from_json`` is exact (``config == RuntimeConfig.from_json(
 config.to_json())``), and every cross-field inconsistency — staleness on a
@@ -33,16 +39,14 @@ from typing import Optional, Tuple, Union
 RUNTIME_REGIMES = {
     "local": "local",
     "zero": "zero",
-    "dynamic": "zero",
     "ps": "ps-sync",
-    "dynamic-ps": "ps-sync",
     "ps-async": "ps-async",
     "dynamic-ps-async": "ps-async",
     "fleet-async": "ps-async",
     "pipeline": "pipeline",
 }
-DYNAMIC_RUNTIMES = ("dynamic", "dynamic-ps", "dynamic-ps-async",
-                    "fleet-async")
+# names merged into the runtime that now runs their loop
+_MERGED_RUNTIMES = {"dynamic": "zero", "dynamic-ps": "ps"}
 
 _STRATEGIES = ("sequential", "lbl", "ibatch", "dynacomm", "bruteforce")
 _THROTTLES = ("reject", "wait")
@@ -181,7 +185,7 @@ class ScheduleConfig:
 
     strategy: str = "dynacomm"
     reschedule_every: int = 20       # steps (sync) / pushes (async) per epoch
-    drift_detect: bool = False       # dynamic runtime: EWMA step-time drift
+    drift_detect: bool = False       # zero/ps: re-plan on EWMA step-time drift
     async_planning: bool = False     # pre-plan epoch e+1 in e's idle window
     plan_cache_size: int = 256       # memoized decisions kept (LRU)
     network: Optional[NetworkConfig] = None
@@ -460,6 +464,11 @@ class RuntimeConfig:
     pipeline: Optional[PipelineConfig] = None
 
     def __post_init__(self):
+        if self.runtime in _MERGED_RUNTIMES:
+            raise ValueError(
+                f"runtime {self.runtime!r} is now "
+                f"{_MERGED_RUNTIMES[self.runtime]!r}, which re-plans "
+                f"whenever its schedule can change the plan")
         if self.runtime not in RUNTIME_REGIMES:
             raise ValueError(f"unknown runtime {self.runtime!r}; choose "
                              f"from {sorted(RUNTIME_REGIMES)}")
@@ -495,22 +504,19 @@ class RuntimeConfig:
         if regime.startswith("ps") and self.schedule.network is not None:
             raise ValueError(f"runtime {self.runtime!r} plans against a PS "
                              f"topology, not a scalar network — drop "
-                             f"schedule.network or pick a zero/dynamic "
-                             f"runtime")
-        if self.runtime in ("zero", "pipeline") and \
-                self.schedule.network is not None \
+                             f"schedule.network or pick the zero runtime")
+        if self.runtime == "pipeline" and self.schedule.network is not None \
                 and self.schedule.network.shift_gbps is not None:
             raise ValueError("a bandwidth shift needs the run-time loop to "
-                             "react to it — use runtime='dynamic' (the "
-                             f"{self.runtime!r} runtime plans once at "
-                             f"startup)")
-        if self.runtime in ("ps", "ps-async") and \
+                             "react to it — use runtime='zero' (the "
+                             "pipeline runtime plans once at startup)")
+        if self.runtime == "ps-async" and \
                 self.schedule.topology is not None and \
                 self.schedule.topology.up_shift_factor is not None:
             raise ValueError("an uplink drift needs the run-time loop to "
-                             "react to it — use runtime='dynamic-ps' or "
-                             f"'dynamic-ps-async' (the {self.runtime!r} "
-                             f"runtime plans once at startup)")
+                             "react to it — use runtime='ps' or "
+                             "'dynamic-ps-async' (the 'ps-async' runtime "
+                             "plans once at startup)")
         if self.fleet is not None and self.runtime != "fleet-async":
             raise ValueError(f"the fleet block configures the elastic "
                              f"'fleet-async' runtime (got runtime "
@@ -543,16 +549,13 @@ class RuntimeConfig:
                 f"compression rides the PS push path (segmented gradient "
                 f"uploads); runtime {self.runtime!r} is a {regime!r} regime "
                 f"— pick a ps-* runtime or set compression.scheme='none'")
-        if self.schedule.drift_detect and self.runtime != "dynamic":
-            raise ValueError("drift_detect re-schedules from observed step "
-                             "times, which only the 'dynamic' runtime "
-                             f"supports (got runtime {self.runtime!r})")
-        if self.measure.cost_source == "measured" and \
-                self.runtime not in ("dynamic", "dynamic-ps"):
-            raise ValueError("cost_source='measured' times the compiled "
-                             "per-layer applies, which the dynamic sync "
-                             "runtimes do (runtime 'dynamic' or "
-                             f"'dynamic-ps'; got {self.runtime!r})")
+        if (self.schedule.drift_detect or
+                self.measure.cost_source == "measured") and \
+                regime not in ("zero", "ps-sync"):
+            raise ValueError("drift_detect and cost_source='measured' drive "
+                             "the run-time loop of the compiled synchronous "
+                             f"runtimes 'zero' and 'ps' (got runtime "
+                             f"{self.runtime!r})")
 
     # ------------------------------------------------------------------
     # derived views
@@ -565,7 +568,16 @@ class RuntimeConfig:
 
     @property
     def is_dynamic(self) -> bool:
-        return self.runtime in DYNAMIC_RUNTIMES
+        """Whether the run-time loop runs: its network or topology has
+        more than one knot, its costs are measured, or a drift detector
+        is attached — or the runtime is ``dynamic-ps-async`` or
+        ``fleet-async``, which always re-plan."""
+        net, topo = self.schedule.network, self.schedule.topology
+        return (self.runtime in ("dynamic-ps-async", "fleet-async")
+                or self.schedule.drift_detect
+                or self.measure.cost_source == "measured"
+                or (net is not None and net.shift_gbps is not None)
+                or (topo is not None and topo.up_shift_factor is not None))
 
     def build_optimizer(self):
         from repro.optim import adamw, sgd

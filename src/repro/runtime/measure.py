@@ -1,10 +1,10 @@
 """Measured per-sched-layer fc/bc timings (the mxnet.profiler analogue).
 
-Hoisted out of ``repro.dist.dynamic`` so both dynamic drivers share one
-implementation: each sched layer's forward apply and VJP is jitted and
-timed standalone against a :class:`repro.core.profiler.LayerTimingHook`.
-The ZeRO and PS trainers share the state layout, so the same
-routine measures either — the PS driver additionally rescales the host
+Used by the re-planning loop of the ``zero`` and ``ps`` runtimes: each
+sched layer's forward apply and VJP is jitted and timed standalone
+against a :class:`repro.core.profiler.LayerTimingHook`.  The ZeRO and PS
+trainers share the state layout, so the same routine measures either —
+the ``ps`` runtime additionally rescales the host
 timings to each worker's compute rate
 (:meth:`repro.ps.topology.PSTopology.topology_costs_measured`).
 """
@@ -22,7 +22,7 @@ from repro.models import model as model_lib
 def measurement_due(fc_bc: Optional[Tuple], measured_epoch: int,
                     epoch: int, remeasure_every: int, *,
                     force: bool = False) -> bool:
-    """The shared re-measurement rule of both dynamic drivers: measure
+    """The re-measurement rule of the re-planning loop: measure
     when nothing is cached, when forced (a drift detector fired), or when
     the cache is ``remeasure_every`` re-plan epochs old
     (``remeasure_every == 0`` ⇒ measure once and keep it)."""

@@ -1,6 +1,6 @@
 """The runtime registry: name → builder, and the one factory entry point.
 
-``@register_runtime("dynamic-ps", description=...)`` on an adapter class
+``@register_runtime("ps", description=...)`` on an adapter class
 makes it buildable from a :class:`~repro.runtime.config.RuntimeConfig`
 whose ``runtime`` field carries that name; :func:`build_runtime` is the
 single construction path every launcher, example, and benchmark goes

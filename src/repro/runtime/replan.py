@@ -1,8 +1,12 @@
-"""Run-time re-planning machinery shared by every dynamic trainer.
+"""The run-time re-planning loop of the synchronous runtimes.
 
-PR 2 grew a compiled-step cache and reschedule-event bookkeeping inside
-``repro.dist.dynamic``; PR 4 duplicated the pattern for the PS regime in
-``repro.ps.dynamic``.  This module is the single home for that machinery:
+The paper's run-time loop (Section IV-C): at every epoch boundary the
+costs are priced anew (pt/gt/Δt from the epoch's network or PS topology,
+fc/bc analytic or measured), the scheduler decides, and the compiled step
+of the decided plan is swapped in.  ``zero`` and ``ps`` run the loop
+whenever their schedule can change the plan
+(:attr:`repro.runtime.config.RuntimeConfig.is_dynamic`); a one-knot
+analytic schedule is decided once at start-up and has nothing to re-plan.
 
 * :class:`PlanStepCache` — ``BucketPlan``-keyed AOT compiled-step cache:
   each distinct plan is traced and compiled exactly once
@@ -12,15 +16,11 @@ PR 2 grew a compiled-step cache and reschedule-event bookkeeping inside
 * :class:`RescheduleEvent` — one scheduling pass (paper Table I
   bookkeeping: scheduling wall time + the overhead-hidden check against
   the Δt + gt¹ idle window);
-* :class:`ReplanMixin` — the swap-and-record loop body both drivers
-  share: activate a plan (compiling on a miss, counting cache hits only
-  for genuine plan swaps), and record the ``RescheduleEvent`` for a
-  scheduling pass, including the Table I idle-window check delegated to
-  the scheduler;
-* plan/event (de)serialization helpers used by the loop-state
-  checkpointing of both drivers.
-
-``repro.dist.dynamic`` keeps deprecation shims for the old import paths.
+* :class:`ReplanMixin` — the loop, written once: boundary detection, cost
+  refresh (:func:`repro.runtime.measure.measurement_due`), decision, plan
+  swap, event record, drift detection and loop-state checkpoints.  A
+  runtime supplies its epoch pricing, its scheduler, and what a new epoch
+  re-points.
 """
 
 from __future__ import annotations
@@ -36,7 +36,9 @@ import numpy as np
 from repro.analysis.hlo import collective_counts as _collective_counts
 from repro.analysis.hlo import segment_counts
 from repro.checkpoint.ckpt import load_checkpoint, save_checkpoint
-from repro.core.buckets import BucketPlan
+from repro.core.buckets import BucketPlan, plan_from_decision
+from repro.core.profiler import EwmaDriftDetector, LayerTimingHook
+from repro.runtime.measure import measure_layer_times, measurement_due
 
 
 def hlo_collective_counts(hlo_text: str) -> Tuple[int, int]:
@@ -137,28 +139,46 @@ class PlanStepCache:
 
 
 class ReplanMixin:
-    """Shared plan-swap + event-record body of the dynamic drivers.
+    """The run-time loop over a synchronous runtime's compiled step.
 
-    A driver calls :meth:`_init_replan` from its ``__post_init__``, then
-    per scheduling pass :meth:`_activate_plan` (compile-or-lookup, swap)
-    and :meth:`_record_reschedule` (``RescheduleEvent`` with the paper's
-    Table I ``scheduling_overhead_hidden`` check — the scheduler compares
-    its last DP wall time against the costs' Δt + gt¹ idle window).
+    The host runtime holds ``config``, ``trainer`` (the active plan's
+    ZeRO or PS trainer), ``_state``, ``_step_fn`` and ``_data_idx`` (steps
+    run), calls :meth:`_init_replan` before building its trainer and
+    :meth:`_replan` before each step, and supplies ``_price(epoch, fc_bc)``
+    (the epoch's costs, with analytic fc/bc where ``fc_bc`` is None) and,
+    where a new epoch changes more than the costs, ``_enter_epoch``.
     """
 
-    def _init_replan(self, *, hlo_retention: int = DEFAULT_HLO_RETENTION
-                     ) -> None:
-        self.events: List[RescheduleEvent] = []
-        self._cache = PlanStepCache(hlo_retention=hlo_retention)
+    def _init_replan(self, scheduler, planner) -> None:
+        measure = self.config.measure
+        self.scheduler, self.planner = scheduler, planner
+        self.hook = LayerTimingHook(warmup=measure.measure_warmup)
+        self.drift_detector = (EwmaDriftDetector()
+                               if self.config.schedule.drift_detect else None)
+        self._replans: List[RescheduleEvent] = []
+        self._cache = PlanStepCache()
         self._plan: Optional[BucketPlan] = None
         self._step_fn: Optional[Callable] = None
+        self._decision = None
+        self._costs = None
+        self._measured_fc_bc: Optional[Tuple[np.ndarray, np.ndarray]] = None
+        self._measured_epoch = -1
+        self._drift_pending = False
 
-    # -- introspection (uniform across drivers) -------------------------
+    def _enter_epoch(self, epoch: int) -> None:
+        """Re-point what follows the epoch besides its costs (a hook)."""
+
+    # -- introspection ---------------------------------------------------
 
     @property
     def plan(self) -> Optional[BucketPlan]:
-        """The currently active bucket plan (None before the first step)."""
+        """The active bucket plan (None before a re-planning run's first
+        step)."""
         return self._plan
+
+    @property
+    def epoch(self) -> int:
+        return self._data_idx // self.config.schedule.reschedule_every
 
     @property
     def plans_seen(self) -> Tuple[BucketPlan, ...]:
@@ -174,42 +194,86 @@ class ReplanMixin:
         """Plan swaps served from the compiled-step cache."""
         return self._cache.hits
 
-    @property
-    def hlo_evictions(self) -> int:
-        """HLO text dumps dropped past the keep-last-N retention bound."""
-        return self._cache.hlo_evictions
-
     def hlo_counts(self, plan: Optional[BucketPlan] = None) -> Tuple[int, int]:
         """(pull, push) segments compiled in a cached plan's step."""
         return self._cache.hlo_counts(self._plan if plan is None else plan)
 
-    # -- the shared loop body -------------------------------------------
+    # -- costs -----------------------------------------------------------
 
-    def _activate_plan(self, plan: BucketPlan,
-                       build_step: Callable[[], Callable],
-                       state, batch) -> Tuple[Optional[BucketPlan], bool]:
-        """Make ``plan`` the active compiled step if it differs from the
-        current one (or none is compiled yet).  Returns
-        ``(previous_plan, retraced)``."""
-        prev = self._plan
-        retraced = False
+    def costs_for_epoch(self, epoch: int, state=None, batch=None, *,
+                        remeasure: bool = False):
+        """The epoch's costs.  With ``cost_source="measured"``, fc/bc are
+        timed on this host every ``remeasure_every`` epochs (or now, with
+        ``remeasure``), which needs ``state`` and ``batch``; without them
+        the last measurement serves."""
+        measure = self.config.measure
+        if measure.cost_source == "measured" and measurement_due(
+                self._measured_fc_bc, self._measured_epoch, epoch,
+                measure.remeasure_every, force=remeasure):
+            if state is not None and batch is not None:
+                # a PS trainer runs the step of its contained ZeroTrainer
+                zero = getattr(self.trainer, "_zero", self.trainer)
+                measure_layer_times(zero, self.hook, state, batch,
+                                    iters=measure.measure_iters)
+                L = zero.num_layers
+                self._measured_fc_bc = (self.hook.median("fc", L),
+                                        self.hook.median("bc", L))
+                self._measured_epoch = epoch
+            elif self._measured_fc_bc is None:
+                raise ValueError("cost_source='measured' needs state and "
+                                 "batch for the first measurement")
+        return self._price(epoch, self._measured_fc_bc)
+
+    # -- the loop ----------------------------------------------------------
+
+    def _replan(self, batch) -> None:
+        """Re-plan before step ``_data_idx``: on an epoch boundary, or
+        when the drift detector fired after the previous step."""
+        i, every = self._data_idx, self.config.schedule.reschedule_every
+        drift, self._drift_pending = self._drift_pending, False
+        boundary = i % every == 0 or drift
+        if boundary:
+            self._costs = self.costs_for_epoch(i // every, self._state, batch,
+                                               remeasure=drift)
+            self._enter_epoch(i // every)
+            if drift:
+                self.scheduler.invalidate()
+        decision = self.scheduler.decision_for_iteration(self._costs)
+        changed = decision != self._decision
+        # (``_step_fn is None`` off-boundary ⇒ loop state was just restored
+        # from a checkpoint: recompile the active plan, no scheduling event)
+        if not boundary and not changed and self._step_fn is not None:
+            return
+        plan = plan_from_decision(*decision, self.trainer.num_layers)
+        prev, retraced = self._plan, False
         if plan != prev or self._step_fn is None:
+            trainer = self.trainer.with_plan(plan)
             self._step_fn, retraced = self._cache.step_for(
-                plan, build_step, state, batch, count_hit=plan != prev)
-            self._plan = plan
-        return prev, retraced
-
-    def _record_reschedule(self, *, step: int, epoch: int, plan: BucketPlan,
-                           prev: Optional[BucketPlan], retraced: bool,
-                           scheduler, costs, trigger: str = "epoch") -> None:
-        """Append the ``RescheduleEvent`` for one scheduling pass."""
-        self.events.append(RescheduleEvent(
-            step=step, epoch=epoch, plan=plan,
-            plan_changed=prev is not None and plan != prev,
-            retraced=retraced,
-            scheduling_seconds=scheduler.last_scheduling_seconds,
-            overhead_hidden=scheduler.scheduling_overhead_hidden(costs),
-            trigger=trigger))
+                plan, trainer.build_train_step, self._state, batch,
+                count_hit=plan != prev)
+            self.trainer, self._plan = trainer, plan
+        self._decision = decision
+        if boundary or changed:
+            self._replans.append(RescheduleEvent(
+                step=i, epoch=i // every, plan=plan,
+                plan_changed=prev is not None and plan != prev,
+                retraced=retraced,
+                scheduling_seconds=self.scheduler.last_scheduling_seconds,
+                overhead_hidden=self.scheduler.scheduling_overhead_hidden(
+                    self._costs),
+                trigger="drift" if drift else "epoch"))
+        sched = self.config.schedule
+        if boundary and sched.async_planning and \
+                self.config.measure.cost_source == "analytic":
+            # Phase one of the async protocol: the analytic cost point of
+            # epoch e+1 is a pure function of the epoch, so its DP can run
+            # now, in this epoch's Δt + gt¹ idle window (Table I), and be
+            # collected at the next boundary.  Measured costs aren't
+            # predictable ahead of time — they solve inline (the planner's
+            # sync fallback).
+            nxt = self.costs_for_epoch(i // every + 1)
+            for costs in getattr(nxt, "workers", (nxt,)):
+                self.planner.submit(costs, sched.strategy)
 
     # -- (de)serialization for loop-state checkpointing -----------------
 
@@ -248,34 +312,31 @@ class ReplanMixin:
             overhead_hidden=e["overhead_hidden"],
             trigger=e.get("trigger", "epoch")) for e in obj]
 
-    # -- loop-state checkpointing (shared by both dynamic drivers) ------
+    # -- loop-state checkpointing ----------------------------------------
     #
     # The *model* state is an ordinary pytree checkpointed separately;
-    # this captures the re-planning bookkeeping — step/scheduler
-    # counters, active plan, event history, measurement cache — so a
-    # resumed run replays the same plan sequence.  Compiled steps are not
-    # serializable: the restored plan recompiles lazily on the first
-    # post-restore step (no scheduling event is recorded).  Drivers
-    # expect the shared attribute set (scheduler, _step_idx, cost_source,
-    # _measured_fc_bc, _measured_epoch, base.num_layers) and add their
-    # extras through ``extra_meta`` / the returned meta dict.
+    # this captures the re-planning bookkeeping — scheduler counters,
+    # active plan, event history, measurement cache, planner memo, drift
+    # detector — so a resumed run replays the same plan sequence.
+    # Compiled steps are not serializable: the restored plan recompiles
+    # lazily on the first post-restore step (no scheduling event is
+    # recorded).
 
-    def loop_state(self, *, extra_meta: Optional[Dict[str, Any]] = None
-                   ) -> Dict[str, np.ndarray]:
+    def loop_state(self) -> Dict[str, np.ndarray]:
         """The re-planning loop bookkeeping as a checkpointable pytree."""
+        det = self.drift_detector
         meta = {
             "scheduler": self.scheduler.state_dict(),
             "plan": self._plan_to_obj(self._plan),
-            "events": self._events_to_obj(self.events),
+            "events": self._events_to_obj(self._replans),
             "measured_epoch": self._measured_epoch,
+            "drift_pending": self._drift_pending,
+            "drift_detector": (det.state_dict()
+                               if hasattr(det, "state_dict") else None),
         }
-        planner = getattr(self, "planner", None)
-        if planner is not None:
-            meta["planner"] = planner.state_dict()
-        if extra_meta:
-            meta.update(extra_meta)
-        state = {"step_idx": np.asarray(self._step_idx, np.int64),
-                 "meta": np.asarray(json.dumps(meta))}
+        if self.planner is not None:
+            meta["planner"] = self.planner.state_dict()
+        state = {"meta": np.asarray(json.dumps(meta))}
         if self._measured_fc_bc is not None:
             fc, bc = self._measured_fc_bc
             state["measured_fc"] = np.asarray(fc, np.float64)
@@ -283,33 +344,33 @@ class ReplanMixin:
         return state
 
     def save_loop_state(self, path: str) -> None:
-        save_checkpoint(path, self.loop_state(), step=self._step_idx)
+        save_checkpoint(path, self.loop_state(), step=self._data_idx)
 
-    def _restore_loop_common(self, path: str) -> Dict[str, Any]:
-        """Restore the shared loop state; returns the meta dict so the
-        driver can pick up its extras."""
-        Ls = self.base.num_layers
-        template: Dict[str, np.ndarray] = {
-            "step_idx": np.zeros((), np.int64), "meta": np.asarray("")}
-        if self.cost_source == "measured":
-            with np.load(path) as probe:
-                has_measured = "measured_fc" in probe.files
-            if has_measured:       # absent ⇒ saved before 1st measurement
+    def restore_loop_state(self, path: str) -> None:
+        """Restore the loop at the step the model checkpoint restored."""
+        Ls = self.trainer.num_layers
+        template: Dict[str, np.ndarray] = {"meta": np.asarray("")}
+        with np.load(path) as probe:
+            if "measured_fc" in probe.files:   # saved after a measurement
                 template["measured_fc"] = np.zeros((Ls,), np.float64)
                 template["measured_bc"] = np.zeros((Ls,), np.float64)
         tree, _ = load_checkpoint(path, template)
         meta = json.loads(str(tree["meta"]))
-        self._step_idx = int(tree["step_idx"])
         self.scheduler.load_state_dict(dict(meta["scheduler"]))
+        self._decision = self.scheduler._decision
         self._plan = self._plan_from_obj(meta["plan"])
         self._measured_epoch = int(meta.get("measured_epoch", -1))
         if "measured_fc" in tree:
             self._measured_fc_bc = (np.asarray(tree["measured_fc"]),
                                     np.asarray(tree["measured_bc"]))
-        self.events = self._events_from_obj(meta["events"])
-        planner = getattr(self, "planner", None)
-        if planner is not None and meta.get("planner") is not None:
-            planner.load_state_dict(meta["planner"])
+        self._replans = self._events_from_obj(meta["events"])
+        if self.planner is not None and meta.get("planner") is not None:
+            self.planner.load_state_dict(meta["planner"])
+        self._drift_pending = bool(meta.get("drift_pending", False))
+        det_state = meta.get("drift_detector")
+        if det_state is not None and \
+                hasattr(self.drift_detector, "load_state_dict"):
+            self.drift_detector.load_state_dict(det_state)
         self._step_fn = None       # recompiled lazily on the next step
         self._costs = None
-        return meta
+        self._enter_epoch(self.epoch)

@@ -1,8 +1,10 @@
-"""Subprocess helper: multi-device checks for the dynamic-PS loop.
+"""Subprocess helper: multi-device checks for the ps runtime's re-planning
+loop.
 
 Run with 4 forged host devices.  Scenario: every worker's uplink degrades
-10 Gbps → 1 Gbps at topology epoch 1 and recovers at epoch 2 (a
-three-knot ``TopologySchedule``).  Prints one JSON line the parent
+10 Gbps → 1 Gbps at topology epoch 1 and recovers at epoch 2: the runtime
+is built from a configuration with an uplink shift and its topology is
+then given the recovery knot as a three-knot ``TopologySchedule``.  Prints one JSON line the parent
 asserts on:
 
 1. the consensus re-plan changes the BucketPlan when the uplinks degrade
@@ -35,8 +37,9 @@ from repro.data.pipeline import SyntheticText
 from repro.models import num_sched_layers
 from repro.models.profiles import layer_profiles
 from repro.optim import adamw
-from repro.ps import (DynamicPSTrainer, PSTopology, PSTrainer,
-                      TopologySchedule, uplink_degradation)
+from repro.ps import PSTrainer, TopologySchedule
+from repro.runtime import (RuntimeConfig, ScheduleConfig, TopologyConfig,
+                           build_runtime)
 
 STEPS_PER_EPOCH, EPOCHS = 3, 3
 B, T = 4, 32
@@ -47,19 +50,21 @@ def main():
     cfg = get_config("granite-3-2b").reduced()
     mesh = Mesh(np.array(jax.devices()).reshape(4,), ("data",))
     pipe = SyntheticText(cfg.vocab_size, T, B, seed=0)
-    base = PSTopology.uniform(2, 4, down_bps=10e9, up_bps=10e9, flops=FLOPS)
-    degraded = uplink_degradation(base, factor=10,
-                                  at_epoch=1).topology_at(1)
-    sched = TopologySchedule(knots=((0, base), (1, degraded), (2, base)))
     shape = InputShape("dyn-ps", T, B, "train")
     num_steps = STEPS_PER_EPOCH * EPOCHS
 
-    dyn = DynamicPSTrainer(cfg=cfg, mesh=mesh, optimizer=adamw(1e-3),
-                           topology=sched,
-                           steps_per_epoch=STEPS_PER_EPOCH,
-                           input_shape=shape)
-    state = dyn.init_state(jax.random.PRNGKey(0))
-    state, losses_dyn = dyn.run(state, pipe.batch, num_steps)
+    dyn = build_runtime(RuntimeConfig(
+        runtime="ps", batch=B, seq=T, lr=1e-3,
+        schedule=ScheduleConfig(
+            reschedule_every=STEPS_PER_EPOCH,
+            topology=TopologyConfig(servers=2, down_gbps=10.0,
+                                    up_gbps=10.0, worker_flops=FLOPS,
+                                    up_shift_factor=10.0, shift_epoch=1))),
+        model=cfg, data=pipe.batch)
+    base, degraded = (dyn.topology.topology_at(e) for e in (0, 1))
+    sched = TopologySchedule(knots=((0, base), (1, degraded), (2, base)))
+    dyn.topology = sched                # the recovery: a third knot
+    losses_dyn = dyn.fit(num_steps)
 
     plans = []
     for plan in dyn.plans_seen:

@@ -1,7 +1,11 @@
-"""Subprocess helper: multi-device checks for the dynamic re-scheduling loop.
+"""Subprocess helper: multi-device checks for the zero runtime's re-planning
+loop.
 
 Run with 4 forged host devices.  Scenario: a 10 Gbps → 1 Gbps → 10 Gbps
-bandwidth drift over three epochs, analytic cost source (deterministic).
+bandwidth drift over three epochs, analytic cost source (deterministic):
+the runtime is built from a configuration with a bandwidth shift and its
+network is then given the recovery knot as a three-knot
+``NetworkSchedule``.
 Prints one JSON line the parent asserts on:
 
 1. the DP re-plans to a *different* BucketPlan when the bandwidth drops,
@@ -31,11 +35,12 @@ from repro.configs.base import InputShape
 from repro.core import (EdgeNetworkModel, NetworkSchedule, costs_from_profiles,
                         plan_from_decision, schedule)
 from repro.data.pipeline import SyntheticText
-from repro.dist.dynamic import DynamicTrainer
 from repro.dist.zero import ZeroTrainer
 from repro.models import num_sched_layers
 from repro.models.profiles import layer_profiles
 from repro.optim import adamw
+from repro.runtime import (MeasureConfig, NetworkConfig, RuntimeConfig,
+                           ScheduleConfig, build_runtime)
 
 BW_HIGH, BW_LOW = 10e9, 1e9
 FLOPS = 1e10                 # edge-worker compute rate fed to the profiler
@@ -54,11 +59,16 @@ def main():
     ))
     num_steps = STEPS_PER_EPOCH * EPOCHS
 
-    dyn = DynamicTrainer(cfg=cfg, mesh=mesh, optimizer=adamw(1e-3),
-                         network=net, steps_per_epoch=STEPS_PER_EPOCH,
-                         compute_flops_per_s=FLOPS)
-    state = dyn.init_state(jax.random.PRNGKey(0))
-    state, losses_dyn = dyn.run(state, pipe.batch, num_steps)
+    dyn = build_runtime(RuntimeConfig(
+        runtime="zero", batch=B, seq=T, lr=1e-3,
+        schedule=ScheduleConfig(
+            reschedule_every=STEPS_PER_EPOCH,
+            network=NetworkConfig(bandwidth_gbps=BW_HIGH / 1e9,
+                                  shift_gbps=BW_LOW / 1e9, shift_epoch=1)),
+        measure=MeasureConfig(compute_flops_per_s=FLOPS)),
+        model=cfg, data=pipe.batch)
+    dyn.network = net                   # the recovery: a third knot
+    losses_dyn = dyn.fit(num_steps)
 
     plans = []
     for plan in dyn.plans_seen:

@@ -816,16 +816,6 @@ class TestLints:
         ok = "def op(x, interpret=None):\n    return x\n"
         assert codes(ok, path="src/repro/kernels/foo/ops.py") == []
 
-    def test_deprecated_alias_imports(self):
-        assert codes("from repro.dist.dynamic import PlanStepCache\n") == \
-            ["DEPRECATED-IMPORT"]
-        assert codes("from repro.ps.dynamic import sequential_plan\n") == \
-            ["DEPRECATED-IMPORT"]
-        # the classes that still live there are fine
-        assert codes("from repro.dist.dynamic import DynamicTrainer\n") == []
-        assert codes(
-            "from repro.runtime.replan import PlanStepCache\n") == []
-
     def test_noqa_suppression(self):
         assert codes("import random\nrandom.random()  # noqa\n") == []
         assert codes("import random\n"
@@ -903,7 +893,7 @@ class TestVerifyRuntimeInProcess:
         assert info["steps_run"] == 1
 
     def test_dynamic_cache(self):
-        info = self._verify("dynamic.json")
+        info = self._verify("zero_bandwidth_shift.json")
         assert info["plans_seen"] >= 1
         assert info["traces"] == info["plans_seen"]
 

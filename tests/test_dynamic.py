@@ -1,5 +1,5 @@
 """Dynamic re-scheduling: time-varying networks, the per-layer timing hook,
-and the DynamicTrainer loop.
+and the re-planning loop of the zero and ps runtimes.
 
 Quick tests run single-device at the cost-model level; the multi-device
 trainer claims (plan swap, step-cache hit counts, bit-identical losses,
@@ -355,21 +355,31 @@ class TestDriftChangesDecision:
         assert decisions[0] != decisions[1]
 
 
+def _zero_loop(cfg, pipe, *, every, shift_epoch=1, **schedule):
+    """The zero runtime re-planning over a 10 → 1 Gbps network."""
+    from repro.runtime import (MeasureConfig, NetworkConfig, RuntimeConfig,
+                               ScheduleConfig, build_runtime)
+    config = RuntimeConfig(
+        runtime="zero", batch=pipe.batch_size, seq=pipe.seq_len, lr=1e-3,
+        schedule=ScheduleConfig(
+            reschedule_every=every, network=NetworkConfig(
+                bandwidth_gbps=10.0, shift_gbps=1.0,
+                shift_epoch=shift_epoch), **schedule),
+        measure=MeasureConfig(compute_flops_per_s=1e10))
+    return build_runtime(config, model=cfg, data=pipe.batch)
+
+
 class TestDynamicTrainerSingleDevice:
     def test_constructor_validation(self):
-        import jax
-        from jax.sharding import Mesh
-        from repro.dist.dynamic import DynamicTrainer
-        from repro.optim import sgd
-
-        cfg = get_config("granite-3-2b").reduced()
-        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-        kw = dict(cfg=cfg, mesh=mesh, optimizer=sgd(1e-2, 0.9),
-                  network=EdgeNetworkModel())
-        with pytest.raises(ValueError, match="steps_per_epoch"):
-            DynamicTrainer(steps_per_epoch=0, **kw)
+        """The loop's interval and cost source are checked where the
+        configuration is made, before any trainer is built."""
+        from repro.runtime import MeasureConfig, RuntimeConfig, ScheduleConfig
+        with pytest.raises(ValueError, match="reschedule_every"):
+            RuntimeConfig(runtime="zero",
+                          schedule=ScheduleConfig(reschedule_every=0))
         with pytest.raises(ValueError, match="cost_source"):
-            DynamicTrainer(steps_per_epoch=5, cost_source="psychic", **kw)
+            RuntimeConfig(runtime="zero",
+                          measure=MeasureConfig(cost_source="psychic"))
 
     def test_sequential_plan_shape(self):
         from repro.runtime.replan import sequential_plan
@@ -473,45 +483,28 @@ class TestDynamicLoopStateSingleDevice:
 
     @pytest.fixture(scope="class")
     def setup(self):
-        import jax
-        from jax.sharding import Mesh
         from repro.data.pipeline import SyntheticText
-        from repro.optim import adamw
-
         cfg = get_config("granite-3-2b").reduced()
-        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
         pipe = SyntheticText(cfg.vocab_size, 32, 4, seed=0)
-        kw = dict(cfg=cfg, mesh=mesh, optimizer=adamw(1e-3),
-                  network=bandwidth_shift(10e9, 1e9, at_epoch=2),
-                  steps_per_epoch=2, compute_flops_per_s=1e10)
-        return kw, pipe
+        return cfg, pipe
 
     def test_resume_is_bit_identical(self, setup, tmp_path):
-        import jax
-        from repro.dist.dynamic import DynamicTrainer
-        kw, pipe = setup
+        cfg, pipe = setup
 
-        ref = DynamicTrainer(**kw)
-        state = ref.init_state(jax.random.PRNGKey(0))
-        state, ref_losses = ref.run(state, pipe.batch, 6)
+        ref = _zero_loop(cfg, pipe, every=2, shift_epoch=2)
+        ref_losses = ref.fit(6)
 
-        a = DynamicTrainer(**kw)
-        sa = a.init_state(jax.random.PRNGKey(0))
-        losses = []
-        for i in range(3):                        # stop mid-epoch
-            sa, l = a.step(sa, pipe.batch(i))
-            losses.append(float(l))
-        path = str(tmp_path / "loop.npz")
-        a.save_loop_state(path)
+        a = _zero_loop(cfg, pipe, every=2, shift_epoch=2)
+        losses = a.fit(3)                         # stop mid-epoch
+        path = str(tmp_path / "rt.npz")
+        a.save_state(path)
 
-        b = DynamicTrainer(**kw)                  # fresh trainer, no memory
-        b.restore_loop_state(path)
-        assert b.step_index == 3
+        b = _zero_loop(cfg, pipe, every=2, shift_epoch=2)  # no memory
+        b.restore_state(path)
+        assert b._data_idx == 3
         assert b.plan == a.plan
         assert [e.step for e in b.events] == [e.step for e in a.events]
-        for i in range(3, 6):
-            sa, l = b.step(sa, pipe.batch(i))
-            losses.append(float(l))
+        losses += b.fit(3)
         assert losses == ref_losses
         # resume replays the same re-schedule history as the straight run
         assert [(e.step, e.epoch, e.plan) for e in b.events] == \
@@ -520,9 +513,7 @@ class TestDynamicLoopStateSingleDevice:
         assert len(b.events) == len(ref.events)
 
     def test_drift_detector_forces_reschedule(self, setup):
-        import jax
-        from repro.dist.dynamic import DynamicTrainer
-        kw, pipe = setup
+        cfg, pipe = setup
 
         class FireOnce:
             calls = 0
@@ -531,11 +522,9 @@ class TestDynamicLoopStateSingleDevice:
                 self.calls += 1
                 return self.calls == 2            # fires after step 2
 
-        dyn = DynamicTrainer(drift_detector=FireOnce(),
-                             **{**kw, "steps_per_epoch": 100})
-        state = dyn.init_state(jax.random.PRNGKey(0))
-        for i in range(4):
-            state, _ = dyn.step(state, pipe.batch(i))
+        dyn = _zero_loop(cfg, pipe, every=100, drift_detect=True)
+        dyn.drift_detector = FireOnce()
+        dyn.fit(4)
         triggers = [(e.step, e.trigger) for e in dyn.events]
         assert triggers[0] == (0, "epoch")
         assert (2, "drift") in triggers           # detector-forced re-plan
@@ -543,37 +532,33 @@ class TestDynamicLoopStateSingleDevice:
 
 
 class TestDynamicPSTrainerSingleDevice:
-    """The dynamic-PS loop on a 1-device mesh: plan swap exactly on the
-    topology-epoch boundary, compiled-step cache, and sync losses
-    bit-identical to statically running each epoch's plan (the ISSUE 4
-    acceptance criterion; the 4-forged-device version runs in the slow
+    """The ps runtime's loop on a 1-device mesh over a degrading uplink:
+    plan swap exactly on the topology-epoch boundary, compiled-step
+    cache, and sync losses bit-identical to statically running each
+    epoch's plan (the 4-forged-device version runs in the slow
     subprocess check)."""
 
     STEPS_PER_EPOCH = 2
 
     @pytest.fixture(scope="class")
     def run(self):
-        import jax
-        from jax.sharding import Mesh
         from repro.data.pipeline import SyntheticText
-        from repro.optim import adamw
-        from repro.ps import (DynamicPSTrainer, PSTopology,
-                              uplink_degradation)
+        from repro.runtime import (RuntimeConfig, ScheduleConfig,
+                                   TopologyConfig, build_runtime)
 
         cfg = get_config("granite-3-2b").reduced()
-        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-        base = PSTopology.uniform(2, 1, down_bps=10e9, up_bps=10e9,
-                                  flops=1e10)
-        sched = uplink_degradation(base, factor=10, at_epoch=1)
-        shape = InputShape("dyn-ps", 32, 4, "train")
         pipe = SyntheticText(cfg.vocab_size, 32, 4, seed=0)
-        dyn = DynamicPSTrainer(cfg=cfg, mesh=mesh, optimizer=adamw(1e-3),
-                               topology=sched,
-                               steps_per_epoch=self.STEPS_PER_EPOCH,
-                               input_shape=shape)
-        state = dyn.init_state(jax.random.PRNGKey(0))
-        state, losses = dyn.run(state, pipe.batch, 3 * self.STEPS_PER_EPOCH)
-        return dyn, sched, pipe, losses
+        config = RuntimeConfig(
+            runtime="ps", batch=4, seq=32, lr=1e-3,
+            schedule=ScheduleConfig(
+                reschedule_every=self.STEPS_PER_EPOCH,
+                topology=TopologyConfig(
+                    servers=2, down_gbps=10.0, up_gbps=10.0,
+                    worker_flops=1e10, up_shift_factor=10.0,
+                    shift_epoch=1)))
+        dyn = build_runtime(config, model=cfg, data=pipe.batch)
+        losses = dyn.fit(3 * self.STEPS_PER_EPOCH)
+        return dyn, dyn.topology, pipe, losses
 
     def test_plan_swaps_exactly_on_boundary_steps(self, run):
         dyn, _, _, _ = run
@@ -593,7 +578,7 @@ class TestDynamicPSTrainerSingleDevice:
         assert not dyn.events[2].retraced          # epoch 2 keeps the plan
         # one device and no compressor: the state is the leaves, so the
         # step has no wire and no collective to count
-        assert dyn.base.layout == "leaves"
+        assert dyn.trainer.layout == "leaves"
         for plan in dyn.plans_seen:
             assert dyn.hlo_counts(plan) == (0, 0)
 
@@ -610,7 +595,8 @@ class TestDynamicPSTrainerSingleDevice:
         cfg = get_config("granite-3-2b").reduced()
         shape = InputShape("dyn-ps", 32, 4, "train")
         profs = layer_profiles(cfg, shape)
-        base = PSTrainer(cfg=cfg, mesh=dyn.mesh, plan=dyn.plans_seen[0],
+        base = PSTrainer(cfg=cfg, mesh=dyn.trainer.mesh,
+                         plan=dyn.plans_seen[0],
                          optimizer=adamw(1e-3),
                          topology=sched.topology_at(0))
         state = base.init_state(jax.random.PRNGKey(0))
@@ -655,23 +641,19 @@ class TestDynamicPSTrainerSingleDevice:
             assert rp.makespans[e] <= rp.frozen_makespans[e] + 1e-12
 
     def test_constructor_validation(self):
-        import jax
-        from jax.sharding import Mesh
-        from repro.optim import adamw
-        from repro.ps import DynamicPSTrainer, PSTopology
+        from repro.runtime import (RuntimeConfig, ScheduleConfig,
+                                   TopologyConfig, build_runtime)
         cfg = get_config("granite-3-2b").reduced()
-        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-        with pytest.raises(ValueError, match="steps_per_epoch"):
-            DynamicPSTrainer(cfg=cfg, mesh=mesh, optimizer=adamw(1e-3),
-                             topology=PSTopology.uniform(1, 1),
-                             steps_per_epoch=0,
-                             input_shape=InputShape("x", 32, 4, "train"))
+        with pytest.raises(ValueError, match="reschedule_every"):
+            ScheduleConfig(reschedule_every=0,
+                           topology=TopologyConfig(up_shift_factor=10.0))
         with pytest.raises(ValueError, match="workers"):
             # 4-worker schedule on a 1-device mesh
-            DynamicPSTrainer(cfg=cfg, mesh=mesh, optimizer=adamw(1e-3),
-                             topology=PSTopology.uniform(1, 4),
-                             steps_per_epoch=2,
-                             input_shape=InputShape("x", 32, 4, "train"))
+            build_runtime(RuntimeConfig(
+                runtime="ps", batch=4, seq=32,
+                schedule=ScheduleConfig(topology=TopologyConfig(
+                    servers=1, workers=4, up_shift_factor=10.0))),
+                model=cfg)
 
 
 def _run_helper(name):

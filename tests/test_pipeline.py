@@ -355,27 +355,27 @@ class TestPlannerPersistence:
         assert restored.stats.hits == 3 and restored.stats.solves == 0
 
     def test_resumed_replan_is_cache_hit(self, tmp_path):
-        """Dynamic runtime: save mid-run, restore fresh, re-plan at the
-        next epoch boundary — the restored decision cache must serve it
-        without a single new DP solve."""
+        """The zero runtime's re-planning loop: save mid-run, restore
+        fresh, re-plan at the next epoch boundary — the restored decision
+        cache must serve it without a single new DP solve."""
         from repro.runtime import (NetworkConfig, RuntimeConfig,
                                    ScheduleConfig, build_runtime)
         cfg = RuntimeConfig(
-            runtime="dynamic", batch=2, seq=16,
+            runtime="zero", batch=2, seq=16,
             schedule=ScheduleConfig(
                 strategy="dynacomm", reschedule_every=2,
                 network=NetworkConfig(bandwidth_gbps=1.0, shift_gbps=0.1,
                                       shift_epoch=1)))
         rt = build_runtime(cfg)
         rt.fit(3)                       # crosses a re-plan boundary
-        assert len(rt.trainer.planner) > 0
+        assert len(rt.planner) > 0
         path = str(tmp_path / "ck.npz")
         rt.save_state(path)
         rt2 = build_runtime(cfg)
         rt2.restore_state(path)
-        assert len(rt2.trainer.planner) == len(rt.trainer.planner)
+        assert len(rt2.planner) == len(rt.planner)
         rt2.fit(3)                      # next boundary re-plans
-        stats = rt2.trainer.planner.stats
+        stats = rt2.planner.stats
         assert stats.hits > 0, stats.as_dict()
         assert stats.solves == 0, stats.as_dict()
 

@@ -1,6 +1,6 @@
 """The repro.runtime layer: config round-trips, the registry, the Trainer
-protocol over every regime, deprecation shims, BSP push aggregation, and
-measured per-worker PS costs (ISSUE 5).
+protocol over every regime, the plan-once and re-planning paths of zero
+and ps, BSP push aggregation, and measured per-worker PS costs.
 
 Every registered runtime is built from its checked-in smoke config
 (``examples/runtime_configs/*.json``) and driven single-device; invalid
@@ -94,13 +94,13 @@ class TestRuntimeConfig:
 
     def test_aggregate_on_sync_runtime_rejected(self):
         with pytest.raises(ValueError, match="aggregate"):
-            RuntimeConfig(runtime="dynamic-ps",
+            RuntimeConfig(runtime="ps",
                           execution=ExecutionConfig(throttle="wait",
                                                     aggregate=True))
 
     def test_topology_on_zero_regime_rejected(self):
         with pytest.raises(ValueError, match="topology"):
-            RuntimeConfig(runtime="dynamic",
+            RuntimeConfig(runtime="zero",
                           schedule=ScheduleConfig(topology=TopologyConfig()))
 
     def test_network_on_ps_regime_rejected(self):
@@ -108,25 +108,36 @@ class TestRuntimeConfig:
             RuntimeConfig(runtime="ps",
                           schedule=ScheduleConfig(network=NetworkConfig()))
 
-    def test_drift_on_static_runtime_needs_dynamic(self):
-        with pytest.raises(ValueError, match="dynamic"):
-            RuntimeConfig(runtime="zero",
+    @pytest.mark.parametrize("old,new", [("dynamic", "zero"),
+                                         ("dynamic-ps", "ps")])
+    def test_merged_runtime_name_refused(self, old, new):
+        """The re-planning loop belongs to zero and ps now: an old
+        configuration naming its own loop runtime is refused, naming the
+        runtime to use."""
+        with pytest.raises(ValueError, match=f"now '{new}'"):
+            RuntimeConfig(runtime=old)
+        with pytest.raises(ValueError, match=f"now '{new}'"):
+            RuntimeConfig.from_dict({"runtime": old})
+
+    def test_shifts_on_plan_once_runtimes_rejected(self):
+        with pytest.raises(ValueError, match="runtime='zero'"):
+            RuntimeConfig(runtime="pipeline",
                           schedule=ScheduleConfig(
                               network=NetworkConfig(shift_gbps=1.0)))
-        with pytest.raises(ValueError, match="dynamic-ps"):
-            RuntimeConfig(runtime="ps",
+        with pytest.raises(ValueError, match="runtime='ps'"):
+            RuntimeConfig(runtime="ps-async",
+                          execution=ExecutionConfig(staleness=1),
                           schedule=ScheduleConfig(
                               topology=TopologyConfig(up_shift_factor=4.0)))
 
-    def test_drift_detect_only_on_dynamic(self):
-        with pytest.raises(ValueError, match="drift_detect"):
-            RuntimeConfig(runtime="zero",
-                          schedule=ScheduleConfig(drift_detect=True))
-
-    def test_measured_only_on_dynamic_sync(self):
-        with pytest.raises(ValueError, match="measured"):
-            RuntimeConfig(runtime="zero",
-                          measure=MeasureConfig(cost_source="measured"))
+    def test_loop_knobs_only_on_zero_and_ps(self):
+        for runtime in ("local", "pipeline", "ps-async"):
+            with pytest.raises(ValueError, match="drift_detect"):
+                RuntimeConfig(runtime=runtime,
+                              schedule=ScheduleConfig(drift_detect=True))
+            with pytest.raises(ValueError, match="measured"):
+                RuntimeConfig(runtime=runtime,
+                              measure=MeasureConfig(cost_source="measured"))
 
     def test_regime_mismatch_rejected(self):
         with pytest.raises(ValueError, match="contradicts"):
@@ -143,6 +154,18 @@ class TestRuntimeConfig:
                           execution=ExecutionConfig(staleness=1))
         assert c.regime == "ps-async" and c.is_dynamic
         assert not RuntimeConfig(runtime="ps").is_dynamic
+        assert not RuntimeConfig(runtime="zero").is_dynamic
+        # the loop runs wherever the schedule can change the plan
+        for dynamic in (
+                RuntimeConfig(runtime="zero", schedule=ScheduleConfig(
+                    network=NetworkConfig(shift_gbps=1.0))),
+                RuntimeConfig(runtime="ps", schedule=ScheduleConfig(
+                    topology=TopologyConfig(up_shift_factor=4.0))),
+                RuntimeConfig(runtime="zero", schedule=ScheduleConfig(
+                    drift_detect=True)),
+                RuntimeConfig(runtime="ps", measure=MeasureConfig(
+                    cost_source="measured"))):
+            assert dynamic.is_dynamic, dynamic
 
     def test_per_worker_length_mismatch_rejected_at_build(self):
         topo = TopologyConfig(workers=3, up_gbps=(1.0, 2.0))
@@ -253,8 +276,7 @@ class TestFleetConfig:
 
 class TestRegistry:
     def test_all_runtimes_registered(self):
-        assert runtime_names() == ("dynamic", "dynamic-ps",
-                                   "dynamic-ps-async", "fleet-async",
+        assert runtime_names() == ("dynamic-ps-async", "fleet-async",
                                    "local", "pipeline", "ps", "ps-async",
                                    "zero")
 
@@ -285,21 +307,26 @@ class TestRegistry:
 
 @pytest.fixture(scope="module")
 def built():
-    """Lazily built runtimes, one per smoke config, shared module-wide."""
+    """Lazily built runtimes, one per smoke config, shared module-wide:
+    by config file name (``zero_bandwidth_shift.json``) or by runtime
+    name, which picks that runtime's baseline config."""
     cache = {}
 
-    def get(runtime_name):
-        if runtime_name not in cache:
-            # a runtime may ship feature-variant configs alongside its
-            # baseline (e.g. ps_async_int8.json) — pick the uncompressed one
+    def get(key):
+        if key.endswith(".json"):
+            path = os.path.join(SMOKE_DIR, key)
+        else:
+            # a runtime may ship variant configs alongside its baseline
+            # (ps_async_int8.json, zero_bandwidth_shift.json): the
+            # uncompressed one first in name order is the baseline
             paths = sorted(p for p in smoke_config_paths()
-                           if RuntimeConfig.load(p).runtime == runtime_name)
-            assert paths, f"no smoke config for {runtime_name}"
+                           if RuntimeConfig.load(p).runtime == key)
+            assert paths, f"no smoke config for {key}"
             path = min(paths, key=lambda p:
                        RuntimeConfig.load(p).compression.enabled)
-            cache[runtime_name] = (build_runtime(RuntimeConfig.load(path)),
-                                   path)
-        return cache[runtime_name]
+        if path not in cache:
+            cache[path] = (build_runtime(RuntimeConfig.load(path)), path)
+        return cache[path]
 
     return get
 
@@ -308,9 +335,8 @@ LEDGER_KEYS = {"pull_bytes", "push_bytes", "num_pulls", "num_pushes"}
 
 
 class TestEveryRuntime:
-    @pytest.mark.parametrize("name", ["local", "zero", "ps", "ps-async",
-                                      "dynamic", "dynamic-ps",
-                                      "dynamic-ps-async", "fleet-async"])
+    @pytest.mark.parametrize("name", [os.path.basename(p)
+                                      for p in smoke_config_paths()])
     def test_builds_from_json_and_steps(self, built, name):
         rt, path = built(name)
         assert isinstance(rt, Trainer), f"{name} breaks the protocol"
@@ -321,7 +347,7 @@ class TestEveryRuntime:
         assert rt.config == RuntimeConfig.load(path)   # config preserved
 
     def test_dynamic_runtimes_reschedule_and_resegment(self, built):
-        for name in ("dynamic", "dynamic-ps"):
+        for name in ("zero_bandwidth_shift.json", "ps_uplink_shift.json"):
             rt, _ = built(name)
             total = rt._data_idx
             rt.fit(4 - min(total, 4))           # reach the shift boundary
@@ -369,7 +395,7 @@ class TestEveryRuntime:
 class TestSaveRestore:
     def test_dynamic_ps_resume_is_bit_identical(self, tmp_path):
         config = RuntimeConfig(
-            runtime="dynamic-ps", **SMOKE,
+            runtime="ps", **SMOKE,
             schedule=ScheduleConfig(reschedule_every=2,
                                     topology=TopologyConfig(
                                         servers=2, up_shift_factor=10.0,
@@ -437,56 +463,128 @@ class TestPeriodicCheckpoint:
 
 
 # ---------------------------------------------------------------------------
-# deprecation shims: old import paths + old hand-wired construction
+# the removed aliases and drivers: the re-planning names live in
+# repro.runtime.replan alone, and the loop runs inside zero and ps
 # ---------------------------------------------------------------------------
 
 
 class TestDeprecationShims:
     def test_moved_classes_warn_and_alias(self):
-        import repro.dist.dynamic as dd
+        """The alias paths are gone: ``repro.dist.dynamic`` no longer
+        exists and ``repro.ps.dynamic`` serves none of the moved names."""
+        import importlib
+
+        import repro.ps.dynamic as pd
         from repro.runtime import replan
-        for name in ("PlanStepCache", "RescheduleEvent"):
-            with pytest.deprecated_call(match="moved to"):
-                cls = getattr(dd, name)
-            assert cls is getattr(replan, name)
+        with pytest.raises(ModuleNotFoundError):
+            importlib.import_module("repro.dist.dynamic")
+        for name in ("PlanStepCache", "RescheduleEvent",
+                     "hlo_collective_counts", "sequential_plan"):
+            assert not hasattr(pd, name)
+            assert hasattr(replan, name)
 
     def test_unknown_attribute_still_raises(self):
-        import repro.dist.dynamic as dd
+        import repro.ps.dynamic as pd
         with pytest.raises(AttributeError):
-            dd.does_not_exist
+            pd.does_not_exist
 
     def test_old_style_construction_matches_factory_losses(self, built):
-        """The pre-registry wiring (hand-built DynamicTrainer, the old
-        launch/train.py path) must produce losses bit-identical to the
-        factory-built runtime on the same config."""
-        from jax.sharding import Mesh
+        """Hand-wiring the loop's plan sequence — each epoch's analytic
+        decision, one jitted ZeroTrainer step per plan — gives losses
+        bit-identical to the factory-built zero runtime re-planning over
+        the same shifting network."""
         from repro.configs import get_config
-        from repro.core import bandwidth_shift
+        from repro.core import (costs_from_profiles, plan_from_decision,
+                                schedule)
         from repro.data.pipeline import SyntheticText
-        from repro.dist.dynamic import DynamicTrainer
-        from repro.optim import adamw
+        from repro.dist.zero import ZeroTrainer
+        from repro.models import num_sched_layers
+        from repro.models.profiles import layer_profiles
+        from repro.runtime.replan import sequential_plan
 
-        rt, path = built("dynamic")
+        rt, path = built("zero_bandwidth_shift.json")
         config = RuntimeConfig.load(path)
         cfg = get_config(config.arch).reduced()
-        mesh = Mesh(np.array(jax.devices()).reshape(len(jax.devices()),),
-                    ("data",))
-        net_cfg = config.schedule.network
-        old = DynamicTrainer(
-            cfg=cfg, mesh=mesh, optimizer=adamw(config.lr),
-            network=bandwidth_shift(net_cfg.bandwidth_gbps * 1e9,
-                                    net_cfg.shift_gbps * 1e9,
-                                    at_epoch=net_cfg.shift_epoch),
-            steps_per_epoch=config.schedule.reschedule_every,
-            strategy=config.schedule.strategy,
-            input_shape=rt.shape,
-            compute_flops_per_s=config.measure.compute_flops_per_s)
-        state = old.init_state(jax.random.PRNGKey(config.seed))
+        net = config.schedule.network.build()
+        Ls = num_sched_layers(cfg)
+        base = ZeroTrainer(cfg=cfg, mesh=rt.trainer.mesh,
+                           plan=sequential_plan(Ls),
+                           optimizer=config.build_optimizer())
+        state = base.init_state(jax.random.PRNGKey(config.seed))
         pipe = SyntheticText(cfg.vocab_size, config.seq, config.batch,
                              seed=config.seed)
-        _, old_losses = old.run(state, pipe.batch, 4)
+        old_losses, fns = [], {}
+        for i in range(4):
+            costs = costs_from_profiles(
+                layer_profiles(cfg, rt.shape),
+                net=net.model_at(i // config.schedule.reschedule_every),
+                compute_flops_per_s=config.measure.compute_flops_per_s)
+            plan = plan_from_decision(
+                *schedule(costs, config.schedule.strategy), Ls)
+            if plan not in fns:
+                fns[plan] = jax.jit(base.with_plan(plan).build_train_step())
+            state, loss = fns[plan](state, pipe.batch(i))
+            old_losses.append(float(loss))
+        assert len(fns) == 2                    # the shift moved the plan
         new = build_runtime(config)             # fresh, same config
         assert new.fit(4) == old_losses
+
+
+# ---------------------------------------------------------------------------
+# zero and ps on a one-knot analytic schedule: planned once, no loop
+# ---------------------------------------------------------------------------
+
+
+class TestStaticPlan:
+    @pytest.mark.parametrize("name", ["zero", "ps"])
+    def test_static_runtime_plans_once(self, name):
+        config = RuntimeConfig.load(os.path.join(SMOKE_DIR, f"{name}.json"))
+        assert not config.is_dynamic
+        rt = build_runtime(config)
+        calls = []
+        decide = rt.scheduler.decision_for_iteration
+        rt.scheduler.decision_for_iteration = \
+            lambda costs: calls.append(costs) or decide(costs)
+        # decided at construction, from epoch 0's analytic costs
+        assert rt.plan is not None and rt.scheduler._iter_seen == 1
+        plan = rt.plan
+        batch = rt._batch_fn(0)
+        text = rt.compiled_step_text(batch)
+        assert "ENTRY" in text
+        losses = rt.fit(config.schedule.reschedule_every + 2)
+        assert all(np.isfinite(losses))
+        assert calls == [] and rt.scheduler._iter_seen == 1
+        assert rt.events == () and rt.traces == 0
+        assert rt.plan == plan == rt.trainer.plan
+        params = rt.trainer.params_from_state(rt._state)
+        assert jax.tree_util.tree_leaves(params)
+        assert rt.timeline() is not None
+
+    @pytest.mark.parametrize("knob", ["drift_detect", "measured"])
+    def test_zero_loop_knobs_build_and_replan(self, knob):
+        """Drift detection and measured costs start zero's loop on a
+        static network: an event at every epoch boundary, the plan-keyed
+        step cache, and (measured) fc/bc timed on this host."""
+        schedule = ScheduleConfig(reschedule_every=2,
+                                  drift_detect=knob == "drift_detect")
+        measure = MeasureConfig(
+            cost_source="measured" if knob == "measured" else "analytic",
+            measure_iters=1, measure_warmup=0)
+        config = RuntimeConfig(runtime="zero", **SMOKE, schedule=schedule,
+                               measure=measure)
+        assert config.is_dynamic
+        rt = build_runtime(config)
+        assert rt.plan is None                  # decided at the first step
+        assert (rt.drift_detector is not None) == (knob == "drift_detect")
+        losses = rt.fit(5)
+        assert all(np.isfinite(losses))
+        # (a detector may also fire on host timing noise: "drift" events)
+        assert {0, 2, 4} <= {e.step for e in rt.events}
+        assert rt.traces == len(rt.plans_seen) >= 1
+        if knob == "measured":
+            assert rt._measured_epoch == 2
+            fc, _ = rt._measured_fc_bc
+            assert np.all(fc > 0)
 
 
 # ---------------------------------------------------------------------------
@@ -714,64 +812,36 @@ class TestMeasuredPSCosts:
             topo.topology_costs_measured(profiles, fc=[1e-3], bc=[1e-3],
                                          ref_flops=0.0)
 
-    def test_dynamic_ps_measured_remeasures_on_schedule(self):
-        """remeasure_every threads into DynamicPSTrainer the way the
-        ZeRO-side DynamicTrainer already re-measures."""
-        from jax.sharding import Mesh
-        from repro.configs import get_config
-        from repro.configs.base import InputShape
-        from repro.data.pipeline import SyntheticText
-        from repro.optim import adamw
-        from repro.ps import DynamicPSTrainer, PSTopology
+    MEASURED_PS = RuntimeConfig(
+        runtime="ps", **SMOKE,
+        schedule=ScheduleConfig(reschedule_every=2,
+                                topology=TopologyConfig(servers=2)),
+        measure=MeasureConfig(cost_source="measured", remeasure_every=2,
+                              measure_iters=1, measure_warmup=0))
 
-        cfg = get_config("granite-3-2b").reduced()
-        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-        pipe = SyntheticText(cfg.vocab_size, 16, 2, seed=0)
-        dyn = DynamicPSTrainer(
-            cfg=cfg, mesh=mesh, optimizer=adamw(1e-3),
-            topology=PSTopology.uniform(2, 1),
-            steps_per_epoch=2, input_shape=InputShape("m", 16, 2, "train"),
-            cost_source="measured", remeasure_every=2,
-            measure_iters=1, measure_warmup=0)
-        state = dyn.init_state(jax.random.PRNGKey(0))
-        state, losses = dyn.run(state, pipe.batch, 6)
+    def test_dynamic_ps_measured_remeasures_on_schedule(self):
+        """remeasure_every threads into the ps runtime's loop the way
+        zero's loop re-measures (one loop serves both)."""
+        rt = build_runtime(self.MEASURED_PS)
+        losses = rt.fit(6)
         assert len(losses) == 6
         # epochs 0,1,2 re-planned; measurement at 0, re-measured at 2
-        assert [e.epoch for e in dyn.events] == [0, 1, 2]
-        assert dyn._measured_epoch == 2
+        assert [e.epoch for e in rt.events] == [0, 1, 2]
+        assert rt._measured_epoch == 2
         # the cached measurement feeds the cost projection
-        costs = dyn.costs_for_epoch(0)
+        costs = rt.costs_for_epoch(0)
         np.testing.assert_allclose(np.asarray(costs.workers[0].fc),
-                                   np.asarray(dyn._measured_fc_bc[0]))
+                                   np.asarray(rt._measured_fc_bc[0]))
 
     def test_measured_first_projection_needs_state_and_batch(self):
-        from jax.sharding import Mesh
-        from repro.configs import get_config
-        from repro.configs.base import InputShape
-        from repro.optim import adamw
-        from repro.ps import DynamicPSTrainer, PSTopology
-        cfg = get_config("granite-3-2b").reduced()
-        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-        dyn = DynamicPSTrainer(
-            cfg=cfg, mesh=mesh, optimizer=adamw(1e-3),
-            topology=PSTopology.uniform(2, 1), steps_per_epoch=2,
-            input_shape=InputShape("m", 16, 2, "train"),
-            cost_source="measured")
+        rt = build_runtime(self.MEASURED_PS)
         with pytest.raises(ValueError, match="state and batch"):
-            dyn.costs_for_epoch(0)
+            rt.costs_for_epoch(0)
 
     def test_validation(self):
-        from jax.sharding import Mesh
-        from repro.configs import get_config
-        from repro.configs.base import InputShape
-        from repro.optim import adamw
-        from repro.ps import DynamicPSTrainer, PSTopology
-        cfg = get_config("granite-3-2b").reduced()
-        mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-        kw = dict(cfg=cfg, mesh=mesh, optimizer=adamw(1e-3),
-                  topology=PSTopology.uniform(2, 1), steps_per_epoch=2,
-                  input_shape=InputShape("m", 16, 2, "train"))
         with pytest.raises(ValueError, match="cost_source"):
-            DynamicPSTrainer(cost_source="psychic", **kw)
+            RuntimeConfig(runtime="ps", measure=MeasureConfig(
+                cost_source="psychic"))
         with pytest.raises(ValueError, match="remeasure_every"):
-            DynamicPSTrainer(remeasure_every=-1, **kw)
+            RuntimeConfig(runtime="ps", measure=MeasureConfig(
+                cost_source="measured", remeasure_every=-1))
